@@ -27,11 +27,12 @@ per-process compile events:
   exported programs, checked by jaxlint the way collective budgets are —
   a silently changed compiled program is a CI finding;
   ``--update-artifacts`` regenerates.
-* :mod:`~harp_tpu.aot.cache` — jax's persistent compilation cache wired
-  as a one-call helper (``--compile-cache-dir`` on every run.py
-  subcommand, ``ServeWorker(compile_cache_dir=)``): distinct from and
-  composable with the export path — export kills the TRACE, the compile
-  cache kills the XLA compile of whatever still lowers.
+* :mod:`~harp_tpu.aot.cache` — jax's persistent compilation cache, on by
+  default for every entry point, its directory chosen by ONE resolver
+  (``JAX_COMPILATION_CACHE_DIR`` when set, else one fixed git-ignored
+  directory in the checkout): distinct from and composable with the
+  export path — export kills the TRACE, the compile cache kills the XLA
+  compile of whatever still lowers.
 """
 
 from __future__ import annotations

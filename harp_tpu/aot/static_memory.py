@@ -14,7 +14,7 @@ must reason about BEFORE placing a program:
   defining equation to its last use (program inputs from equation 0,
   program outputs to the end), and the peak is the largest byte sum of any
   equation's live set, recursively including sub-jaxpr interiors (scan /
-  while / cond / pjit bodies contribute ``max(0, sub peak − sub args)`` on
+  while / cond / jit bodies contribute ``max(0, sub peak − sub args)`` on
   top of the enclosing live set — branches of one cond never coexist, so
   subprograms take a max, not a sum).
 * ``transient_peak_ratio`` — ``peak / resident``, the static twin of the
@@ -29,7 +29,7 @@ deterministic for a given jaxpr — the pinned rows move exactly when the
 traced program moves, which is the same contract the collective-budget
 rows already enforce for wire bytes.
 
-The donation audit rides the same trace: a ``pjit`` equation's
+The donation audit rides the same trace: a ``jit`` equation's
 ``donated_invars`` mark buffers the caller promised to XLA, but XLA only
 honors a donation whose aval (shape + dtype) matches an output's — an
 unmatched donation is SILENTLY dropped (jax emits only a warning), and the
@@ -173,14 +173,18 @@ class CapturedConst(NamedTuple):
 
 def captured_consts(closed) -> List[CapturedConst]:
     """Every closed-over constant baked into the traced program,
-    recursively (top-level ClosedJaxpr consts plus inner pjit/closed-call
+    recursively (top-level ClosedJaxpr consts plus inner jit/closed-call
     consts) — the JL403 surface: each one is duplicated HBM per program
     AND a retrace hazard (a new closure constant is a new program)."""
     out: List[CapturedConst] = []
 
     def note(consts):
         for c in consts:
-            b = int(getattr(c, "nbytes", 0) or 0)
+            # size x itemsize, not .nbytes: jax 0.9.0 hands captured numpy
+            # constants over as TypedNdArray, which has no nbytes
+            dt = getattr(c, "dtype", None)
+            b = (int(getattr(c, "size", 0)) * dt.itemsize
+                 if dt is not None else 0)
             if b:
                 out.append(CapturedConst(
                     b, tuple(int(s) for s in getattr(c, "shape", ())),
@@ -197,14 +201,14 @@ def captured_consts(closed) -> List[CapturedConst]:
 
 
 class DroppedDonation(NamedTuple):
-    jit_name: str        # the pjit's `name` param (the traced fn's name)
+    jit_name: str        # the jit eqn's `name` param (the traced fn's name)
     aval: str            # the donated-but-unaliasable buffer's aval
     nbytes: int
 
 
 def dropped_donations(closed) -> List[DroppedDonation]:
     """Donated buffers that cannot alias ANY output (module docstring):
-    walks every pjit equation, greedily matches each output aval
+    walks every jit equation, greedily matches each output aval
     (shape + dtype, in output order — the lowering's own matching) against
     the still-unclaimed donated inputs, and returns the leftovers. A
     non-empty result means XLA drops those donations with only a warning:
@@ -213,7 +217,7 @@ def dropped_donations(closed) -> List[DroppedDonation]:
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pjit":
+            if eqn.primitive.name == "jit":    # jax 0.9.0 (was "pjit")
                 don = eqn.params.get("donated_invars") or ()
                 if any(don):
                     unmatched = [v.aval for v, d in zip(eqn.invars, don)

@@ -296,10 +296,16 @@ def measure_restart(*, num_users: int = 64, num_items: int = 32,
     BEFORE rendezvous), plus the composed leg (``aot_cache``): artifacts
     + the persistent compilation cache, primed by one unmeasured start —
     export kills the trace, the cache kills the XLA compile of the
-    shipped module. Per-leg medians over ``repeats`` runs, plus the
+    shipped module. The two legs that measure WITHOUT the cache switch it
+    off through jax's own ``JAX_ENABLE_COMPILATION_CACHE`` in the workers'
+    environment (an outer ``JAX_COMPILATION_CACHE_DIR`` would otherwise
+    turn it on); the composed leg names one fixed directory under the
+    checkout's cache (the fleet's workers are CPU-pinned, and the CPU
+    backend takes no cache unless one is named — ``aot.cache``). Per-leg medians over ``repeats`` runs, plus the
     replacement-side stage breakdown (spawn→main / jax init / build /
     compile-or-load) from the worker's published rendezvous record — the
     PERF.md recovery-window stage table is THIS data."""
+    import os
     import tempfile
 
     from harp_tpu.serve import OP_TOPK
@@ -311,13 +317,19 @@ def measure_restart(*, num_users: int = 64, num_items: int = 32,
     ref = fleet_mod.topk_reference(*fleet_mod.topk_factors(models["mf"],
                                                            0), k)
 
-    def one_leg(aot_dir, compile_cache_dir=None, prime: bool = False
+    def one_leg(aot_dir, compile_cache: bool = False, prime: bool = False
                 ) -> dict:
         totals, stage_rows, first_reply_waits = [], [], []
+        from harp_tpu.aot import cache
+
+        env = ({} if compile_cache
+               else {"JAX_ENABLE_COMPILATION_CACHE": "false"})
+        cache_dir = (os.path.join(cache.DEFAULT_DIR, "serving_fleet_bench")
+                     if compile_cache else None)
         for i in range(repeats + int(prime)):
             gang = fleet_mod.ProcessServeGang(
                 models, {"mf": 0}, mesh_workers=2, aot_dir=aot_dir,
-                compile_cache_dir=compile_cache_dir)
+                compile_cache_dir=cache_dir, env_extra=env)
             t0 = time.perf_counter()
             t0_wall = time.time()
             try:
@@ -367,17 +379,14 @@ def measure_restart(*, num_users: int = 64, num_items: int = 32,
     import shutil
 
     aot_dir = tempfile.mkdtemp(prefix="harp-bench-aot-")
-    cache_dir = tempfile.mkdtemp(prefix="harp-bench-cc-")
     try:
         prebuild_s = round(_warm_subprocess(models, aot_dir), 3)
         cold = one_leg(None)
         warm = one_leg(aot_dir)
-        composed = one_leg(aot_dir, compile_cache_dir=cache_dir,
-                           prime=True)
+        composed = one_leg(aot_dir, compile_cache=True, prime=True)
     finally:
         # bench runs must not accumulate populated stores in /tmp
         shutil.rmtree(aot_dir, ignore_errors=True)
-        shutil.rmtree(cache_dir, ignore_errors=True)
 
     def speed(leg):
         return (round(cold["restart_to_first_reply_s"]

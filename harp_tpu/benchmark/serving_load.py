@@ -73,7 +73,9 @@ def build_gang(session, *, num_users: int = 512, num_items: int = 256,
     """A 2-worker serving gang over synthetic trained state.
 
     Returns ``(workers, make_client, meta)`` — ``meta`` carries the
-    id/feature spaces the load threads draw from. Factors are random
+    id/feature spaces the load threads draw from and the served state
+    itself (``user_factors``/``item_factors``/``classify_params``, for a
+    caller that checks replies against a reference). Factors are random
     (serving cost does not depend on their values); the tier-1 parity tests
     in tests/test_serve.py cover correctness against fitted models.
 
@@ -101,6 +103,8 @@ def build_gang(session, *, num_users: int = 512, num_items: int = 256,
         slo_p99_s=slo_p99_s, slo_kw=slo_kw, accept_enc=accept_enc)
     meta = {"num_users": num_users, "num_items": num_items, "rank": rank,
             "k": k, "classify_dim": classify_dim,
+            "user_factors": user_factors, "item_factors": item_factors,
+            "classify_params": model.params,
             "endpoints": {CLASSIFY_MODEL: ep_classify, TOPK_MODEL: ep_topk}}
     return workers, make_client, meta
 

@@ -20,7 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from harp_tpu import compat
 from harp_tpu import combiner as combiner_lib
 from harp_tpu.collectives import quantize
 from harp_tpu.parallel.mesh import WORKERS
@@ -32,7 +31,7 @@ def worker_id(axis_name: str = WORKERS) -> jax.Array:
 
 
 def num_workers(axis_name: str = WORKERS) -> int:
-    return compat.axis_size(axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def barrier(axis_name: str = WORKERS) -> None:
@@ -152,7 +151,7 @@ def reduce_scatter(
     if residual is not None:
         out = reduce_scatter(x, combiner, axis_name)
         return out, residual
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if combiner.op in (combiner_lib.Op.SUM, combiner_lib.Op.AVG):
         out = jax.lax.psum_scatter(x, axis_name, scatter_dimension=0, tiled=True)
         if combiner.op is combiner_lib.Op.AVG:
@@ -187,7 +186,7 @@ def rotate(x: jax.Array, steps: int = 1, axis_name: str = WORKERS,
         # encode is one program either way, and a quantized DCN hop is
         # already 2-4x smaller than the chunking threshold assumes
         return quantize.rotate_q(x, steps, axis_name, comm)
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + steps) % n) for i in range(n)]
     if num_chunks > 1 and x.ndim and x.shape[0] > 1:
         parts = jnp.array_split(x, min(num_chunks, x.shape[0]), axis=0)
@@ -204,7 +203,7 @@ def rotate_map(x: jax.Array, mapping: dict, axis_name: str = WORKERS) -> jax.Arr
     nothing for missing sources and delivers ZEROS to unnamed destinations,
     so a malformed map would silently drop shards — validate loudly instead.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     srcs, dsts = set(mapping.keys()), set(mapping.values())
     expect = set(range(n))
     if srcs != expect or dsts != expect:
@@ -226,7 +225,7 @@ def all_to_all(x: jax.Array, axis_name: str = WORKERS) -> jax.Array:
     The substrate for general regroup and for Ulysses-style sequence parallelism.
     ``x`` has shape (n*block, ...); result has the same shape.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     block = x.shape[0] // n
     chunks = x.reshape((n, block) + x.shape[1:])
     out = jax.lax.all_to_all(chunks, axis_name, split_axis=0, concat_axis=0)

@@ -45,7 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from harp_tpu import combiner as combiner_lib
-from harp_tpu import compat
 
 QUANT_MODES = (None, "int8", "bf16")
 
@@ -196,7 +195,7 @@ def rotate_q(x: jax.Array, steps: int, axis_name: str,
     """Quantized ring-shift: encode, ppermute the payload (+scales for
     int8), decode on arrival. One lossy encode per hop; error feedback for
     repeated hops lives in ``rotation.rotate_scan``'s carry."""
-    n_ax = compat.axis_size(axis_name)
+    n_ax = jax.lax.axis_size(axis_name)
     perm = [(i, (i + steps) % n_ax) for i in range(n_ax)]
     shape = x.shape
     flat = x.reshape(-1).astype(jnp.float32)
@@ -213,7 +212,7 @@ def allgather_q(x: jax.Array, axis_name: str, comm: CommConfig,
     """Quantized allgather: each worker's block rides the wire encoded and
     is dequantized on arrival — every worker decodes the SAME payload, so
     the gathered result stays replicated-consistent."""
-    w = compat.axis_size(axis_name)
+    w = jax.lax.axis_size(axis_name)
     flat = x.reshape(-1).astype(jnp.float32)
     block = _block_for(flat.shape[0], comm)
     payload, scale, n = encode_flat(flat, comm, block)
@@ -245,7 +244,7 @@ def reduce_scatter_q(
     ``residual`` (shaped like x, f32): error-feedback state — compress
     (x + residual) and return the new residual alongside the result."""
     _check_combiner(combiner, "reduce_scatter")
-    w = compat.axis_size(axis_name)
+    w = jax.lax.axis_size(axis_name)
     p = x.shape[0]
     if p % w:
         raise ValueError(f"leading dim {p} must divide over {w} workers")
@@ -303,7 +302,7 @@ def allreduce_q(
     element, and this worker's stage-2 re-encode error is folded into its
     own chunk's slice — the residual lives entirely in x's domain."""
     _check_combiner(combiner, "allreduce")
-    w = compat.axis_size(axis_name)
+    w = jax.lax.axis_size(axis_name)
     shape = x.shape
     flat = x.reshape(-1).astype(jnp.float32)
     n = flat.shape[0]

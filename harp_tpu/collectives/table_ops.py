@@ -22,7 +22,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from harp_tpu import compat
 from harp_tpu import combiner as combiner_lib
 from harp_tpu import partitioner as partitioner_lib
 from harp_tpu.collectives import lax_ops
@@ -272,7 +271,7 @@ def bucket_route(dest: jax.Array, capacity: int, payloads,
     ``recv_mask`` marks filled slots; ``overflow`` is the psum'd count of
     VALID records dropped for capacity; ``routing`` feeds
     :func:`route_back`."""
-    w = compat.axis_size(axis_name)
+    w = jax.lax.axis_size(axis_name)
     n = dest.shape[0]
     # invalid records (valid=False or negative dest) route to a virtual
     # "drop" destination w so they never consume a real bucket's capacity;
@@ -345,7 +344,7 @@ def group_by_key_sharded(
     (``replicate_result=False`` keeps only this worker's (ceil(num_keys/W),
     ...) key block).
     """
-    w = compat.axis_size(axis_name)
+    w = jax.lax.axis_size(axis_name)
     kpw = -(-num_keys // w)
     n = keys.shape[0]
     cap = capacity or default_route_capacity(n, w)

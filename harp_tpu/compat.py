@@ -1,19 +1,10 @@
-"""Cross-version jax compatibility — the single home for API-skew shims.
+"""The one jax spelling this package pins for every SPMD program.
 
-The package targets current jax but must run on the 0.4.x line too (some
-TPU images pin it). Every version difference is absorbed HERE, never
-inline at call sites, so raising the supported floor later is a one-file
-audit:
-
-* ``shard_map`` — moved from ``jax.experimental.shard_map`` to the top
-  level, and ``check_rep`` was renamed ``check_vma``.
-* ``axis_size`` — ``jax.lax.axis_size`` did not exist on 0.4.x; ``psum``
-  of a python scalar folds statically to the same int inside shard_map.
-* ``tpu_compiler_params`` — pallas renamed ``TPUCompilerParams`` to
-  ``CompilerParams``.
-* ``enable_cpu_collectives`` — 0.4.x ships CPU cross-process collectives
-  behind an off-by-default gloo switch; newer releases enable them
-  unconditionally and drop the option.
+``shard_map`` with the varying-manual-axes check off: collectives here
+return values the checker cannot prove replicated (a psum'd carry re-enters
+a scan as a replicated input), and every program states its own
+``out_specs``. One installed jax (0.9.0) is supported; there are no
+version branches.
 """
 
 from __future__ import annotations
@@ -22,39 +13,4 @@ import functools
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:                                   # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _esm
-
-    shard_map = functools.partial(_esm, check_rep=False)
-
-
-if hasattr(jax.lax, "axis_size"):
-    def axis_size(axis_name) -> int:
-        """Static size of a mesh axis inside an SPMD program."""
-        return jax.lax.axis_size(axis_name)
-else:                                   # pragma: no cover - version-dependent
-    def axis_size(axis_name) -> int:
-        """Static size of a mesh axis inside an SPMD program."""
-        return jax.lax.psum(1, axis_name)
-
-
-def tpu_compiler_params(pltpu, **kwargs):
-    """``pltpu.CompilerParams`` under either of its names (a jax with
-    neither raises a NAMED AttributeError rather than NoneType-call)."""
-    cls = (getattr(pltpu, "CompilerParams", None)
-           or getattr(pltpu, "TPUCompilerParams"))
-    return cls(**kwargs)
-
-
-def enable_cpu_collectives() -> None:
-    """Turn on cross-process CPU collectives where they are opt-in.
-
-    Must run before ``jax.distributed.initialize``. A CPU gang without this
-    deadlocks on 0.4.x with "Multiprocess computations aren't implemented";
-    the option only affects the CPU backend, so calling it is always safe."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # pragma: no cover - newer jax
-        pass
+shard_map = functools.partial(jax.shard_map, check_vma=False)

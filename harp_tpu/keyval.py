@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harp_tpu import compat
 from harp_tpu import combiner as combiner_lib
 from harp_tpu.collectives.table_ops import (bucket_route,
                                             default_route_capacity,
@@ -168,7 +167,7 @@ class DistributedKV:
         whose shards were moved off a straggler routes by its explicit
         owner map instead of the modulo (serve.endpoints.TopKEndpoint
         .rebalance). Same collectives either way."""
-        w = compat.axis_size(self.axis_name)
+        w = jax.lax.axis_size(self.axis_name)
         n = keys.shape[0]
         cap = route_cap or default_route_capacity(n, w)
         k = keys.astype(jnp.int32)
@@ -195,7 +194,7 @@ class DistributedKV:
         :meth:`update` — explicit per-query owners for rebalanced stores
         (identical collective counts/kinds, so the serve dispatch budget
         pins hold for both routings)."""
-        w = compat.axis_size(self.axis_name)
+        w = jax.lax.axis_size(self.axis_name)
         n = keys.shape[0]
         cap = route_cap or default_route_capacity(n, w)
         k = keys.astype(jnp.int32)
@@ -382,7 +381,7 @@ class DistributedKV64:
                route_cap: int = 0, mask=None):
         """Route (hi, lo, val) records to owners and combine. Returns
         (new DistributedKV64, route_overflow, store_overflow)."""
-        w = compat.axis_size(self.axis_name)
+        w = jax.lax.axis_size(self.axis_name)
         n = hi.shape[0]
         cap = route_cap or default_route_capacity(n, w)
         h = hi.astype(jnp.int32)
@@ -405,7 +404,7 @@ class DistributedKV64:
     def lookup(self, hi, lo, default=0, route_cap: int = 0, mask=None):
         """Distributed get over 64-bit keys; same contract as
         DistributedKV.lookup."""
-        w = compat.axis_size(self.axis_name)
+        w = jax.lax.axis_size(self.axis_name)
         n = hi.shape[0]
         cap = route_cap or default_route_capacity(n, w)
         h = hi.astype(jnp.int32)

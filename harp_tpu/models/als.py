@@ -207,10 +207,6 @@ def _spd_solve(a, b, cfg: ALSConfig):
     if solver == "pallas":
         from harp_tpu.ops import pallas_kernels
 
-        if not pallas_kernels._HAVE_PALLAS:
-            raise ValueError(
-                "solver='pallas' requires jax.experimental.pallas; use "
-                "solver='cholesky' (or 'auto') on this platform")
         # explicit request off-TPU runs the kernel in interpret mode (slow
         # but exact — the path CI and the CPU mesh exercise); 'auto' never
         # resolves here off-TPU

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harp_tpu import compat
 from harp_tpu.collectives import lax_ops
 from harp_tpu.models.als import pad_csr_lists
 from harp_tpu.parallel.mesh import WORKERS
@@ -55,7 +54,7 @@ def _rank1_update(factor_other, my_factor, idx, val, mask, f, lam):
 
 def _train(u_idx, u_val, u_mask, i_idx, i_val, i_mask, u0, v0,
            cfg: CCDConfig, axis_name: str = WORKERS):
-    w = compat.axis_size(axis_name)
+    w = jax.lax.axis_size(axis_name)
 
     def rank_sweep(carry, f):
         u, v = carry          # u: (U, K) replicated; v: (V, K) replicated

@@ -328,12 +328,16 @@ class KMeans:
                 " (pad at ingest)")
         dtype = (jnp.bfloat16 if self.config.compute_dtype == "bfloat16"
                  else jnp.float32)
-        points = np.asarray(points)
+        # cast and pad on the HOST and scatter from the host array: each
+        # worker's rows go straight to its own device. Staging through
+        # jnp.asarray first would land the whole block on device 0 — the
+        # one chip that then has to hold W workers' data
+        points = np.asarray(points, dtype)
         if self.config.lane_pad and points.shape[1] < self._d_pad:
             points = np.pad(points,
                             ((0, 0), (0, self._d_pad - points.shape[1])))
-        pts = self.session.scatter(jnp.asarray(points, dtype))
-        cen = self.session.replicate_put(jnp.asarray(centroids0, jnp.float32))
+        pts = self.session.scatter(points)
+        cen = self.session.replicate_put(np.asarray(centroids0, np.float32))
         return pts, cen
 
     def fit_prepared(self, pts: jax.Array, cen: jax.Array):

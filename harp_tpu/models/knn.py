@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harp_tpu import compat
 from harp_tpu.collectives import lax_ops
 from harp_tpu.ops import distance
 from harp_tpu.parallel.mesh import WORKERS
@@ -33,7 +32,7 @@ def _knn_search(queries, x_block, y_block, k: int, axis_name: str = WORKERS
     # gather W*k candidates per query, then global top-k
     all_d = lax_ops.allgather(loc_d[None], axis_name)     # (W, Q, k)
     all_lab = lax_ops.allgather(loc_lab[None], axis_name)
-    w = compat.axis_size(axis_name)
+    w = jax.lax.axis_size(axis_name)
     all_d = jnp.moveaxis(all_d, 0, 1).reshape(queries.shape[0], w * k)
     all_lab = jnp.moveaxis(all_lab, 0, 1).reshape(queries.shape[0], w * k)
     best_d, best_i = jax.lax.top_k(all_d, k)

@@ -24,7 +24,6 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from harp_tpu import compat
 from harp_tpu.collectives import lax_ops
 from harp_tpu.parallel.mesh import WORKERS
 
@@ -219,7 +218,7 @@ def distributed_sort(x: jax.Array, axis_name: str = WORKERS) -> jax.Array:
     all-gathered O(N) per chip — VERDICT r3 weak #6). Worker w's output
     block holds global order statistics [w·N/W, (w+1)·N/W).
     """
-    w = compat.axis_size(axis_name)
+    w = jax.lax.axis_size(axis_name)
     wid = lax_ops.worker_id(axis_name)
     n_l = x.shape[0]
     x = jnp.sort(x, axis=0)
@@ -250,7 +249,7 @@ def quantiles(x: jax.Array, qs: jax.Array, axis_name: str = WORKERS) -> jax.Arra
     bracketing global order statistics with one masked psum — no chip ever
     materializes the full column.
     """
-    w = compat.axis_size(axis_name)
+    w = jax.lax.axis_size(axis_name)
     wid = lax_ops.worker_id(axis_name)
     xs = distributed_sort(x, axis_name)          # sorted shard (N/W, D)
     n_l = xs.shape[0]

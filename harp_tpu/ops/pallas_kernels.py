@@ -7,8 +7,10 @@ matrix + row argmin + partial-sum accumulation WITHOUT materializing the (N, K)
 distance matrix in HBM — the kernel tiles N, keeps the tile's distances in
 VMEM, and accumulates (K, D) sums / (K,) counts in-place across grid steps.
 
-Falls back transparently to the XLA path (ops/distance.py) on backends without
-pallas TPU lowering; on CPU tests run the kernel in interpret mode.
+Each kernel has a dispatch predicate (``use_*``) that selects it on the TPU
+backend at the shapes it tiles and the XLA path elsewhere; CPU tests run the
+kernels in interpret mode. A kernel Mosaic refuses raises the compiler's own
+error — nothing here catches it and retries through XLA.
 
 Measured (v5e chip, K-means n=1M k=100 d=100, 200 in-program iterations):
 the fused kernel ties the XLA path (919 vs 925 iters/s) — XLA's own fusion of
@@ -24,17 +26,11 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from harp_tpu import compat
 from harp_tpu.ops import distance as xla_path
 from harp_tpu.ops import lane_pack
-
-try:
-    from jax.experimental import pallas as pl
-    _HAVE_PALLAS = True
-except ImportError:    # pragma: no cover
-    pl = None
-    _HAVE_PALLAS = False
 
 
 def _kmeans_tile_kernel(x_ref, c_ref, sums_ref, counts_ref, cost_ref,
@@ -261,8 +257,6 @@ def dense_mf_hop_pallas(vb: jax.Array, w_t: jax.Array, h_t: jax.Array,
     ``(w_t_new, h_t_new, sse, h_t_next)`` — ``h_t_next`` is the block this
     worker receives, i.e. what ``lax_ops.rotate(h, 1)`` would deliver; the
     caller's rotation scan must then run shift=0."""
-    from jax.experimental.pallas import tpu as pltpu
-
     if ring_hop and interpret:
         raise ValueError("ring_hop=True has no interpret-mode lowering "
                          "(remote DMA is not emulated off-TPU)")
@@ -309,7 +303,7 @@ def dense_mf_hop_pallas(vb: jax.Array, w_t: jax.Array, h_t: jax.Array,
     scratch_shapes = [pltpu.VMEM((k, s), jnp.float32)]
     params = {"vmem_limit_bytes": min(int(vmem_bytes), 100 * 1024 * 1024)}
     if ring is not None:
-        out_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))  # h_t_next
+        out_specs.append(pl.BlockSpec(memory_space=pl.ANY))     # h_t_next
         out_shape.append(jax.ShapeDtypeStruct((k, cpb), jnp.float32))
         scratch_shapes += [pltpu.SemaphoreType.DMA] * 2
         from harp_tpu.ops import ring_dma as _rd
@@ -328,7 +322,7 @@ def dense_mf_hop_pallas(vb: jax.Array, w_t: jax.Array, h_t: jax.Array,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
-        compiler_params=compat.tpu_compiler_params(pltpu, **params),
+        compiler_params=pltpu.CompilerParams(**params),
         interpret=interpret,
     )(vb, w_t, rc8, cc8, h_t)
     if ring is not None:
@@ -581,8 +575,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     round trip through HBM. ``parallel.ring_attention.ring_attention_mha``
     is the consumer.
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     if ring_hop and not return_stats:
         raise ValueError("ring_hop=True requires return_stats=True (the "
                          "ring merge needs the streaming-softmax stats)")
@@ -677,16 +669,16 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         # DMA source must see the whole array, the blocked specs only see
         # per-step tiles) and two ANY-space outputs receive the neighbor's
         # blocks; per-direction double-buffered send/recv semaphore pairs
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
         out_shape += [jax.ShapeDtypeStruct(kt.shape, kt.dtype),
                       jax.ShapeDtypeStruct(vt.shape, vt.dtype)]
-        out_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
+        out_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
         scratch_shapes += [pltpu.SemaphoreType.DMA((2,)),
                            pltpu.SemaphoreType.DMA((2,))]
         from harp_tpu.ops import ring_dma as _rd
 
-        call_kwargs["compiler_params"] = compat.tpu_compiler_params(
-            pltpu, collective_id=_rd.COLLECTIVE_IDS["flash_ring"])
+        call_kwargs["compiler_params"] = pltpu.CompilerParams(
+            collective_id=_rd.COLLECTIVE_IDS["flash_ring"])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                     # iq_of, j_of
         grid=(h_dim, len(iq_of)),
@@ -740,7 +732,7 @@ def use_flash_pallas(l: int) -> bool:
     HARP_FLASH_PALLAS=0."""
     import os
 
-    if os.environ.get("HARP_FLASH_PALLAS", "1") == "0" or not _HAVE_PALLAS:
+    if os.environ.get("HARP_FLASH_PALLAS", "1") == "0":
         return False
     if jax.default_backend() != "tpu":
         return False
@@ -874,7 +866,7 @@ def use_spd_solve_pallas(k: int) -> bool:
     HARP_ALS_PALLAS=0."""
     import os
 
-    if os.environ.get("HARP_ALS_PALLAS", "1") == "0" or not _HAVE_PALLAS:
+    if os.environ.get("HARP_ALS_PALLAS", "1") == "0":
         return False
     if jax.default_backend() != "tpu":
         return False
@@ -887,7 +879,7 @@ def use_dense_mf_pallas(cpb: int, s_rows: int, k: int) -> bool:
     HARP_DENSE_PALLAS=0. Shapes must satisfy the kernel's tiling."""
     import os
 
-    if os.environ.get("HARP_DENSE_PALLAS", "1") == "0" or not _HAVE_PALLAS:
+    if os.environ.get("HARP_DENSE_PALLAS", "1") == "0":
         return False
     if jax.default_backend() != "tpu":
         return False
@@ -905,8 +897,8 @@ def kmeans_stats(x: jax.Array, c: jax.Array, block_n: int = 256,
     it at BOTH storage dtypes (measured r4 bench config: XLA 828 f32 /
     918 bf16 iters/s vs pallas 877 / 895 — the hypothesis that XLA's
     score materialization would dominate at bf16 did not survive
-    measurement), while mosaic compile time for large grids is minutes on
-    remote-compile setups — pay it only when you ask to. Accepts f32 or
+    measurement), so the extra Mosaic compile buys nothing — pay it only
+    when you ask to. Accepts f32 or
     bf16 ``x``; scores/stats always accumulate f32 and Σ‖x‖² derives
     in-kernel (``x_sq_sum`` applies to the XLA path only).
     """
@@ -914,7 +906,7 @@ def kmeans_stats(x: jax.Array, c: jax.Array, block_n: int = 256,
 
     on_tpu = jax.default_backend() == "tpu"
     opted = os.environ.get("HARP_USE_PALLAS", "") == "1"
-    if (_HAVE_PALLAS and on_tpu and opted and x.shape[0] % block_n == 0
+    if (on_tpu and opted and x.shape[0] % block_n == 0
             and x.dtype in (jnp.float32, jnp.bfloat16)):
         return kmeans_stats_pallas(x, c, block_n, valid_k=valid_k)
     return xla_path.partial_sums_counts(x, c, compute_dtype, x_sq_sum,

@@ -61,19 +61,11 @@ import os
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from harp_tpu import compat
 from harp_tpu.collectives import lax_ops
 from harp_tpu.parallel.mesh import WORKERS
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except ImportError:    # pragma: no cover
-    pl = None
-    pltpu = None
-    _HAVE_PALLAS = False
 
 # The jit name the CPU/interpret fallback wraps the lax rotate in. jaxlint's
 # jaxpr walker keys on this exact prefix to book the hop's operand bytes as
@@ -113,11 +105,11 @@ def _next_hop_id() -> int:
 
 
 def use_ring_dma() -> bool:
-    """Dispatch gate for the fused kernels: TPU backend with pallas, opt-out
+    """Dispatch gate for the fused kernels: the TPU backend, opt-out
     HARP_RING_DMA=0. Off TPU the engine ALWAYS takes the tagged lax
-    fallback (interpret mode has no remote-DMA emulation on this jax), so
-    tier-1 and the budget traces run the identical schedule off-chip."""
-    if os.environ.get("HARP_RING_DMA", "1") == "0" or not _HAVE_PALLAS:
+    twin (the kernels have no remote-DMA lowering there), so tier-1 and the
+    budget traces run the identical schedule off-chip."""
+    if os.environ.get("HARP_RING_DMA", "1") == "0":
         return False
     return jax.default_backend() == "tpu"
 
@@ -125,6 +117,15 @@ def use_ring_dma() -> bool:
 # --------------------------------------------------------------------------- #
 # Kernel-side engine (use INSIDE a pallas kernel)
 # --------------------------------------------------------------------------- #
+
+
+def _on_axis(axis_name: str, index):
+    """MESH device id of the worker at ``index`` along ``axis_name``. The
+    dict form leaves every OTHER mesh axis at this device's own coordinate
+    — every session mesh has two axes (``workers``, ``model``), and a bare
+    ``(index,)`` tuple is refused there ("Number of device ids must match
+    the number of mesh axes")."""
+    return {axis_name: index}
 
 
 def ring_neighbor(axis_name: str, num_workers: int, shift: int = 1):
@@ -153,12 +154,12 @@ def ring_ready(axis_name: str, num_workers: int, shift: int = 1) -> None:
     plain wait(2) does NOT have this property (two signals from the fast
     side could satisfy the wait while the slow side never arrived, r10
     review finding). Requires the kernel to carry a ``collective_id``
-    (compat.tpu_compiler_params); concurrent kernels must use DISTINCT ids
+    (``pltpu.CompilerParams``); concurrent kernels must use DISTINCT ids
     (:func:`_next_hop_id`) so their barrier semaphores never alias."""
     bsem = pltpu.get_barrier_semaphore()
     my = lax.axis_index(axis_name)
     src = lax.rem(my - (shift % num_workers) + num_workers, num_workers)
-    pltpu.semaphore_signal(bsem, inc=1, device_id=(src,),
+    pltpu.semaphore_signal(bsem, inc=1, device_id=_on_axis(axis_name, src),
                            device_id_type=pltpu.DeviceIdType.MESH)
     pltpu.semaphore_wait(bsem, 1)
 
@@ -173,7 +174,7 @@ def hop_op(src_ref, dst_ref, send_sem, recv_sem, axis_name: str,
     _, dst = ring_neighbor(axis_name, num_workers, shift)
     return pltpu.make_async_remote_copy(
         src_ref=src_ref, dst_ref=dst_ref, send_sem=send_sem,
-        recv_sem=recv_sem, device_id=(dst,),
+        recv_sem=recv_sem, device_id=_on_axis(axis_name, dst),
         device_id_type=pltpu.DeviceIdType.MESH)
 
 
@@ -214,39 +215,41 @@ def _fallback_hop(axis_name: str, shift: int):
 
 
 def _hop_kernel(x_ref, o_ref, send_sem, recv_sem, *, axis_name: str,
-                num_workers: int, shift: int, barrier: bool):
-    if barrier:
-        ring_ready(axis_name, num_workers, shift)
+                num_workers: int, shift: int):
+    ring_ready(axis_name, num_workers, shift)
     start_hop(x_ref, o_ref, send_sem, recv_sem, axis_name, num_workers,
               shift).wait()
 
 
-def hop(x: jax.Array, shift: int = 1, axis_name: str = WORKERS,
-        barrier: bool = True) -> jax.Array:
+def hop(x: jax.Array, shift: int = 1, axis_name: str = WORKERS
+        ) -> jax.Array:
     """One fused ring hop: this worker's block moves to ``(id + shift)``;
     the return value is the block from ``(id - shift)`` — exactly
     ``lax_ops.rotate(x, shift)``, bitwise, on every backend.
 
     On TPU the payload rides a single in-kernel ``make_async_remote_copy``
     (HBM → remote HBM: the DMA reads the producer's buffer directly, where
-    ``ppermute`` costs a staging copy on both ends). ``barrier=False``
-    skips the :func:`ring_ready` handshake for callers that already
-    synchronized this step themselves.
+    ``ppermute`` costs a staging copy on both ends), after the
+    :func:`ring_ready` handshake (jax refuses a ``collective_id`` on a
+    kernel that takes no barrier semaphore, so there is no handshake-free
+    variant).
 
     Off TPU: the tagged lax fallback (module docstring)."""
     if not use_ring_dma():
         return _fallback_hop(axis_name, shift)(x)
     nw = lax_ops.num_workers(axis_name)
+    if nw == 1:
+        return x        # a ring of one: no neighbour, no DMA, no handshake
     kernel = functools.partial(_hop_kernel, axis_name=axis_name,
-                               num_workers=nw, shift=shift, barrier=barrier)
+                               num_workers=nw, shift=shift)
     return pl.pallas_call(
         kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
-        compiler_params=compat.tpu_compiler_params(
-            pltpu, collective_id=_next_hop_id()),
+        compiler_params=pltpu.CompilerParams(
+            collective_id=_next_hop_id()),
     )(x)
 
 
@@ -286,7 +289,8 @@ def _allgather_kernel(x_ref, o_ref, copy_sem, send_sem, recv_sems, *,
     slot = lax.rem(my - t + num_workers, num_workers)
     op = pltpu.make_async_remote_copy(
         src_ref=o_ref.at[slot], dst_ref=o_ref.at[slot],
-        send_sem=send_sem, recv_sem=recv_sems.at[t], device_id=(right,),
+        send_sem=send_sem, recv_sem=recv_sems.at[t],
+        device_id=_on_axis(axis_name, right),
         device_id_type=pltpu.DeviceIdType.MESH)
     op.start()
     op.wait()
@@ -320,12 +324,12 @@ def ring_allgather(x: jax.Array, axis_name: str = WORKERS) -> jax.Array:
     out = pl.pallas_call(
         kernel,
         grid=(nw - 1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((nw,) + x.shape, x.dtype),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.DMA((nw - 1,))],
-        compiler_params=compat.tpu_compiler_params(
-            pltpu, collective_id=COLLECTIVE_IDS["allgather"]),
+        compiler_params=pltpu.CompilerParams(
+            collective_id=COLLECTIVE_IDS["allgather"]),
     )(x)
     return out.reshape((nw * x.shape[0],) + x.shape[1:])

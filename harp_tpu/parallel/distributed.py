@@ -21,8 +21,6 @@ from typing import Optional
 
 import jax
 
-from harp_tpu import compat
-
 log = logging.getLogger("harp_tpu.distributed")
 
 _gang_watchdog = None
@@ -55,7 +53,6 @@ def initialize(
     # the gang env written by parallel.launch (the depl/ nodes-file
     # launcher) plays the role of Harp's <jobID>/tasks file: each value is
     # adopted independently, only where the caller left the parameter None
-    compat.enable_cpu_collectives()
     coordinator_address = coordinator_address or os.environ.get("HARP_COORDINATOR")
     if num_processes is None and "HARP_NUM_PROCESSES" in os.environ:
         num_processes = int(os.environ["HARP_NUM_PROCESSES"])

@@ -78,14 +78,11 @@ class P2PAuthModeMismatch(ConnectionError):
 
 
 def _kv_client():
-    """The jax.distributed coordination-service client, if a gang is up."""
-    try:
-        from jax._src import distributed as _jd
+    """The jax.distributed coordination-service client (jax 0.9.0 keeps it
+    at ``jax._src.distributed.global_state.client``); None = no gang."""
+    from jax._src import distributed as _jd
 
-        return _jd.global_state.client
-    except (ImportError, AttributeError):
-        # jax._src layout shifts across versions; no gang = no global_state
-        return None
+    return _jd.global_state.client
 
 
 def _routable_host() -> str:
@@ -97,13 +94,9 @@ def _routable_host() -> str:
     When the coordinator itself is NON-loopback — a real multi-host gang —
     falling back to 127.0.0.1 would publish an address every peer resolves
     to ITSELF (advisor r3): that case raises instead."""
-    coord = None
-    try:
-        from jax._src import distributed as _jd
+    from jax._src import distributed as _jd
 
-        coord = _jd.global_state.coordinator_address
-    except (ImportError, AttributeError):
-        pass
+    coord = _jd.global_state.coordinator_address     # None = no gang
     coord_host = coord.rsplit(":", 1)[0] if coord else None
     if coord_host:
         try:

@@ -78,8 +78,8 @@ to compile; the compiled programs themselves are content-hash-pinned in
 ``tools/artifact_manifest.json`` (jaxlint ``--artifacts-only``).
 Per-model coalescing deadlines (``max_wait_overrides``, with
 :func:`~harp_tpu.serve.batcher.suggest_max_wait_s` deriving a value from
-the span table's per-model coalesce stage) and jax's persistent
-compilation cache (``compile_cache_dir=``) ride the same surfaces.
+the span table's per-model coalesce stage) ride the same surfaces; jax's
+persistent compilation cache is always on under them (``aot.cache``).
 """
 
 from __future__ import annotations

@@ -120,10 +120,9 @@ class ServeWorker:
         # receive thread starts — for a fleet subprocess that means
         # before rendezvous: an elastic replacement never recompiles
         # under traffic.
-        if compile_cache_dir:
-            from harp_tpu.aot.cache import enable_compile_cache
+        from harp_tpu.aot.cache import enable_compile_cache
 
-            enable_compile_cache(compile_cache_dir)
+        enable_compile_cache(compile_cache_dir)
         self.aot_loaded: Dict[str, list] = {}
         if aot_store is not None:
             from harp_tpu.aot import serve_artifacts

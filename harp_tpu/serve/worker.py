@@ -29,8 +29,11 @@ The process:
    FROM ARTIFACTS: store hits are installed as the resident dispatches
    (``trace_counts`` stays 0 for them — the never-recompile contract) and
    every bucket is warmed, all BEFORE rendezvous — an elastic replacement
-   never compiles under traffic (ISSUE 15). ``compile_cache_dir`` wires
-   jax's persistent compilation cache underneath either path;
+   never compiles under traffic (ISSUE 15). jax's persistent compilation
+   cache is always on underneath either path (``aot.cache`` resolves its
+   directory; ``compile_cache_dir`` names one where
+   ``JAX_COMPILATION_CACHE_DIR`` does not). The process fleet's workers
+   are pinned to CPU for now (``_force_cpu``; ROADMAP S7/D6);
 4. publishes its address atomically into the rendezvous directory
    (``w<rank>.g<generation>.json``) together with its measured START-UP
    STAGE timings (jax init / build+restore / compile-or-load) — the
@@ -83,8 +86,9 @@ def main(argv=None) -> int:
                    help="artifact store to prepare dispatches from "
                         "(overrides the spec's aot_dir; '' disables)")
     p.add_argument("--compile-cache-dir", default=None,
-                   help="jax persistent compilation cache (overrides the "
-                        "spec's compile_cache_dir)")
+                   help="jax persistent compilation cache directory "
+                        "(overrides the spec's compile_cache_dir; "
+                        "JAX_COMPILATION_CACHE_DIR wins over both)")
     args = p.parse_args(argv)
     with open(args.spec) as f:
         spec = json.load(f)

@@ -245,7 +245,7 @@ def log_device_mem_usage(metrics: Optional[Metrics] = None
     Returns ``{device: {"bytes_in_use": ..., "peak_bytes_in_use": ...}}`` and,
     when a ``metrics`` registry is passed, gauges both values per device.
     Backends without the introspection raise ``NotImplementedError`` (CPU) or
-    an ``XlaRuntimeError`` (a ``RuntimeError`` subclass, e.g. remote tunnels
+    an ``XlaRuntimeError`` (a ``RuntimeError`` subclass, e.g. a backend
     mid-teardown); those devices are skipped, anything else propagates.
     """
     import jax           # deferred: registry users (the gang supervisor) must

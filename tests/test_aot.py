@@ -312,13 +312,46 @@ def test_two_models_one_worker_honor_different_deadlines(session, rng):
     assert dt_fast < slow * 0.5, dt_fast
 
 
-def test_compile_cache_dir_populates(session, rng, tmp_path):
-    """ServeWorker(compile_cache_dir=) wires jax's persistent cache: a
-    dispatch writes cache entries into the directory."""
+def test_compile_cache_resolver(monkeypatch, tmp_path, caplog):
+    """aot.cache.resolve_cache_dir is the ONE place the cache directory is
+    chosen: the environment's when set (a disagreeing explicit directory
+    does not win, and is logged once), else the explicit one, else one
+    fixed path inside the checkout — never a temp name."""
+    import tempfile
+
+    from harp_tpu.aot import cache
+
+    env_dir, explicit = str(tmp_path / "from_env"), str(tmp_path / "flag")
+    monkeypatch.setenv(cache.ENV_VAR, env_dir)
+    assert cache.resolve_cache_dir() == env_dir
+    with caplog.at_level("WARNING", logger="harp_tpu.aot"):
+        assert cache.resolve_cache_dir(explicit) == env_dir
+        assert cache.resolve_cache_dir(explicit) == env_dir
+    ignored = [r for r in caplog.records if "ignoring" in r.getMessage()]
+    assert len(ignored) == 1 and explicit in ignored[0].getMessage()
+    assert cache.resolve_cache_dir(env_dir) == env_dir   # agreeing: silent
+    monkeypatch.delenv(cache.ENV_VAR)
+    assert cache.resolve_cache_dir(explicit) == explicit
+    default = cache.resolve_cache_dir()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert default == cache.DEFAULT_DIR == os.path.join(
+        root, ".jax_compile_cache")
+    assert not default.startswith(tempfile.gettempdir())
+    assert cache.resolve_cache_dir() == default          # fixed, not minted
+    # the CPU backend does not take the default directory (XLA:CPU logs an
+    # error per executable it loads back); a named one it does take
+    assert cache.enable_compile_cache() is None
+
+
+def test_compile_cache_dir_populates(session, rng, tmp_path, monkeypatch):
+    """ServeWorker(compile_cache_dir=) wires jax's persistent cache through
+    the resolver: a dispatch writes cache entries into the directory."""
     import jax
 
+    from harp_tpu.aot import cache
     from harp_tpu.serve import OP_CLASSIFY, local_gang
 
+    monkeypatch.delenv(cache.ENV_VAR, raising=False)
     cache_dir = str(tmp_path / "cc")
     prev = jax.config.jax_compilation_cache_dir
     workers, make_client = local_gang(
@@ -328,6 +361,7 @@ def test_compile_cache_dir_populates(session, rng, tmp_path):
     try:
         x = rng.normal(size=(12,)).astype(np.float32)
         client.request(OP_CLASSIFY, "cc", x, timeout=60.0)
+        assert jax.config.jax_compilation_cache_dir == cache_dir
         assert os.listdir(cache_dir), "no persistent-cache entries written"
     finally:
         client.close()

@@ -1098,6 +1098,17 @@ def test_hlo_parser_shapes_collectives_and_while():
     assert sorted(str(s) for s in
                   hlo_audit.entry_param_shapes(_HLO_FIXTURE)) == \
         ["f32[8,2]", "s32[]"]
+    # the TPU compiler's tiled layouts carry parentheses (recorded from the
+    # 4-chip NN step, PR 21): they must not end a tuple type early
+    tpu = ("  %all-reduce.2 = (f32[128,256]{1,0:T(8,128)S(1)}, "
+           "f32[256]{0:T(256)S(1)}, /*index=2*/f32[]{:T(128)}) "
+           "all-reduce(%fusion.108, %gte.928, %f.3), channel_id=1\n"
+           "  %while.99 = (s32[]{:T(128)}, f32[16]{0:T(128)S(1)}) "
+           "while(%tuple.1), condition=%c, body=%b\n")
+    assert hlo_audit.collective_stats(tpu) == {"all-reduce": {
+        "count": 1, "bytes": (128 * 256 + 256 + 1) * 4,
+        "shapes": ["f32[128,256]+f32[256]+f32[]"]}}
+    assert hlo_audit.while_count(tpu) == 1
 
 
 def test_jl501_injected_compiler_allgather_and_clean_twin():

@@ -90,7 +90,7 @@ def _walk(jaxpr, counts: Dict[str, int], dtype_bad: List[str],
           nbytes: Dict[str, int]) -> None:
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
-        if (name == "pjit"
+        if (name == "jit"     # jax 0.9.0's name for the jit primitive
                 and str(eqn.params.get("name", "")).startswith(
                     FUSED_HOP_PREFIX)):
             counts["fused_dma"] = counts.get("fused_dma", 0) + 1
