@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from harp_tpu import combiner as combiner_lib
 from harp_tpu.collectives import quantize
 from harp_tpu.parallel.mesh import WORKERS
+from harp_tpu.telemetry.scopes import scoped
 
 
 def worker_id(axis_name: str = WORKERS) -> jax.Array:
@@ -34,6 +35,7 @@ def num_workers(axis_name: str = WORKERS) -> int:
     return jax.lax.axis_size(axis_name)
 
 
+@scoped("lax.barrier")
 def barrier(axis_name: str = WORKERS) -> None:
     """Reference: Communication.barrier:61 (master counts workers then replies).
 
@@ -44,6 +46,7 @@ def barrier(axis_name: str = WORKERS) -> None:
     jax.lax.psum(jnp.ones((), jnp.int32), axis_name)
 
 
+@scoped("lax.allreduce")
 def allreduce(
     x: jax.Array,
     combiner: combiner_lib.Combiner = combiner_lib.SUM,
@@ -66,6 +69,7 @@ def allreduce(
     return (out, residual) if residual is not None else out
 
 
+@scoped("lax.reduce")
 def reduce(
     x: jax.Array,
     root: int = 0,
@@ -83,6 +87,7 @@ def reduce(
     return jnp.where(mask, full, jnp.full_like(full, combiner.identity))
 
 
+@scoped("lax.broadcast")
 def broadcast(x: jax.Array, root: int = 0, axis_name: str = WORKERS) -> jax.Array:
     """Every worker ends with ``root``'s value.
 
@@ -93,6 +98,7 @@ def broadcast(x: jax.Array, root: int = 0, axis_name: str = WORKERS) -> jax.Arra
     return jax.lax.psum(jnp.where(mask, x, jnp.zeros_like(x)), axis_name)
 
 
+@scoped("lax.allgather")
 def allgather(x: jax.Array, axis_name: str = WORKERS, tiled: bool = True,
               comm: Optional[quantize.CommConfig] = None,
               fused: bool = False) -> jax.Array:
@@ -119,6 +125,7 @@ def allgather(x: jax.Array, axis_name: str = WORKERS, tiled: bool = True,
     return jax.lax.all_gather(x, axis_name, tiled=tiled)
 
 
+@scoped("lax.gather")
 def gather(x: jax.Array, root: int = 0, axis_name: str = WORKERS,
            tiled: bool = True) -> jax.Array:
     """Root ends with all blocks; others get zeros (Communication.gather:196)."""
@@ -127,6 +134,7 @@ def gather(x: jax.Array, root: int = 0, axis_name: str = WORKERS,
     return jnp.where(mask, full, jnp.zeros_like(full))
 
 
+@scoped("lax.reduce_scatter")
 def reduce_scatter(
     x: jax.Array,
     combiner: combiner_lib.Combiner = combiner_lib.SUM,
@@ -165,6 +173,7 @@ def reduce_scatter(
     return combiner.tree_combine(exchanged, axis=0)
 
 
+@scoped("lax.rotate")
 def rotate(x: jax.Array, steps: int = 1, axis_name: str = WORKERS,
            comm: Optional[quantize.CommConfig] = None,
            num_chunks: int = 1) -> jax.Array:
@@ -186,6 +195,14 @@ def rotate(x: jax.Array, steps: int = 1, axis_name: str = WORKERS,
         # encode is one program either way, and a quantized DCN hop is
         # already 2-4x smaller than the chunking threshold assumes
         return quantize.rotate_q(x, steps, axis_name, comm)
+    return ring_shift(x, steps, axis_name, num_chunks)
+
+
+def ring_shift(x: jax.Array, steps: int, axis_name: str = WORKERS,
+               num_chunks: int = 1) -> jax.Array:
+    """The bare ring ``ppermute`` of :func:`rotate`, under no scope of its
+    own: ``rotate`` names it ``lax.rotate``, the rotation loops name it
+    ``rotation.hop`` (telemetry/scopes.py)."""
     n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + steps) % n) for i in range(n)]
     if num_chunks > 1 and x.ndim and x.shape[0] > 1:
@@ -195,6 +212,7 @@ def rotate(x: jax.Array, steps: int = 1, axis_name: str = WORKERS,
     return jax.lax.ppermute(x, axis_name, perm)
 
 
+@scoped("lax.rotate_map")
 def rotate_map(x: jax.Array, mapping: dict, axis_name: str = WORKERS) -> jax.Array:
     """Rotate with an explicit worker→worker map (Harp's rotateMap Int2IntMap,
     LocalGlobalSyncCollective.rotateGlobal:746).
@@ -219,6 +237,7 @@ def rotate_map(x: jax.Array, mapping: dict, axis_name: str = WORKERS) -> jax.Arr
     return jax.lax.ppermute(x, axis_name, perm)
 
 
+@scoped("lax.all_to_all")
 def all_to_all(x: jax.Array, axis_name: str = WORKERS) -> jax.Array:
     """Block transpose across workers: chunk j of worker i → slot i of worker j.
 
@@ -232,6 +251,7 @@ def all_to_all(x: jax.Array, axis_name: str = WORKERS) -> jax.Array:
     return out.reshape((n * block,) + x.shape[1:])
 
 
+@scoped("lax.send_recv")
 def send_recv(x: jax.Array, pairs: list[tuple[int, int]],
               axis_name: str = WORKERS) -> jax.Array:
     """Point-to-point sends (source, dest) — Harp's DataSender/event substitute.

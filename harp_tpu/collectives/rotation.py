@@ -59,6 +59,7 @@ from harp_tpu.collectives import lax_ops, quantize
 from harp_tpu.ops import ring_dma
 from harp_tpu.parallel import mesh as mesh_lib
 from harp_tpu.parallel.mesh import WORKERS
+from harp_tpu.telemetry.scopes import scoped
 
 Carry = TypeVar("Carry")
 Slice = Any  # pytree of arrays — one model slice's per-worker block
@@ -103,6 +104,7 @@ def _ef_zero(block: Slice):
 ef_zero = _ef_zero
 
 
+@scoped("rotation.hop")
 def _shift_block(block: Slice, res: Optional[Slice], shift: int,
                  axis_name: str, comm: Optional[quantize.CommConfig],
                  link_class: str, fused: bool = False):
@@ -114,14 +116,14 @@ def _shift_block(block: Slice, res: Optional[Slice], shift: int,
         def send(x):
             if fused and _quantizable(x):
                 return ring_dma.hop(x, shift, axis_name)
-            return lax_ops.rotate(
+            return lax_ops.ring_shift(
                 x, shift, axis_name,
                 num_chunks=chunks_for_link(_leaf_bytes(x), link_class))
         return jax.tree.map(send, block), res
 
     def send_ef(leaf, r):
         if not _quantizable(leaf):
-            return lax_ops.rotate(leaf, shift, axis_name), r
+            return lax_ops.ring_shift(leaf, shift, axis_name), r
         flat = leaf.reshape(-1).astype(jnp.float32)
         block_sz = quantize._block_for(flat.shape[0], comm)
         payload, scale, n, new_r = quantize.ef_encode_flat(

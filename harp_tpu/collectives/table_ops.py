@@ -27,6 +27,7 @@ from harp_tpu import partitioner as partitioner_lib
 from harp_tpu.collectives import lax_ops
 from harp_tpu.parallel.mesh import WORKERS
 from harp_tpu.table import Dist, Table
+from harp_tpu.telemetry.scopes import scoped
 
 
 def _perm_apply(data: jax.Array, perm) -> jax.Array:
@@ -37,6 +38,7 @@ def _perm_apply(data: jax.Array, perm) -> jax.Array:
     return jnp.take(data, jnp.asarray(perm), axis=0)
 
 
+@scoped("table.allreduce")
 def allreduce(t: Table, axis_name: str = WORKERS, comm=None, residual=None):
     """LOCAL → REPLICATED: combine per-worker contributions partition-wise.
 
@@ -55,6 +57,7 @@ def allreduce(t: Table, axis_name: str = WORKERS, comm=None, residual=None):
     return t.with_data(out, Dist.REPLICATED)
 
 
+@scoped("table.reduce")
 def reduce(t: Table, root: int = 0, axis_name: str = WORKERS) -> Table:
     """LOCAL → LOCAL: combined table on ``root``, identity elsewhere
     (ReduceCollective.reduce:150)."""
@@ -63,12 +66,14 @@ def reduce(t: Table, root: int = 0, axis_name: str = WORKERS) -> Table:
     return t.with_data(out, Dist.LOCAL)
 
 
+@scoped("table.broadcast")
 def broadcast(t: Table, root: int = 0, axis_name: str = WORKERS) -> Table:
     """LOCAL@root → REPLICATED (BcastCollective.broadcast:338)."""
     out = lax_ops.broadcast(t.data, root, axis_name)
     return t.with_data(out, Dist.REPLICATED)
 
 
+@scoped("table.regroup")
 def regroup(
     t: Table,
     partitioner: Optional[partitioner_lib.Partitioner] = None,
@@ -100,6 +105,7 @@ def regroup(
     return t.with_data(out, Dist.SHARDED)
 
 
+@scoped("table.allgather")
 def allgather(
     t: Table,
     partitioner: Optional[partitioner_lib.Partitioner] = None,
@@ -123,6 +129,7 @@ def allgather(
     return t.with_data(full, Dist.REPLICATED)
 
 
+@scoped("table.aggregate")
 def aggregate(
     t: Table,
     partitioner: Optional[partitioner_lib.Partitioner] = None,
@@ -137,6 +144,7 @@ def aggregate(
     return allgather(regroup(t, partitioner, axis_name), partitioner, axis_name)
 
 
+@scoped("table.rotate")
 def rotate(t: Table, steps: int = 1, axis_name: str = WORKERS) -> Table:
     """SHARDED → SHARDED: ring-shift ownership by ``steps``
     (LocalGlobalSyncCollective.rotate:710 → ppermute over the ICI ring)."""
@@ -144,12 +152,14 @@ def rotate(t: Table, steps: int = 1, axis_name: str = WORKERS) -> Table:
     return t.with_data(lax_ops.rotate(t.data, steps, axis_name))
 
 
+@scoped("table.rotate_with_map")
 def rotate_with_map(t: Table, mapping: dict, axis_name: str = WORKERS) -> Table:
     """Rotate with an explicit worker→worker map (rotateGlobal:746)."""
     _expect(t, Dist.SHARDED, "rotate")
     return t.with_data(lax_ops.rotate_map(t.data, mapping, axis_name))
 
 
+@scoped("table.push")
 def push(
     local: Table,
     global_table: Table,
@@ -176,6 +186,7 @@ def push(
     return global_table.with_data(merged)
 
 
+@scoped("table.pull")
 def pull(
     global_table: Table,
     partitioner: Optional[partitioner_lib.Partitioner] = None,
@@ -191,6 +202,7 @@ def pull(
                      fused=fused)
 
 
+@scoped("table.gather")
 def gather(t: Table, root: int = 0, axis_name: str = WORKERS) -> Table:
     """SHARDED → root holds the full table (Communication.gather:196)."""
     _expect(t, Dist.SHARDED, "gather")
@@ -198,6 +210,7 @@ def gather(t: Table, root: int = 0, axis_name: str = WORKERS) -> Table:
     return t.with_data(out, Dist.LOCAL)
 
 
+@scoped("table.join")
 def join(
     dynamic: Table,
     static: Table,
@@ -222,6 +235,7 @@ def join(
     return regroup(dynamic, partitioner, axis_name)
 
 
+@scoped("table.group_by_key")
 def group_by_key(
     keys: jax.Array,
     values: jax.Array,
@@ -257,6 +271,7 @@ def default_route_capacity(n: int, num_workers: int) -> int:
     return max(1, 2 * -(-n // num_workers))
 
 
+@scoped("table.bucket_route")
 def bucket_route(dest: jax.Array, capacity: int, payloads,
                  valid: Optional[jax.Array] = None,
                  axis_name: str = WORKERS):
@@ -307,6 +322,7 @@ def bucket_route(dest: jax.Array, capacity: int, payloads,
     return routed, recv_mask, overflow, routing
 
 
+@scoped("table.route_back")
 def route_back(answers, routing, axis_name: str = WORKERS):
     """Return per-slot answers (W, capacity, ...) to the senders, restoring
     the original record order. Second output marks records whose answer
@@ -318,6 +334,7 @@ def route_back(answers, routing, axis_name: str = WORKERS):
     return picked[inv], ok[inv]
 
 
+@scoped("table.group_by_key_sharded")
 def group_by_key_sharded(
     keys: jax.Array,
     values: jax.Array,

@@ -45,6 +45,7 @@ from harp_tpu.collectives import lax_ops, quantize, rotation, table_ops
 from harp_tpu.ops import distance, lane_pack, pallas_kernels
 from harp_tpu.session import HarpSession
 from harp_tpu.table import Table
+from harp_tpu.telemetry.scopes import scoped
 
 COMM_VARIANTS = ("regroupallgather", "allreduce", "pushpull", "bcastreduce",
                  "rotation")
@@ -131,11 +132,17 @@ class KMeans:
             sums, counts, sq = pallas_kernels.kmeans_stats(
                 points, centroids, compute_dtype=cdtype, x_sq_sum=x_sq_sum,
                 valid_k=cfg.num_centroids)
-            stats = jnp.concatenate([sums, counts[:, None]], axis=1)  # (K, D+1)
+            with jax.named_scope("kmeans.stats"):
+                stats = jnp.concatenate([sums, counts[:, None]], axis=1)  # (K, D+1)
             return stats, sq
 
+        @scoped("kmeans.update")
         def average(stats):
             return stats[:, :-1] / jnp.maximum(stats[:, -1:], 1.0)
+
+        @scoped("kmeans.update")
+        def total_cost(sq):
+            return jax.lax.psum(sq, lax_ops.WORKERS)
 
         comm = (quantize.CommConfig(quant=cfg.quant) if cfg.quant is not None
                 else None)
@@ -150,7 +157,7 @@ class KMeans:
             if cfg.comm == "rotation":
                 new_c, sq, qres = self._rotation_iter(
                     points, centroids, k_pad, w, x_sq_sum, cdtype, comm, qres)
-                cost = jax.lax.psum(sq, lax_ops.WORKERS)
+                cost = total_cost(sq)
                 return new_c, cost, qres
             stats, sq = estep(points, centroids, x_sq_sum)
             local = Table.local(stats, num_workers=w, name="cen")
@@ -185,10 +192,11 @@ class KMeans:
                 own = average(red.data)
                 new_c = table_ops.broadcast(
                     Table.local(own, num_workers=w), root=0).data
-            cost = jax.lax.psum(sq, lax_ops.WORKERS)
+            cost = total_cost(sq)
             return new_c, cost, qres
 
         def fit_fn(points, centroids0):
+            telemetry.traced("kmeans.fit")   # runs when jax traces, only
             # points arrive feature-padded from prepare(); pad again here so
             # a raw fit_prepared(points, ·) call stays correct (no-op on
             # prepared arrays). Centroids pad to the full (k_pad, d_pad)
@@ -198,16 +206,22 @@ class KMeans:
                 lane_pack.pad_cols(centroids0, d_pad), k_pad)
             # Σ‖x‖² is iteration-invariant: hoist it so the hot loop reads the
             # point block exactly twice per iteration (the two MXU matmuls)
-            pf = points.astype(jnp.float32)
-            x_sq_sum = jnp.sum(pf * pf)
+            with jax.named_scope("kmeans.norms"):
+                pf = points.astype(jnp.float32)
+                x_sq_sum = jnp.sum(pf * pf)
 
+            # the loop's own plumbing (the counter, the stacking of the
+            # per-iteration cost) reads under the M-step's name; what the
+            # body names itself keeps its deeper name
+            loop_scope = jax.named_scope("kmeans.update")
             if comm is None:
                 def scan_body(c, _):
                     new_c, cost, _ = iter_body(c, points, x_sq_sum)
                     return new_c, cost
 
-                cen, costs = jax.lax.scan(scan_body, cen, None,
-                                          length=cfg.iterations)
+                with loop_scope:
+                    cen, costs = jax.lax.scan(scan_body, cen, None,
+                                              length=cfg.iterations)
             else:
                 # EF residual rides the fit carry: stats-table shaped f32
                 qres0 = jnp.zeros((k_pad, d_pad + 1), jnp.float32)
@@ -217,8 +231,10 @@ class KMeans:
                     new_c, cost, qres = iter_body(c, points, x_sq_sum, qres)
                     return (new_c, qres), cost
 
-                (cen, _), costs = jax.lax.scan(
-                    scan_body_q, (cen, qres0), None, length=cfg.iterations)
+                with loop_scope:
+                    (cen, _), costs = jax.lax.scan(
+                        scan_body_q, (cen, qres0), None,
+                        length=cfg.iterations)
             return cen[: cfg.num_centroids, : cfg.dim], costs
 
         return sess.spmd(fit_fn, in_specs=(sess.shard(), sess.replicate()),
@@ -258,14 +274,17 @@ class KMeans:
                     jnp.where(upd, gid, best_id)), cen_block
 
         init = (jnp.full((points.shape[0],), jnp.inf), jnp.zeros(points.shape[0], jnp.int32))
-        (best_d, best_id), my = rotation.rotate_scan(body, init, my, w)
-        onehot = jax.nn.one_hot(best_id, k_pad, dtype=points.dtype)
-        sums = jax.lax.dot_general(onehot, points, (((0,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        # counts must accumulate in f32: a bf16 one-hot (bf16 point storage)
-        # cannot represent integer sums past 256
-        counts = jnp.sum(onehot.astype(jnp.float32), axis=0)
-        stats = jnp.concatenate([sums, counts[:, None]], axis=1)
+        (best_d, best_id), my = rotation.rotate_scan(
+            scoped("kmeans.scores")(body), init, my, w)
+        with jax.named_scope("kmeans.stats"):
+            onehot = jax.nn.one_hot(best_id, k_pad, dtype=points.dtype)
+            sums = jax.lax.dot_general(
+                onehot, points, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            # counts must accumulate in f32: a bf16 one-hot (bf16 point
+            # storage) cannot represent integer sums past 256
+            counts = jnp.sum(onehot.astype(jnp.float32), axis=0)
+            stats = jnp.concatenate([sums, counts[:, None]], axis=1)
         if comm is None:
             full = table_ops.allreduce(Table.local(stats, num_workers=w))
         else:
@@ -278,9 +297,11 @@ class KMeans:
         data = full.data
         # keep the full padded table in the carry (phantom rows average to
         # zero); fit_fn trims once at exit
-        new_c = data[:, :-1] / jnp.maximum(data[:, -1:], 1.0)
+        with jax.named_scope("kmeans.update"):
+            new_c = data[:, :-1] / jnp.maximum(data[:, -1:], 1.0)
         # best_d holds scores; true sq-distance cost adds the Σ‖x‖² constant
-        return new_c, jnp.sum(best_d) + x_sq_sum, qres
+        with jax.named_scope("kmeans.scores"):
+            return new_c, jnp.sum(best_d) + x_sq_sum, qres
 
     def comm_scale(self) -> float:
         """Ratio of this model's padded stat-table elements to the budget
@@ -306,7 +327,7 @@ class KMeans:
         loaders pad at ingest).
         """
         pts, cen = self.prepare(points, centroids0)
-        return self._fit(pts, cen)
+        return self.fit_prepared(pts, cen)
 
     def prepare(self, points, centroids0):
         """Place data on the mesh once; pair with :meth:`fit_prepared` to keep
@@ -326,23 +347,30 @@ class KMeans:
             raise ValueError(
                 f"num points {n} must divide over {self.session.num_workers} workers"
                 " (pad at ingest)")
-        dtype = (jnp.bfloat16 if self.config.compute_dtype == "bfloat16"
-                 else jnp.float32)
-        # cast and pad on the HOST and scatter from the host array: each
-        # worker's rows go straight to its own device. Staging through
-        # jnp.asarray first would land the whole block on device 0 — the
-        # one chip that then has to hold W workers' data
-        points = np.asarray(points, dtype)
-        if self.config.lane_pad and points.shape[1] < self._d_pad:
-            points = np.pad(points,
-                            ((0, 0), (0, self._d_pad - points.shape[1])))
-        pts = self.session.scatter(points)
-        cen = self.session.replicate_put(np.asarray(centroids0, np.float32))
+        with telemetry.phase("kmeans.prepare"):
+            dtype = (jnp.bfloat16 if self.config.compute_dtype == "bfloat16"
+                     else jnp.float32)
+            # cast and pad on the HOST and scatter from the host array: each
+            # worker's rows go straight to its own device. Staging through
+            # jnp.asarray first would land the whole block on device 0 — the
+            # one chip that then has to hold W workers' data
+            points = np.asarray(points, dtype)
+            if self.config.lane_pad and points.shape[1] < self._d_pad:
+                points = np.pad(
+                    points, ((0, 0), (0, self._d_pad - points.shape[1])))
+            pts = self.session.scatter(points)
+            cen = self.session.replicate_put(
+                np.asarray(centroids0, np.float32))
         return pts, cen
 
     def fit_prepared(self, pts: jax.Array, cen: jax.Array):
-        """Run training on already-placed device arrays (no H2D in the hot path)."""
-        return self._fit(pts, cen)
+        """Run training on already-placed device arrays (no H2D in the hot
+        path). Returns at the enqueue: the caller's fetch waits for the run."""
+        with telemetry.phase("kmeans.call"):
+            with telemetry.phase("step.dispatch"):
+                out = self._fit(pts, cen)
+            telemetry.record_program("kmeans.fit", self._fit, (pts, cen))
+        return out
 
     def fit_from_stream(self, chunks, centroids0, total_rows: int,
                         *, metrics=None) -> Tuple[jax.Array, jax.Array]:

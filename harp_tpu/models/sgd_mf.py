@@ -74,6 +74,7 @@ from harp_tpu.ops import ring_dma
 from harp_tpu.ops import lane_pack, pallas_kernels
 from harp_tpu.parallel.mesh import fetch
 from harp_tpu.session import HarpSession
+from harp_tpu.telemetry.scopes import scoped
 
 
 @dataclasses.dataclass(frozen=True)
@@ -290,13 +291,19 @@ class SGDMF:
         two_slice = cfg.num_slices == 2
 
         def fit_fn(*args):
+            telemetry.traced("sgd_mf.fit")   # runs when jax traces, only
             data, (w0, h0) = args[:num_data_args], args[num_data_args:]
-            update_bucket = make_update_bucket(tuple(d[0] for d in data))
+            # this worker's block of each data array; a relayout of the slab
+            # on the way into the loops reads under this name
+            with jax.named_scope("sgdmf.select"):
+                local_data = tuple(d[0] for d in data)
+            update_bucket = make_update_bucket(local_data)
 
             def hop_body(carry, h_block, t):
                 w_local, sse, cnt = carry
-                wid = lax_ops.worker_id()
-                bucket_id = self._bucket_id(wid, t, w)
+                with jax.named_scope("sgdmf.select"):
+                    wid = lax_ops.worker_id()
+                    bucket_id = self._bucket_id(wid, t, w)
                 w_local, h_block, sse, cnt = update_bucket(
                     w_local, h_block, sse, cnt, bucket_id)
                 return (w_local, sse, cnt), h_block
@@ -310,20 +317,27 @@ class SGDMF:
 
             def epoch(state, _):
                 w_local, h = state
-                carry0 = (w_local, jnp.zeros(()), jnp.zeros(()))
+                with jax.named_scope("sgdmf.rmse"):
+                    carry0 = (w_local, jnp.zeros(()), jnp.zeros(()))
                 slices = h if two_slice else (h,)
-                (w_local, sse, cnt), out = rotator.run(hop_body, carry0,
-                                                       slices)
+                # the hop loop's own plumbing (its counter, which hop this
+                # is) reads as part of picking the resident bucket
+                with jax.named_scope("sgdmf.select"):
+                    (w_local, sse, cnt), out = rotator.run(hop_body, carry0,
+                                                           slices)
                 h = out if two_slice else out[0]
-                sse = jax.lax.psum(sse, lax_ops.WORKERS)
-                cnt = jax.lax.psum(cnt, lax_ops.WORKERS)
-                return (w_local, h), jnp.sqrt(sse / jnp.maximum(cnt, 1.0))
+                with jax.named_scope("sgdmf.rmse"):
+                    sse = jax.lax.psum(sse, lax_ops.WORKERS)
+                    cnt = jax.lax.psum(cnt, lax_ops.WORKERS)
+                    return (w_local, h), jnp.sqrt(sse / jnp.maximum(cnt, 1.0))
 
             # two-slice h0 arrives as this worker's (1, 2, cpb, K) chunk:
             # slice A block w and slice B block W+w
             h_init = (h0[0, 0], h0[0, 1]) if two_slice else h0
-            (w_local, h_fin), rmse = jax.lax.scan(
-                epoch, (w0, h_init), None, length=epochs)
+            # the epoch loop's plumbing stacks the per-epoch RMSE
+            with jax.named_scope("sgdmf.rmse"):
+                (w_local, h_fin), rmse = jax.lax.scan(
+                    epoch, (w0, h_init), None, length=epochs)
             if two_slice:
                 h_fin = jnp.stack(h_fin, axis=0)[None]   # (1, 2, cpb, K)
             return w_local, h_fin, rmse
@@ -346,10 +360,11 @@ class SGDMF:
             def update_bucket(w_local, h_block, sse, cnt, bucket_id):
                 """Run the minibatched SGD updates of one (worker, block)
                 bucket against the resident H block."""
-                r = jnp.take(r_idx, bucket_id, axis=0).reshape(nmb, mbs)
-                c = jnp.take(c_idx, bucket_id, axis=0).reshape(nmb, mbs)
-                v = jnp.take(val, bucket_id, axis=0).reshape(nmb, mbs)
-                msk = jnp.take(mask, bucket_id, axis=0).reshape(nmb, mbs)
+                with jax.named_scope("sgdmf.select"):
+                    r = jnp.take(r_idx, bucket_id, axis=0).reshape(nmb, mbs)
+                    c = jnp.take(c_idx, bucket_id, axis=0).reshape(nmb, mbs)
+                    v = jnp.take(val, bucket_id, axis=0).reshape(nmb, mbs)
+                    msk = jnp.take(mask, bucket_id, axis=0).reshape(nmb, mbs)
 
                 def mb_step(state, xs):
                     wl, hb, sse, cnt = state
@@ -365,8 +380,9 @@ class SGDMF:
                     return (wl, hb, sse + jnp.sum(err * err),
                             cnt + jnp.sum(mm)), None
 
-                (w_local, h_block, sse, cnt), _ = jax.lax.scan(
-                    mb_step, (w_local, h_block, sse, cnt), (r, c, v, msk))
+                with jax.named_scope("sgdmf.stripes"):
+                    (w_local, h_block, sse, cnt), _ = jax.lax.scan(
+                        mb_step, (w_local, h_block, sse, cnt), (r, c, v, msk))
                 return w_local, h_block, sse, cnt
 
             return update_bucket
@@ -407,6 +423,7 @@ class SGDMF:
             # HBM traffic; measured +14% samples/s, identical SSE)
             v_slab, row_cnt, col_cnt = data
 
+            @scoped("sgdmf.stripes")
             def _run_stripes_pallas(w_local, h_block, sse, cnt, vb, rcnt,
                                     ccnt, col_tile, ring_hop):
                 # fused hop kernel: pred/G stay in VMEM → one slab read per
@@ -430,6 +447,7 @@ class SGDMF:
                 return (w_t.T, h_t.T, sse + hop_sse,
                         cnt + jnp.sum(ccnt))
 
+            @scoped("sgdmf.stripes")
             def _run_stripes(w_local, h_block, sse, cnt, vb, rcnt, ccnt):
                 def stripe(state, xs):
                     hb, sse = state
@@ -465,17 +483,20 @@ class SGDMF:
                 return w_new.reshape(rpw, -1), h_block, sse, cnt
 
             def update_bucket(w_local, h_block, sse, cnt, bucket_id):
-                if v_slab.shape[0] == 1:
-                    # single-block mesh (W=1, 1 slice): static index — the
-                    # dynamic-slice would copy the full slab (GBs) every hop
-                    vb, rcnt, ccnt = v_slab[0], row_cnt[0], col_cnt[0]
-                else:
-                    vb = jnp.take(v_slab, bucket_id, axis=0)   # (rpw, cpb)
-                    rcnt = jnp.take(row_cnt, bucket_id, axis=0)
-                    ccnt = jnp.take(col_cnt, bucket_id, axis=0)
-                # col counts are stored at the finest stripe granularity
-                # (nmb_fine, cpb); coarser budgets sum adjacent fine stripes
-                ccnt = ccnt.reshape(nmb, nmb_fine // nmb, cpb).sum(axis=1)
+                with jax.named_scope("sgdmf.select"):
+                    if v_slab.shape[0] == 1:
+                        # single-block mesh (W=1, 1 slice): static index —
+                        # the dynamic-slice would copy the full slab (GBs)
+                        # every hop
+                        vb, rcnt, ccnt = v_slab[0], row_cnt[0], col_cnt[0]
+                    else:
+                        vb = jnp.take(v_slab, bucket_id, axis=0)  # (rpw, cpb)
+                        rcnt = jnp.take(row_cnt, bucket_id, axis=0)
+                        ccnt = jnp.take(col_cnt, bucket_id, axis=0)
+                    # col counts are stored at the finest stripe granularity
+                    # (nmb_fine, cpb); coarser budgets sum adjacent fine
+                    # stripes
+                    ccnt = ccnt.reshape(nmb, nmb_fine // nmb, cpb).sum(axis=1)
                 if fused:
                     return _run_stripes_pallas(w_local, h_block, sse, cnt,
                                                vb, rcnt, ccnt, col_tile,
@@ -552,16 +573,17 @@ class SGDMF:
         if cfg.layout not in ("auto", "dense", "sparse"):
             raise ValueError(f"layout must be auto|dense|sparse, got "
                              f"{cfg.layout!r}")
-        _validate_coo(rows, cols, num_rows, num_cols, vals)
-        # keep-first dedupe for BOTH layouts: identical training sets
-        rows, cols, vals, dropped = dedupe_coo(rows, cols, vals, num_cols)
-        layout = self._choose_layout(num_rows, num_cols)
-        if layout == "dense":
-            state = self._prepare_dense(rows, cols, vals, num_rows, num_cols,
-                                        seed)
-        else:
-            state = self._prepare_sparse(rows, cols, vals, num_rows, num_cols,
-                                         seed)
+        with telemetry.phase("sgd_mf.prepare"):
+            _validate_coo(rows, cols, num_rows, num_cols, vals)
+            # keep-first dedupe for BOTH layouts: identical training sets
+            rows, cols, vals, dropped = dedupe_coo(rows, cols, vals, num_cols)
+            layout = self._choose_layout(num_rows, num_cols)
+            if layout == "dense":
+                state = self._prepare_dense(rows, cols, vals, num_rows,
+                                            num_cols, seed)
+            else:
+                state = self._prepare_sparse(rows, cols, vals, num_rows,
+                                             num_cols, seed)
         self.last_layout_stats["duplicates_dropped"] = dropped
         return state
 
@@ -658,6 +680,7 @@ class SGDMF:
             # prepare), so add == set and no f32 transient doubles the peak
             # memory that _choose_layout budgeted. Missing entries become NaN
             # (the mask slab is transient, freed after this program).
+            telemetry.traced("sgd_mf.densify")   # runs when jax traces, only
             idx, val, msk = idx[0], val[0], msk[0]
             bf = jnp.bfloat16
             v = jnp.zeros((slab_elems,), bf).at[idx].add(
@@ -735,17 +758,24 @@ class SGDMF:
         import time as _time
 
         layout, data, w0, h0, meta = state
-        key = self._program(layout, self.config.minibatches_per_hop,
-                            self.config.epochs, meta[6])
-        t0 = _time.perf_counter()
-        out_w, out_h, rmse = self._compiled[key](*data, w0, h0)
-        rmse = np.asarray(rmse)
-        # telemetry at the fetch that was already here: one event per epoch,
-        # wall amortized over the scanned program (step_log docstring)
-        telemetry.record_chunk(
-            "sgd_mf", start=0, losses=rmse.tolist(),
-            wall_s=_time.perf_counter() - t0,
-            ledger=telemetry.ledger_for("sgd_mf", quant=self.config.quant))
+        with telemetry.phase("sgd_mf.call"):
+            key = self._program(layout, self.config.minibatches_per_hop,
+                                self.config.epochs, meta[6])
+            step, args = self._compiled[key], (*data, w0, h0)
+            t0 = _time.perf_counter()
+            with telemetry.phase("step.dispatch"):
+                out_w, out_h, rmse = step(*args)
+            telemetry.record_program("sgd_mf.fit", step, args)
+            with telemetry.phase("step.fetch"):
+                rmse = np.asarray(rmse)
+            # telemetry at the fetch that was already here: one event per
+            # epoch, wall amortized over the scanned program (step_log
+            # docstring)
+            telemetry.record_chunk(
+                "sgd_mf", start=0, losses=rmse.tolist(),
+                wall_s=_time.perf_counter() - t0,
+                ledger=telemetry.ledger_for("sgd_mf",
+                                            quant=self.config.quant))
         return out_w, out_h, rmse
 
     def fit_prepared(self, state) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from harp_tpu.ops import lane_pack
+from harp_tpu.telemetry.scopes import scoped
 
 
 def pairwise_sq_dist(x: jax.Array, c: jax.Array,
@@ -52,6 +53,7 @@ def pairwise_sq_dist(x: jax.Array, c: jax.Array,
     return x2 - 2.0 * xc + c2
 
 
+@scoped("kmeans.scores")
 def pairwise_scores(x: jax.Array, c: jax.Array,
                     compute_dtype=None) -> jax.Array:
     """Assignment scores ‖c‖² − 2x·c (N, K): same argmin ordering as
@@ -98,18 +100,26 @@ def partial_sums_counts(
     # constant and never needs materializing — the E-step reads x exactly
     # twice (two MXU matmuls) and touches no (N, D)-sized temporaries.
     scores = pairwise_scores(x, c, compute_dtype)         # (N, K)
+    # two kernels, interleaved as the equations always were: the score GEMM
+    # with its mask, argmin and min, and the one-hot stats product
     if valid_k is not None:
-        scores = lane_pack.mask_phantom_cols(scores, valid_k)
-    xm = x if compute_dtype is None else x.astype(compute_dtype)
-    assign = jnp.argmin(scores, axis=1)
-    min_s = jnp.min(scores, axis=1)
-    oh_dtype = x.dtype if compute_dtype is None else compute_dtype
-    onehot = jax.nn.one_hot(assign, c.shape[0], dtype=oh_dtype)  # (N, K)
-    sums = jax.lax.dot_general(                                  # (K, D) on MXU
-        onehot, xm, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    counts = jnp.sum(onehot.astype(jnp.float32), axis=0)
+        with jax.named_scope("kmeans.scores"):
+            scores = lane_pack.mask_phantom_cols(scores, valid_k)
+    with jax.named_scope("kmeans.stats"):
+        xm = x if compute_dtype is None else x.astype(compute_dtype)
+    with jax.named_scope("kmeans.scores"):
+        assign = jnp.argmin(scores, axis=1)
+        min_s = jnp.min(scores, axis=1)
+    with jax.named_scope("kmeans.stats"):
+        oh_dtype = x.dtype if compute_dtype is None else compute_dtype
+        onehot = jax.nn.one_hot(assign, c.shape[0], dtype=oh_dtype)  # (N, K)
+        sums = jax.lax.dot_general(                              # (K, D) on MXU
+            onehot, xm, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        counts = jnp.sum(onehot.astype(jnp.float32), axis=0)
     if x_sq_sum is None:
-        xf = x.astype(jnp.float32)
-        x_sq_sum = jnp.sum(xf * xf)
-    return sums, counts, jnp.sum(min_s) + x_sq_sum
+        with jax.named_scope("kmeans.norms"):
+            xf = x.astype(jnp.float32)
+            x_sq_sum = jnp.sum(xf * xf)
+    with jax.named_scope("kmeans.scores"):
+        return sums, counts, jnp.sum(min_s) + x_sq_sum

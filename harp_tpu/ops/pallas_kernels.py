@@ -148,6 +148,7 @@ def kmeans_stats_pallas(
             jax.ShapeDtypeStruct((1, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="kmeans_stats",
     )(x, c)
     return (sums[:k_orig, :d_orig], counts2d[0, :k_orig], cost1[0, 0])
 
@@ -324,6 +325,7 @@ def dense_mf_hop_pallas(vb: jax.Array, w_t: jax.Array, h_t: jax.Array,
         scratch_shapes=scratch_shapes,
         compiler_params=pltpu.CompilerParams(**params),
         interpret=interpret,
+        name="dense_mf_hop",
     )(vb, w_t, rc8, cc8, h_t)
     if ring is not None:
         w_t_new, h_t_new, sse128, h_next = outs

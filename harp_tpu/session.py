@@ -36,7 +36,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from harp_tpu import compat
+from harp_tpu import compat, telemetry
 from harp_tpu.parallel import mesh as mesh_lib
 from harp_tpu.parallel.mesh import WORKERS
 
@@ -88,10 +88,12 @@ class HarpSession:
         pads for you). This replaces Harp's whole-files-per-worker input split
         (MultiFileInputFormat) for in-memory data.
         """
-        return jax.device_put(array, self.sharding(self.shard(axis)))
+        with telemetry.phase("session.place"):
+            return jax.device_put(array, self.sharding(self.shard(axis)))
 
     def replicate_put(self, array) -> jax.Array:
-        return jax.device_put(array, self.sharding(self.replicate()))
+        with telemetry.phase("session.place"):
+            return jax.device_put(array, self.sharding(self.replicate()))
 
     # -- SPMD compilation --------------------------------------------------------
     def spmd(
@@ -120,7 +122,9 @@ class HarpSession:
     def run(self, fn: Callable, *args, in_specs: Any, out_specs: Any, **kw):
         """One-shot: compile and invoke (for scripts; hot paths should keep the
         callable from :meth:`spmd`)."""
-        return self.spmd(fn, in_specs=in_specs, out_specs=out_specs, **kw)(*args)
+        with telemetry.phase("session.run"):
+            return self.spmd(fn, in_specs=in_specs, out_specs=out_specs,
+                             **kw)(*args)
 
     def barrier(self) -> None:
         """Host-level barrier across processes (multi-host); on a single host this

@@ -4,14 +4,27 @@ straggler detection, and on-demand profiler windows.
 The reference's only observability was log4j inline wall-clock per phase
 (SURVEY §5: KMeansCollectiveMapper.java:190-195 per-iteration compute/merge/
 aggregate ms). This package is that idiom grown into a subsystem, under one
-hard constraint: **telemetry must never enter a jitted step program**. Every
-hook lives at the host chunk boundaries where the training loops ALREADY
-synchronize losses to the host (the ``fit_checkpointed`` chunk fetches, the
-final ``np.asarray`` of a scanned fit) — jaxlint's JL104 host-sync check and
-the JL201/JL203 collective-budget manifest are bitwise unchanged with
-telemetry on, and ``tools/ci_checks.sh`` gates exactly that.
+hard constraint: **no telemetry operation and no host sync ever enters a
+jitted step program**. Every hook lives at the host chunk boundaries where
+the training loops ALREADY synchronize losses to the host (the
+``fit_checkpointed`` chunk fetches, the final ``np.asarray`` of a scanned
+fit) — jaxlint's JL104 host-sync check and the JL201/JL203 collective-budget
+manifest are bitwise unchanged with telemetry on, and ``tools/ci_checks.sh``
+gates exactly that. Names are not operations: the step programs carry
+``jax.named_scope`` names on every kernel (metadata only).
 
-Layers:
+Always on, writing nothing (one span layer, at the layer boundaries of the
+training path):
+
+* :mod:`~harp_tpu.telemetry.host_spans` — ``phase(name)``, the one host span:
+  ``(name, start, end, parent, call)`` on ``time.perf_counter()`` in a bounded
+  ring, and a ``TraceAnnotation`` in any open profiler session; ``traced``
+  counts the traces of a program. Readers: ``phases``, ``self_seconds``.
+* :mod:`~harp_tpu.telemetry.scopes` — the list of device scopes, the
+  ``scoped`` decorator, and the two readers that turn a compiled text and a
+  profiler trace into device time per scope.
+
+Layers that write, when enabled:
 
 * :mod:`~harp_tpu.telemetry.step_log` — per-step structured events into a
   bounded ring buffer, flushed as JSONL per rank. ``record_chunk`` is the one
@@ -54,7 +67,7 @@ callers (gang members inherit them from the launcher environment).
 
 from __future__ import annotations
 
-from harp_tpu.telemetry import spans
+from harp_tpu.telemetry import scopes, spans
 from harp_tpu.telemetry.comm_ledger import (CommLedger, ledger_for,
                                             load_manifest, manifest_target)
 from harp_tpu.telemetry.exporter import (MetricsExporter,
@@ -62,17 +75,21 @@ from harp_tpu.telemetry.exporter import (MetricsExporter,
                                          prometheus_text)
 from harp_tpu.telemetry.gang import (gather_snapshots, publish_straggler_report,
                                      straggler_report)
+from harp_tpu.telemetry.host_spans import (PhaseRecord, phase, phases,
+                                           self_seconds, traced)
 from harp_tpu.telemetry.spans import record_span
 from harp_tpu.telemetry.step_log import (StepLog, active, configure, disable,
-                                         phase, record_chunk, record_timing)
+                                         record_chunk, record_program,
+                                         record_timing)
 from harp_tpu.telemetry.watchdog import SLOWatchdog
 from harp_tpu.telemetry.xprof import XprofController, request_xprof
 
 __all__ = [
-    "CommLedger", "MetricsExporter", "SLOWatchdog", "StepLog",
+    "CommLedger", "MetricsExporter", "PhaseRecord", "SLOWatchdog", "StepLog",
     "XprofController", "active", "aggregate_snapshots", "configure",
     "disable", "gather_snapshots", "ledger_for", "load_manifest",
-    "manifest_target", "phase", "prometheus_text",
-    "publish_straggler_report", "record_chunk", "record_span",
-    "record_timing", "request_xprof", "spans", "straggler_report",
+    "manifest_target", "phase", "phases", "prometheus_text",
+    "publish_straggler_report", "record_chunk", "record_program",
+    "record_span", "record_timing", "request_xprof", "scopes",
+    "self_seconds", "spans", "straggler_report", "traced",
 ]

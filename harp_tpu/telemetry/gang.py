@@ -248,7 +248,7 @@ class GangCollector:
     def __call__(self, boundary_index: int, log) -> None:
         if boundary_index % (self.every * log.interval) != 0:
             return
-        from harp_tpu.telemetry.step_log import phase
+        from harp_tpu.telemetry.host_spans import phase
 
         with phase("gang.straggler_publish"):
             snaps = gather_snapshots(self.session, metrics=log.metrics)

@@ -1,0 +1,240 @@
+"""Device scopes — the names the step programs give their own kernels.
+
+XLA numbers what it compiles (``fusion.19``, ``copy.36``) and renumbers it
+whenever the program changes, so a device trace read under those names cannot
+be laid beside the trace of the commit before. The program therefore names
+its kernels itself: every listed scope is a ``jax.named_scope`` on the
+training path, which is metadata only (no operation is added: the jaxpr's
+equations, the collective budget, the AOT content hashes and the persistent
+cache key are what they were) and survives fusion in the compiled text's
+``op_name``.
+
+:data:`SCOPES` is the whole list; :func:`scoped` is how a module takes a name
+from it. The two readers are shared by the operator and the benchmark:
+:func:`scope_map` reads a compiled program's text into ``{instruction:
+scope}``, and :func:`device_time_by_scope` reduces a profiler trace's
+``XLA Ops`` line to self time per scope through such a map::
+
+    python -m harp_tpu.telemetry.scopes <trace dir> <hlo text>
+
+A fusion that spans two scopes has one ``op_name``, the one XLA kept for it
+(its root's): that scope takes the fusion's whole time.
+
+Names are compile-time metadata, and jax leaves metadata out of the persistent
+compile cache's key: an executable loaded from an entry that an earlier build
+compiled carries *that* build's names (none, if it predates them). Where a
+text shows no listed scope, compile once past the stale entry
+(``JAX_ENABLE_COMPILATION_CACHE=false``, or jax's
+``JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY=1``) and take text and trace
+from that process: the text gains the scopes.
+"""
+
+from __future__ import annotations
+
+import functools
+import glob
+import os
+import re
+import sys
+from typing import Callable, Dict, Optional
+
+import jax
+
+_TABLE_OPS = ("allreduce", "reduce", "broadcast", "regroup", "allgather",
+              "aggregate", "rotate", "rotate_with_map", "push", "pull",
+              "gather", "join", "group_by_key", "bucket_route", "route_back",
+              "group_by_key_sharded")
+_LAX_OPS = ("barrier", "allreduce", "reduce", "broadcast", "allgather",
+            "gather", "reduce_scatter", "rotate", "rotate_map", "all_to_all",
+            "send_recv")
+
+SCOPES = (
+    "kmeans.norms",     # the hoisted sum of squared norms
+    "kmeans.scores",    # the score GEMM with the mask, argmin and min
+    "kmeans.stats",     # the one-hot stats product: one_hot, sums GEMM, counts
+    "kmeans.update",    # M-step arithmetic: average, the cost psum
+    *(f"table.{op}" for op in _TABLE_OPS),   # collectives/table_ops.py
+    *(f"lax.{op}" for op in _LAX_OPS),       # collectives/lax_ops.py
+    "rotation.hop",     # the ring hop of a model block
+    "sgdmf.select",     # picking the resident bucket of the slab
+    "sgdmf.stripes",    # the masked stripe update (XLA, Pallas or sparse)
+    "sgdmf.rmse",       # per-epoch quality: two psums and a square root
+)
+_LISTED = frozenset(SCOPES)
+
+
+def scoped(name: str) -> Callable:
+    """Decorator: run the function under the listed scope ``name``."""
+    if name not in _LISTED:
+        raise ValueError(f"{name!r} is not in telemetry.scopes.SCOPES")
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return wrap
+
+
+# -- readers ----------------------------------------------------------------- #
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([A-Za-z_][\w.\-]*)\s+=\s")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([A-Za-z_][\w.\-]*)\s.*\{\s*$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_OPCODE = re.compile(r"(?<=\s)([a-z][a-z0-9\-]*)\(")
+_REFERENCE = re.compile(r"%([A-Za-z_][\w.\-]*)")
+_CALLED = re.compile(r"(?:body|condition|calls)=%([A-Za-z_][\w.\-]*)")
+_CONTAINERS = ("while", "conditional", "call")
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The deepest listed scope on an ``op_name`` path, or None."""
+    for part in reversed(op_name.split("/")):
+        if part in _LISTED:
+            return part
+    return None
+
+
+def scope_map(hlo_text: str) -> Dict[str, Optional[str]]:
+    """``{instruction name: scope}`` over every instruction of a compiled
+    program's text (``compiled.as_text()``).
+
+    An instruction the program wrote carries an ``op_name`` path
+    (``jit(fit_fn)/.../kmeans.stats/dot_general``): its scope is the deepest
+    listed scope on that path, None where the path has none. An instruction
+    the compiler made itself (a layout copy, a collective it rewrote, a
+    tuple, the relayout of an argument) carries none, or a parameter's bare
+    ``args[0]``: it takes the scope of the nearest instruction with a path
+    that uses it, else of the nearest that it reads, looking through other
+    such instructions; else the scope of the loop or fusion whose body it
+    stands in. None where all of that finds nothing."""
+    named: Dict[str, Optional[str]] = {}     # the program's: from op_name
+    made: Dict[str, str] = {}                # the compiler's: its computation
+    reads: Dict[str, list] = {}
+    used_by: Dict[str, list] = {}
+    caller: Dict[str, str] = {}              # computation -> who runs it
+    computation = ""
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                computation = c.group(1)
+            continue
+        name = m.group(1)
+        body, _, meta = line[m.end():].partition("metadata={")
+        op_name = _OP_NAME.search(meta)
+        # a path is the program's; a bare ``args[0]`` is XLA's own name for
+        # a parameter, which the copies it makes of one inherit
+        if op_name is not None and "/" in op_name.group(1):
+            named[name] = scope_of(op_name.group(1))
+        else:
+            made[name] = computation
+        reads[name] = _REFERENCE.findall(body)
+        for other in reads[name]:
+            used_by.setdefault(other, []).append(name)
+        for called in _CALLED.findall(body):
+            caller[called] = name
+
+    def nearest(name: str, links: Dict[str, list]) -> Optional[str]:
+        seen, stack = {name}, list(reversed(links.get(name, ())))
+        while stack:
+            other = stack.pop()
+            if other in seen:
+                continue
+            seen.add(other)
+            if named.get(other) is not None:
+                return named[other]
+            if other in made:
+                stack.extend(reversed(links.get(other, ())))
+        return None
+
+    out = dict(named)
+    for name in made:
+        out[name] = nearest(name, used_by) or nearest(name, reads)
+    for name, computation in made.items():     # callers are resolved by now
+        if out[name] is None:
+            out[name] = out.get(caller.get(computation))
+    return out
+
+
+def _instruction(event_name: str) -> tuple:
+    """``(name, opcode)`` of an ``XLA Ops`` event, which is named by its
+    instruction's text (``%fusion.19 = f32[...] fusion(...), kind=...``)."""
+    lhs, _, rhs = event_name.partition(" = ")
+    name = lhs.lstrip("%").strip()
+    m = _OPCODE.search(" " + rhs)
+    return name, m.group(1) if m else name.split(".")[0]
+
+
+def device_time_by_scope(xplane_path: str, scopes: Dict[str, Optional[str]],
+                         device: int = 0) -> Dict[Optional[str], float]:
+    """Seconds of device self time per scope over one chip's ``XLA Ops``.
+
+    An instruction's self time is its event's duration less what the events
+    nested in it cover, so a ``while`` is not counted again for its body; the
+    containers' own remainder is loop overhead and is left out. Instructions
+    the map does not know, or knows under no scope, are summed under None.
+    """
+    from jax.profiler import ProfileData
+
+    planes = sorted((p for p in ProfileData.from_file(xplane_path).planes
+                     if p.name.startswith("/device:TPU:")),
+                    key=lambda p: p.name)
+    if device >= len(planes):
+        return {}
+    events = sorted(
+        ((e.start_ns, e.start_ns + e.duration_ns, e.name)
+         for line in planes[device].lines if line.name == "XLA Ops"
+         for e in line.events), key=lambda e: (e[0], -e[1]))
+    out: Dict[Optional[str], float] = {}
+    stack: list = []                     # [start, end, name, covered]
+
+    def close(start, end, name, covered):
+        name, opcode = _instruction(name)
+        if opcode in _CONTAINERS:
+            return
+        scope = scopes.get(name)
+        out[scope] = out.get(scope, 0.0) + (end - start - covered) * 1e-9
+
+    for start, end, name in events:
+        while stack and stack[-1][1] <= start:
+            close(*stack.pop())
+        if stack:
+            stack[-1][3] += min(end, stack[-1][1]) - start
+        stack.append([start, end, name, 0])
+    while stack:
+        close(*stack.pop())
+    return out
+
+
+def main(argv=None) -> int:
+    argv = sys.argv[1:] if argv is None else argv
+    if len(argv) != 2:
+        print(__doc__.split("::")[1].strip().splitlines()[0], file=sys.stderr)
+        return 2
+    trace_dir, hlo_path = argv
+    traces = ([trace_dir] if os.path.isfile(trace_dir) else sorted(glob.glob(
+        os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)))
+    if not traces:
+        print(f"no .xplane.pb under {trace_dir}", file=sys.stderr)
+        return 1
+    with open(hlo_path) as fh:
+        mapped = scope_map(fh.read())
+    if not any(mapped.values()):
+        print(f"{hlo_path} names no listed scope: the executable was loaded "
+              "from a compile-cache entry an earlier build compiled (module "
+              "docstring)", file=sys.stderr)
+    by_scope = device_time_by_scope(traces[-1], mapped)
+    whole = sum(by_scope.values())
+    for scope, seconds in sorted(by_scope.items(), key=lambda kv: -kv[1]):
+        print(f"{scope or '(no scope)':24s} {seconds:12.6f} s "
+              f"{100.0 * seconds / whole if whole else 0.0:6.2f} %")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

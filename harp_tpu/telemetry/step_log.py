@@ -18,7 +18,9 @@ Events are one JSON object per training step::
 ``step_s`` is the chunk wall amortized over the chunk's steps when the chunk
 ran several iterations inside one compiled program (the honest per-step figure
 available without syncing inside the scan); a one-step chunk's ``step_s`` is
-a real per-step measurement. Events land in a bounded ring (oldest dropped
+a real per-step measurement: a count with a mean, not a span (the spans are
+:mod:`~harp_tpu.telemetry.host_spans`' phases, whose ``kind: "phase"`` events
+ride this stream). Events land in a bounded ring (oldest dropped
 first, drops counted) and flush as JSONL to ``<dir>/rank<r>/steps.jsonl`` at
 boundary cadence — never inside a step.
 """
@@ -27,7 +29,6 @@ from __future__ import annotations
 
 import atexit
 import collections
-import contextlib
 import json
 import os
 import threading
@@ -77,6 +78,7 @@ class StepLog:
         # is atomic under the GIL)
         self._flush_lock = threading.Lock()
         self._hooks: List[Callable[[int, "StepLog"], None]] = []
+        self._programs: set = set()     # step programs whose text is written
         self._rank_dir = os.path.join(directory, f"rank{self.rank}")
         os.makedirs(self._rank_dir, exist_ok=True)
         self.path = os.path.join(self._rank_dir, "steps.jsonl")
@@ -283,18 +285,19 @@ def record_timing(name: str, *, timer: Optional[str] = None,
     log.boundary()
 
 
-@contextlib.contextmanager
-def phase(name: str):
-    """Host phase timer (checkpoint save, data load, gang gather): records
-    into the bounded ``telemetry.phase.<name>`` timer when telemetry is on;
-    a plain no-op otherwise."""
+def record_program(name: str, step, args: Sequence) -> None:
+    """Write the compiled text of the step program ``name`` (``step`` is its
+    jitted callable, ``args`` what it was just dispatched with) to
+    ``<dir>/rank<r>/programs/<name>.hlo.txt``, once per log: the text whose
+    ``op_name``s :func:`harp_tpu.telemetry.scopes.scope_map` reads, so that a
+    profiler window opened on the running job can be read by kernel name.
+    Costs one load from the compile cache. No-op (one None check) when
+    telemetry is off."""
     log = active()
-    if log is None:
-        yield
+    if log is None or name in log._programs:
         return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        log.metrics.observe(f"telemetry.phase.{name}",
-                            time.perf_counter() - t0)
+    log._programs.add(name)
+    directory = os.path.join(log._rank_dir, "programs")
+    os.makedirs(directory, exist_ok=True)
+    with open(os.path.join(directory, name + ".hlo.txt"), "w") as f:
+        f.write(step.lower(*args).compile().as_text())

@@ -39,6 +39,23 @@ from typing import Optional
 XPROF_TAG = "harp.telemetry.xprof"
 
 
+def start_trace(log_dir: str) -> None:
+    """Open a ``jax.profiler`` capture into ``log_dir`` (a window spans host
+    loop boundaries, so it is opened and closed by two calls). Every
+    :func:`~harp_tpu.telemetry.host_spans.phase` inside it lies in the trace's
+    ``/host:CPU`` plane under its own name."""
+    import jax
+
+    jax.profiler.start_trace(log_dir)
+
+
+def stop_trace() -> None:
+    """Close the capture opened by :func:`start_trace`."""
+    import jax
+
+    jax.profiler.stop_trace()
+
+
 def request_xprof(session, steps: int, directory: str, *,
                   source: int = 0) -> None:
     """Arm an N-boundary profiler window on every rank (COLLECTIVE: all
@@ -130,20 +147,16 @@ class XprofController:
         return {"tag": XPROF_TAG, "steps": steps, "dir": out}
 
     def _start(self, req: dict) -> None:
-        from harp_tpu.utils import tracing
-
         self.trace_dir = os.path.join(req["dir"], f"rank{self.rank}")
         os.makedirs(self.trace_dir, exist_ok=True)
-        tracing.start_trace(self.trace_dir)
+        start_trace(self.trace_dir)
         self.remaining = max(1, int(req["steps"]))
         print(f"harp_tpu.telemetry: xprof window open (rank {self.rank}, "
               f"{self.remaining} boundaries) -> {self.trace_dir}",
               file=sys.stderr, flush=True)
 
     def _stop(self) -> None:
-        from harp_tpu.utils import tracing
-
-        tracing.stop_trace()
+        stop_trace()
         print(f"harp_tpu.telemetry: xprof window closed (rank {self.rank}) "
               f"-> {self.trace_dir}", file=sys.stderr, flush=True)
         self.remaining = 0

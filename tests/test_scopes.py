@@ -1,0 +1,251 @@
+"""Device scopes (ISSUE 25): the two step programs, compiled for a described
+``v5e:2x2`` at the benchmark cells' widths, carry a listed scope on every
+kernel of their loop bodies; ``scope_map`` reads the compiled text and
+``device_time_by_scope`` a recorded v5e trace.
+
+The topology is described inside a module fixture and nowhere else (the
+on-chip-measurement guide, section 2): only the worker that is handed this
+file loads the TPU's library. Nothing runs: a compile says nothing about time.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding
+
+from harp_tpu.models import kmeans, sgd_mf
+from harp_tpu.session import HarpSession
+from harp_tpu.telemetry import scopes
+
+TRACE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "benchmark",
+                     "data", "kmeans_v5e_1chip.xplane.pb")
+# what shows as a device event of its own and takes the time
+KERNELS = {"fusion", "convolution", "copy", "custom-call",
+           "dynamic-update-slice", "all-reduce", "all-gather",
+           "reduce-scatter", "collective-permute", "all-to-all"}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one: keep it off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _shaped(sess, shape, dtype, spec):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=NamedSharding(sess.mesh, spec))
+
+
+def _kmeans_text(topo, workers: int) -> str:
+    """The K-means step at the cell's widths (k 100, d 100 lane-padded to
+    128, ``highest`` products), the point count cut."""
+    sess = HarpSession(num_workers=workers, devices=topo.devices[:workers])
+    model = kmeans.KMeans(sess, kmeans.KMeansConfig(
+        num_centroids=100, dim=100, iterations=5))
+    points = _shaped(sess, (65536 * workers, 128), jnp.float32, sess.shard())
+    centroids = _shaped(sess, (100, 100), jnp.float32, sess.replicate())
+    with jax.default_matmul_precision("highest"):
+        return model._fit.lower(points, centroids).compile().as_text()
+
+
+def _sgdmf_text(topo, workers: int, rows: int = 1024) -> str:
+    """The dense SGD-MF step at rank 100 and ML-10M's columns per block,
+    the rows per worker cut."""
+    sess = HarpSession(num_workers=workers, devices=topo.devices[:workers])
+    model = sgd_mf.SGDMF(sess, sgd_mf.SGDMFConfig(
+        rank=100, lam=0.05, lr=1e-4, minibatches_per_hop=8, epochs=5))
+    nmb, cpb = 8, -(-10681 // workers)
+    key = model._program("dense", nmb, 5, (nmb, rows, cpb))
+    shard = sess.shard()
+    args = (_shaped(sess, (workers, workers, rows, cpb), jnp.bfloat16, shard),
+            _shaped(sess, (workers, workers, rows), jnp.float32, shard),
+            _shaped(sess, (workers, workers, nmb, cpb), jnp.float32, shard),
+            _shaped(sess, (workers * rows, 100), jnp.float32, shard),
+            _shaped(sess, (workers * cpb, 100), jnp.float32, shard))
+    return model._compiled[key].lower(*args).compile().as_text()
+
+
+def _loop_kernels(text: str):
+    """``(instruction, opcode)`` of the kernels that stand directly in a
+    ``while`` body of the compiled text, nested loops included."""
+    bodies = set(re.findall(r"body=%([\w.\-]+)", text))
+    out, computation = [], None
+    for line in text.splitlines():
+        m = scopes._INSTRUCTION.match(line)
+        if m is None:
+            c = scopes._COMPUTATION.match(line)
+            computation = c.group(1) if c else computation
+            continue
+        name, opcode = scopes._instruction(line.strip())
+        opcode = re.sub(r"-(start|done)$", "", opcode)
+        if computation in bodies and opcode in KERNELS:
+            out.append((name, opcode))
+    return out
+
+
+PROGRAMS = {
+    "kmeans-1": (lambda t: _kmeans_text(t, 1),
+                 {"kmeans.norms", "kmeans.scores", "kmeans.stats",
+                  "kmeans.update"}),
+    "kmeans-4": (lambda t: _kmeans_text(t, 4),
+                 {"kmeans.norms", "kmeans.scores", "kmeans.stats",
+                  "kmeans.update", "lax.allgather"}),
+    "sgdmf-1": (lambda t: _sgdmf_text(t, 1),
+                {"sgdmf.stripes", "sgdmf.rmse", "rotation.hop"}),
+    "sgdmf-4": (lambda t: _sgdmf_text(t, 4),
+                {"sgdmf.select", "sgdmf.stripes", "sgdmf.rmse",
+                 "rotation.hop"}),
+}
+
+
+@pytest.fixture(scope="module")
+def compiled(topo, no_compile_cache):
+    texts = {}
+
+    def get(program: str) -> str:
+        if program not in texts:
+            texts[program] = PROGRAMS[program][0](topo)
+        return texts[program]
+
+    return get
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_every_kernel_of_the_loop_bodies_has_a_listed_scope(compiled, program):
+    text = compiled(program)
+    mapped = scopes.scope_map(text)
+    kernels = _loop_kernels(text)
+    assert len(kernels) >= 5, kernels
+    bare = [(name, op) for name, op in kernels
+            if mapped[name] not in scopes.SCOPES]
+    assert not bare, bare
+    assert {op for _, op in kernels} >= {"fusion", "dynamic-update-slice"}
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_each_scope_the_program_reaches_is_present(compiled, program):
+    text = compiled(program)
+    loops = {scopes.scope_map(text)[name] for name, _ in _loop_kernels(text)}
+    everywhere = set(scopes.scope_map(text).values())
+    reached = PROGRAMS[program][1]
+    # the hoisted norms run once, before the loop; the rest inside it
+    assert reached - {"kmeans.norms"} <= loops, (reached, loops)
+    assert reached <= everywhere
+    if program.startswith("sgdmf"):
+        assert not any(s and s.startswith("kmeans") for s in everywhere)
+    # a ring of one picks its single block by a static index: no select
+    if program == "sgdmf-1":
+        assert "sgdmf.select" not in loops
+
+
+def test_the_ring_hop_is_a_collective_permute_under_its_own_name(compiled):
+    text = compiled("sgdmf-4")
+    mapped = scopes.scope_map(text)
+    hops = [name for name, op in _loop_kernels(text)
+            if op == "collective-permute"]
+    assert hops and {mapped[name] for name in hops} == {"rotation.hop"}
+
+
+def test_scope_map_gives_none_without_a_scope(compiled):
+    mapped = scopes.scope_map(compiled("sgdmf-4"))
+    # the program's argument, relaid at entry, is under no scope of its own
+    outside = [name for name, scope in mapped.items() if scope is None]
+    assert outside
+    assert scopes.scope_of("jit(fit_fn)/shard_map/while/body/add") is None
+    assert scopes.scope_of("jit(f)/kmeans.update/while/body/kmeans.stats/"
+                           "dot_general") == "kmeans.stats"
+
+
+def test_scope_map_reads_op_names_and_hands_names_to_what_the_compiler_made():
+    text = """HloModule m
+%body (p: (f32[8], f32[8])) -> (f32[8], f32[8]) {
+  %p = (f32[8], f32[8]) parameter(0)
+  %gte = f32[8] get-tuple-element(%p), index=0
+  %fusion.1 = f32[8] fusion(%gte), kind=kLoop, calls=%fc, metadata={op_name="jit(f)/kmeans.update/while/body/kmeans.stats/mul"}
+  %copy.1 = f32[8] copy(%fusion.1)
+  %copy.2 = f32[8] copy(%gte)
+  %add.3 = f32[8] add(%copy.2, %copy.2), metadata={op_name="jit(f)/while/body/add"}
+  ROOT %tuple = (f32[8], f32[8]) tuple(%copy.1, %add.3)
+}
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8] parameter(0)
+  %copy.9 = f32[8] copy(%a), metadata={op_name="args[0]"}
+  %while.1 = (f32[8], f32[8]) while(%t), condition=%cond, body=%body, metadata={op_name="jit(f)/kmeans.update/while"}
+  ROOT %out = f32[8] get-tuple-element(%while.1), index=0
+}
+"""
+    mapped = scopes.scope_map(text)
+    assert mapped["fusion.1"] == "kmeans.stats"         # deepest listed
+    assert mapped["while.1"] == "kmeans.update"
+    assert mapped["add.3"] is None                      # named, not listed
+    assert mapped["copy.9"] is None
+    assert mapped["copy.1"] == "kmeans.stats"           # reads fusion.1
+    # no neighbour with a scope: the loop it stands in names it
+    assert mapped["copy.2"] == "kmeans.update"
+
+
+def test_scoped_refuses_a_name_that_is_not_listed():
+    with pytest.raises(ValueError, match="SCOPES"):
+        scopes.scoped("kmeans.typo")
+    assert len(set(scopes.SCOPES)) == len(scopes.SCOPES)
+
+
+def test_device_time_by_scope_sums_to_the_ops_self_time():
+    from benchmark import trace_reduce
+
+    devices, _ = trace_reduce.read_planes(TRACE)
+    ops = devices[0].ops
+    own = trace_reduce._self_times(ops)          # by XLA's names
+    containers = {n for n in own if n.split(".")[0] == "while"}
+    assert containers
+    # XLA's names of the recorded PR 24 trace, mapped by hand
+    by_hand = {"fusion.19": "kmeans.scores",
+               "multiply_reduce_fusion.4": "kmeans.stats",
+               "fusion.20": "kmeans.stats",
+               "multiply_reduce_fusion.1": "kmeans.norms"}
+    got = scopes.device_time_by_scope(TRACE, by_hand)
+    kernels = sum(v for k, v in own.items() if k not in containers)
+    assert sum(got.values()) == pytest.approx(kernels, rel=1e-9)
+    assert got["kmeans.scores"] == pytest.approx(own["fusion.19"], rel=1e-9)
+    assert got["kmeans.stats"] == pytest.approx(
+        own["multiply_reduce_fusion.4"] + own["fusion.20"], rel=1e-9)
+    # a while is not counted again for its body: the whole is below the
+    # span from the first event to the last
+    span = max(e.end for e in ops) - min(e.start for e in ops)
+    assert sum(got.values()) < span
+    assert got[None] < 0.01 * sum(got.values())      # four kernels: 99 %
+    assert scopes.device_time_by_scope(TRACE, by_hand, device=1) == {}
+
+
+def test_the_command_prints_a_table(capsys, tmp_path):
+    hlo = tmp_path / "step.hlo.txt"
+    hlo.write_text('  %fusion.19 = f32[8] fusion(%x), kind=kLoop, calls=%f, '
+                   'metadata={op_name="jit(f)/kmeans.scores/dot_general"}\n')
+    assert scopes.main([TRACE, str(hlo)]) == 0
+    lines = capsys.readouterr().out.splitlines()
+    assert lines[0].split()[0] == "(no" or lines[0].startswith("kmeans.scores")
+    assert any(line.startswith("kmeans.scores") for line in lines)
+    assert scopes.main([str(tmp_path), str(hlo)]) == 1       # no trace there
+    assert scopes.main([]) == 2

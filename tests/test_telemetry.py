@@ -34,6 +34,12 @@ def _read_jsonl(path):
         return [json.loads(line) for line in f.read().strip().splitlines()]
 
 
+def _step_events(path):
+    """The per-step events of a steps.jsonl (phases, spans and timings ride
+    the same stream under a ``kind``)."""
+    return [e for e in _read_jsonl(path) if "kind" not in e]
+
+
 # --------------------------------------------------------------------------- #
 # Metrics: bounded reservoir + percentiles (satellite: unbounded-growth fix)
 # --------------------------------------------------------------------------- #
@@ -189,14 +195,259 @@ def test_flush_cadence_follows_the_boundary_interval(tmp_path):
     assert len(_read_jsonl(telemetry.active().path)) == 3
 
 
-def test_phase_timer_records_only_when_enabled(tmp_path):
+# --------------------------------------------------------------------------- #
+# Host phases (ISSUE 25): one span, always recorded, written only when on
+# --------------------------------------------------------------------------- #
+
+def _since():
+    return time.perf_counter()
+
+
+def test_phase_feeds_the_reservoir_only_when_enabled(tmp_path):
     with telemetry.phase("x.checkpoint"):
-        pass                               # disabled: pure no-op
+        pass                               # off: ring only, no reservoir
     m = Metrics()
     telemetry.configure(str(tmp_path), metrics=m)
     with telemetry.phase("x.checkpoint"):
         pass
     assert m.timing("telemetry.phase.x.checkpoint")["count"] == 1
+
+
+def test_phases_nest_and_name_their_parent():
+    t0 = _since()
+    with telemetry.phase("t.call") as root:
+        with telemetry.phase("t.dispatch") as a:
+            pass
+        with telemetry.phase("t.fetch") as b:
+            with telemetry.phase("t.inner"):
+                pass
+    recs = {r.name: r for r in telemetry.phases(t0)}
+    assert set(recs) == {"t.call", "t.dispatch", "t.fetch", "t.inner"}
+    assert recs["t.call"].parent is None
+    assert recs["t.dispatch"].parent == recs["t.fetch"].parent == root.id
+    assert recs["t.inner"].parent == b.id and a.id != b.id
+    # a child lies inside its parent, on time.perf_counter()
+    assert (t0 <= recs["t.call"].start <= recs["t.fetch"].start
+            <= recs["t.inner"].start <= recs["t.inner"].end
+            <= recs["t.fetch"].end <= recs["t.call"].end <= _since())
+    # children are handed to the ring as they end, before their parent
+    assert [r.name for r in telemetry.phases(t0)][-1] == "t.call"
+
+
+def test_the_spans_of_one_call_share_its_index():
+    t0 = _since()
+    for _ in range(2):
+        with telemetry.phase("t.call"):
+            with telemetry.phase("t.dispatch"):
+                pass
+            with telemetry.phase("t.fetch"):
+                pass
+    recs = telemetry.phases(t0)
+    calls = [r.call for r in recs if r.name == "t.call"]
+    assert len(calls) == 2 and calls[1] == calls[0] + 1
+    for call in calls:
+        assert sorted(r.name for r in recs if r.call == call) == [
+            "t.call", "t.dispatch", "t.fetch"]
+
+
+def test_phase_survives_an_exception_and_restores_its_parent():
+    t0 = _since()
+    with telemetry.phase("t.outer") as outer:
+        with pytest.raises(RuntimeError):
+            with telemetry.phase("t.boom"):
+                raise RuntimeError("boom")
+        with telemetry.phase("t.after"):
+            pass
+    recs = {r.name: r for r in telemetry.phases(t0)}
+    assert recs["t.boom"].parent == recs["t.after"].parent == outer.id
+
+
+def test_self_seconds_is_the_span_less_its_children():
+    R = telemetry.PhaseRecord
+    records = [R("child", 1.0, 2.0, 7, 0, 8), R("child", 2.5, 3.0, 7, 0, 9),
+               R("grandchild", 1.2, 1.4, 8, 0, 10),
+               R("root", 0.0, 4.0, None, 0, 7),
+               R("root", 10.0, 11.0, None, 1, 11)]
+    assert telemetry.self_seconds(records, "root") == pytest.approx(3.5)
+    assert telemetry.self_seconds(records, "child") == pytest.approx(1.3)
+    assert telemetry.self_seconds(records, "absent") == 0.0
+    t0 = _since()
+    with telemetry.phase("t.root"):
+        with telemetry.phase("t.child"):
+            time.sleep(0.02)
+    recs = telemetry.phases(t0)
+    root = next(r for r in recs if r.name == "t.root")
+    own = telemetry.self_seconds(recs, "t.root")
+    assert 0.0 <= own < 0.01 < root.end - root.start
+
+
+def test_phase_ring_is_bounded_and_counts_what_it_drops():
+    from harp_tpu.telemetry import host_spans
+
+    ring = host_spans._Ring(8)
+    for i in range(20):
+        ring.append(telemetry.PhaseRecord("x", 0.0, 1.0, None, i, i))
+    assert [r.id for r in ring.snapshot()] == list(range(12, 20))
+    assert ring.dropped == 12
+    # the process's own ring: bounded at its capacity, drops counted
+    before = host_spans.dropped()
+    held = len(telemetry.phases())
+    for _ in range(host_spans.RING_CAPACITY + 10):
+        with telemetry.phase("t.fill"):
+            pass
+    assert len(telemetry.phases()) == host_spans.RING_CAPACITY
+    assert host_spans.dropped() - before == held + 10
+
+
+def test_phase_ring_loses_nothing_under_contention():
+    import threading
+
+    from harp_tpu.telemetry import host_spans
+
+    ring = host_spans._Ring(64)
+    threads = [threading.Thread(target=lambda: [ring.append(
+        telemetry.PhaseRecord("x", 0.0, 1.0, None, 0, 0))
+        for _ in range(2000)]) for _ in range(16)]
+    before = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(before)
+    assert not any(t.is_alive() for t in threads)
+    assert len(ring.snapshot()) + ring.dropped == 16 * 2000
+
+
+def test_phases_are_per_thread():
+    import threading
+
+    t0 = _since()
+    seen = {}
+
+    def other():
+        with telemetry.phase("t.thread") as p:
+            seen["parent"] = p._parent
+
+    with telemetry.phase("t.main"):
+        th = threading.Thread(target=other)
+        th.start()
+        th.join(timeout=30)
+    assert not th.is_alive() and seen["parent"] is None
+    rec = next(r for r in telemetry.phases(t0) if r.name == "t.thread")
+    assert rec.parent is None
+
+
+def test_phase_shows_under_its_name_in_a_profiler_trace(tmp_path):
+    """What test_tracing.py checked of ``annotate``: inside a profiler
+    session a phase is a span of the trace's ``/host:CPU`` plane."""
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from harp_tpu.telemetry import xprof
+
+    d = str(tmp_path / "trace")
+    xprof.start_trace(d)
+    try:
+        with telemetry.phase("harp-test-phase"):
+            jnp.sum(jnp.ones(16)).block_until_ready()
+    finally:
+        xprof.stop_trace()
+    found = [os.path.join(r, f) for r, _, fs in os.walk(d) for f in fs
+             if f.endswith(".xplane.pb")]
+    assert found, f"no trace under {d}"
+    host = [p for p in ProfileData.from_file(found[0]).planes
+            if p.name == "/host:CPU"]
+    names = {e.name for p in host for line in p.lines for e in line.events}
+    assert "harp-test-phase" in names
+    # the window closes cleanly: a second capture opens
+    xprof.start_trace(str(tmp_path / "again"))
+    xprof.stop_trace()
+
+
+def test_phases_off_write_no_file(session, rng, tmp_path, monkeypatch):
+    from harp_tpu.models import kmeans as km
+
+    monkeypatch.chdir(tmp_path)
+    t0 = _since()
+    model = km.KMeans(session, km.KMeansConfig(num_centroids=4, dim=8,
+                                               iterations=2))
+    pts = rng.normal(size=(64, 8)).astype(np.float32)
+    p, c = model.prepare(pts, pts[:4])
+    np.asarray(model.fit_prepared(p, c)[1])
+    names = [r.name for r in telemetry.phases(t0)]
+    assert names.count("kmeans.prepare") == 1
+    assert names.count("session.place") == 2
+    assert names.count("kmeans.call") == names.count("step.dispatch") == 1
+    assert telemetry.active() is None
+    assert os.listdir(tmp_path) == []
+
+
+def test_phases_on_emit_phase_events_and_the_programs_text(
+        session, rng, tmp_path):
+    from harp_tpu.models import sgd_mf
+    from harp_tpu.telemetry import scopes
+
+    telemetry.configure(str(tmp_path), interval=1, metrics=Metrics())
+    n = 600
+    model = sgd_mf.SGDMF(session, sgd_mf.SGDMFConfig(rank=4, epochs=2))
+    state = model.prepare(rng.integers(0, 64, n), rng.integers(0, 48, n),
+                          rng.normal(size=n).astype(np.float32), 64, 48)
+    model.train_prepared(state)
+    model.train_prepared(state)
+    telemetry.disable()
+    events = [e for e in _read_jsonl(tmp_path / "rank0" / "steps.jsonl")
+              if e.get("kind") == "phase"]
+    by_name = {}
+    for e in events:
+        by_name.setdefault(e["name"], []).append(e)
+        assert {"v", "rank", "name", "start", "end", "parent", "call",
+                "id"} <= set(e)
+    assert len(by_name["sgd_mf.prepare"]) == 1
+    assert len(by_name["session.run"]) == 1
+    assert len(by_name["sgd_mf.call"]) == 2
+    assert len(by_name["step.dispatch"]) == len(by_name["step.fetch"]) == 2
+    call = by_name["sgd_mf.call"][0]
+    assert {e["name"] for e in events if e["parent"] == call["id"]} >= {
+        "step.dispatch", "step.fetch"}
+    assert {e["detail"] for e in by_name["program.trace"]} == {
+        "sgd_mf.densify", "sgd_mf.fit"}
+    # the step's compiled text, once, readable by scope
+    programs = os.listdir(tmp_path / "rank0" / "programs")
+    assert programs == ["sgd_mf.fit.hlo.txt"]
+    mapped = scopes.scope_map(
+        (tmp_path / "rank0" / "programs" / programs[0]).read_text())
+    assert "sgdmf.stripes" in set(mapped.values())
+
+
+def test_program_traces_count_traces_not_calls(session, rng):
+    from harp_tpu.models import kmeans as km
+    from harp_tpu.utils.metrics import DEFAULT
+
+    def traces():
+        return DEFAULT.snapshot()["counters"].get(
+            "program.traces.kmeans.fit", 0)
+
+    model = km.KMeans(session, km.KMeansConfig(num_centroids=4, dim=8,
+                                               iterations=2))
+    pts = rng.normal(size=(128, 8)).astype(np.float32)
+    before, t0 = traces(), _since()
+    p, c = model.prepare(pts, pts[:4])
+    model.fit_prepared(p, c)
+    model.fit_prepared(p, c)
+    assert traces() - before == 1          # two calls of one shape
+    p2, c2 = model.prepare(pts[:64], pts[:4])
+    np.asarray(model.fit_prepared(p2, c2)[1])
+    assert traces() - before == 2          # a new shape traces again
+    marks = [r for r in telemetry.phases(t0) if r.name == "program.trace"]
+    assert [r.detail for r in marks] == ["kmeans.fit", "kmeans.fit"]
+    assert all(r.start == r.end for r in marks)
+    # the mark lies in the dispatch that traced
+    dispatch = {r.id for r in telemetry.phases(t0)
+                if r.name == "step.dispatch"}
+    assert all(r.parent in dispatch for r in marks)
 
 
 # --------------------------------------------------------------------------- #
@@ -437,7 +688,7 @@ def test_kmeans_fit_checkpointed_emits_telemetry_and_stays_bitwise(
     np.testing.assert_array_equal(np.asarray(cen_off), np.asarray(cen_on))
     np.testing.assert_array_equal(costs_off, costs_on)
 
-    events = _read_jsonl(tmp_path / "tele" / "rank0" / "steps.jsonl")
+    events = _step_events(tmp_path / "tele" / "rank0" / "steps.jsonl")
     assert [e["step"] for e in events] == [0, 1, 2, 3]
     assert [e["loss"] for e in events] == pytest.approx(costs_on.tolist())
     assert all(e["model"] == "kmeans" and e["comm"] == cfg.comm
@@ -469,7 +720,7 @@ def test_lda_and_nn_fits_emit_per_epoch_events(session, rng, tmp_path):
                                                   epochs=2))
     losses = clf.fit(x, y, seed=0)
     telemetry.disable()
-    events = _read_jsonl(tmp_path / "rank0" / "steps.jsonl")
+    events = _step_events(tmp_path / "rank0" / "steps.jsonl")
     by_model = {}
     for e in events:
         by_model.setdefault(e["model"], []).append(e)
