@@ -27,7 +27,8 @@ under ``chiprun_out/chip_smoke/``.
 Legs (main path first):
   device     jax sees a TPU; wall of an already-compiled trivial dispatch
   kmeans     harp_tpu.run kmeans, n=1M k=100 d=100 f32, 20 iterations
-  sgd_mf     harp_tpu.run sgd_mf, 32768x32768 density 0.01 rank 32 — and the
+  sgd_mf     harp_tpu.run sgd_mf, 32768x32768 density 0.01 rank 32, and
+             7157x1069 rank 100 (MovieLens-10M's geometry, on no tile): each
              compiled program contains the Mosaic call (the Pallas hop ran)
   kernels    flash attention L=16384 H=8 causal (Dh=64 packed, Dh=128) and
              the rank-32 SPD solve: Mosaic in the compiled text, results
@@ -64,7 +65,12 @@ PLATFORM = "tpu"          # what every leg must find
 # the sizes the repo calls its flagship (bench.py tpu_kmeans / tpu_sgd_mf,
 # README "Performance"); the restart leg runs reduced
 KMEANS = {"n": 1_000_000, "k": 100, "d": 100, "iterations": 20}
-SGD_MF = {"n": 32768, "density": 0.01, "rank": 32, "nmb": 8, "epochs": 3}
+SGD_MF = {"users": 32768, "items": 32768, "density": 0.01, "rank": 32,
+          "nmb": 8, "epochs": 3}
+# MovieLens-10M's geometry at a tenth of its rows and columns: no stripe
+# (895 rows), block (1069 columns) or rank on a tile of the fused hop kernel
+SGD_MF_UNALIGNED = {"users": 7157, "items": 1069, "density": 0.05,
+                    "rank": 100, "nmb": 8, "epochs": 3}
 RESTART = {"n": 100_000, "k": 100, "d": 100, "iterations": 6, "crash_at": 3}
 FLASH = {"l": 16384, "h": 8}
 # ring attention block length per chip: below and at the flash crossover
@@ -236,8 +242,9 @@ def _kmeans_argv(c: dict, *extra) -> list:
 
 
 def _sgd_mf_argv(c: dict, *extra) -> list:
-    return ["sgd_mf", "--num-users", str(c["n"]), "--num-items", str(c["n"]),
-            "--density", str(c["density"]), "--rank", str(c["rank"]),
+    return ["sgd_mf", "--num-users", str(c["users"]), "--num-items",
+            str(c["items"]), "--density", str(c["density"]), "--rank",
+            str(c["rank"]),
             "--minibatches-per-hop", str(c["nmb"]), "--epochs",
             str(c["epochs"]), *extra]
 
@@ -309,35 +316,44 @@ def leg_kmeans() -> dict:
 def leg_sgd_mf() -> dict:
     import numpy as np
 
-    info = _device("sgd_mf")
-    stats = _CompileStats()
-    c = SGD_MF
-    text = _cli(_sgd_mf_argv(c))
-    if "sgd_mf[dense]" not in text:
-        raise SystemExit("sgd_mf did not take the dense masked-stripe layout")
-    first, last = _first_last(text, "rmse")
-    _finite(first, last)
-    if not last < first:
-        raise SystemExit(f"SGD-MF RMSE did not fall: {first} -> {last}")
-    # the program the launcher just ran, rebuilt at the same geometry and
-    # config (its shapes do not depend on the ratings, so a handful
-    # suffice) and read back as compiled text
     from harp_tpu.aot import hlo_audit
     from harp_tpu.models import sgd_mf
     from harp_tpu.session import HarpSession
 
-    sess = HarpSession()
-    model = sgd_mf.SGDMF(sess, sgd_mf.SGDMFConfig(
-        rank=c["rank"], minibatches_per_hop=c["nmb"], epochs=c["epochs"]))
-    ids = np.arange(64, dtype=np.int64)
-    layout, data, w0, h0, meta = model.prepare(
-        ids, ids, np.ones(64, np.float32), c["n"], c["n"])
-    key = model._program(layout, c["nmb"], c["epochs"], meta[6])
-    _assert_mosaic(hlo_audit.lower_fn_text(model._compiled[key],
-                                           (*data, w0, h0)),
-                   "sgd_mf dense hop")
-    return {"device": info, "rmse": [first, last], "mosaic": True,
-            "cache": stats.row()}
+    info = _device("sgd_mf")
+    stats = _CompileStats()
+    out = {"device": info}
+    for name, c in (("aligned", SGD_MF), ("unaligned", SGD_MF_UNALIGNED)):
+        text = _cli(_sgd_mf_argv(c))
+        if "sgd_mf[dense]" not in text:
+            raise SystemExit(f"sgd_mf ({name}) did not take the dense "
+                             "masked-stripe layout")
+        first, last = _first_last(text, "rmse")
+        _finite(first, last)
+        if not last < first:
+            raise SystemExit(f"SGD-MF ({name}) RMSE did not fall: "
+                             f"{first} -> {last}")
+        # the program the launcher just ran, rebuilt at the same geometry
+        # and config (its shapes do not depend on the ratings, so a handful
+        # suffice) and read back as compiled text
+        model = sgd_mf.SGDMF(HarpSession(), sgd_mf.SGDMFConfig(
+            rank=c["rank"], minibatches_per_hop=c["nmb"], epochs=c["epochs"]))
+        ids = np.arange(64, dtype=np.int64)
+        layout, data, w0, h0, meta = model.prepare(
+            ids, ids, np.ones(64, np.float32), c["users"], c["items"])
+        key = model._program(layout, c["nmb"], c["epochs"], meta[6])
+        _assert_mosaic(hlo_audit.lower_fn_text(model._compiled[key],
+                                               (*data, w0, h0)),
+                       f"sgd_mf dense hop ({name})")
+        layout_stats = model.last_layout_stats
+        if not layout_stats["fused_hop"]:
+            raise SystemExit(f"sgd_mf ({name}): last_layout_stats says the "
+                             f"XLA stripe scan runs: {layout_stats}")
+        out[name] = {"rmse": [first, last], "mosaic": True,
+                     "col_tile": layout_stats["col_tile"],
+                     "pad_overhead": layout_stats["pad_overhead"]}
+    out["cache"] = stats.row()
+    return out
 
 
 def leg_kernels() -> dict:
@@ -705,6 +721,37 @@ def leg_multichip_ring() -> dict:
          what="ring_attention_mha[fused hop, XLA blocks]")
     pair(mha(True), mha(False), *qkv(4 * RING_BLOCK["flash"], 8, 64),
          what="ring_attention_mha[hop fused into flash]")
+
+    # the dense SGD-MF hop kernel ships the updated H block from its own
+    # epilogue (fused_dma=True), at a shape no tile divides: the factors it
+    # returns equal the ppermute schedule's bit for bit, and the compiled
+    # program holds no collective-permute
+    from harp_tpu.io import datagen
+    from harp_tpu.models import sgd_mf
+
+    c = SGD_MF_UNALIGNED
+    users, items = 2 * c["users"], 2 * c["items"]
+    rows, cols, vals = datagen.sparse_ratings(users, items, rank=8,
+                                              density=0.02, seed=1)
+    factors = {}
+    for fused in (False, True):
+        model = sgd_mf.SGDMF(sess, sgd_mf.SGDMFConfig(
+            rank=c["rank"], lr=1e-3, epochs=c["epochs"],
+            minibatches_per_hop=c["nmb"], fused_dma=fused))
+        layout, data, w0, h0, meta = state = model.prepare(
+            rows, cols, vals, users, items, seed=3)
+        key = model._program(layout, c["nmb"], c["epochs"], meta[6])
+        text = model._compiled[key].lower(*data, w0, h0).compile().as_text()
+        _assert_mosaic(text, f"sgd_mf dense hop, fused_dma={fused}")
+        if fused == ("collective-permute" in text):
+            raise SystemExit(f"sgd_mf fused_dma={fused}: the compiled "
+                             "program's ring hop is not where it should be")
+        factors[fused] = model.fit_prepared(state)
+    if not all(a.tobytes() == b.tobytes()
+               for a, b in zip(factors[False], factors[True])):
+        raise SystemExit("sgd_mf in-kernel ring hop: factors differ from "
+                         "the ppermute schedule's")
+    out["sgd_mf[hop fused into the dense kernel]"] = "bitwise == ppermute"
     out["cache"] = stats.row()
     return out
 

@@ -41,7 +41,13 @@ Two data layouts, selected by density (``SGDMFConfig.layout``):
   host-side) — but the slab stores ratings in bf16 (~8-bit mantissa), so
   values/residuals are quantized: the two layouts are convergence-equivalent,
   not bit-identical. Input NaN values are rejected at validation — NaN is the
-  missing-entry sentinel.
+  missing-entry sentinel. The slab and the factors are STORED with every
+  stripe, column block and the rank padded to the fused hop kernel's tiles
+  (:class:`DenseGeometry`: the mini-batches, the first model and every table
+  that leaves the device keep the logical sizes), so that on TPU a hop is
+  ``pallas_kernels.dense_mf_hop_pallas``' single pass over the slab; where
+  no tile fits VMEM, and off the TPU, the XLA stripe scan runs on the same
+  arrays.
 * **sparse** (padded COO buckets): for data too sparse/large to densify. Ratings
   are pre-sorted on the host into a (W workers × B column-blocks) grid of padded
   COO buckets; the inner loop is gather → rank-K dot → two scatter-adds. Hot
@@ -75,6 +81,7 @@ from harp_tpu.ops import lane_pack, pallas_kernels
 from harp_tpu.parallel.mesh import fetch
 from harp_tpu.session import HarpSession
 from harp_tpu.telemetry.scopes import scoped
+from harp_tpu.utils import metrics
 
 
 @dataclasses.dataclass(frozen=True)
@@ -244,6 +251,60 @@ def bucketize(
     return r_idx, c_idx, val, mask, rpw, cpb
 
 
+@dataclasses.dataclass(frozen=True)
+class DenseGeometry:
+    """The dense layout's two geometries (``meta[6]`` of a dense state).
+
+    **Logical** is the job: a worker's ``rpw = nmb * s_rows`` rows in ``nmb``
+    stripes, column blocks of ``cpb``, ``rank`` factor columns. The
+    mini-batches (one stripe x block tile each), the first model's draw,
+    ``meta``'s ``rpw``/``cpb`` and id maps, ``_finalize``'s tables and a
+    checkpoint's tables are all logical.
+
+    **Stored** is what the device arrays hold, and only ``_prepare_dense``,
+    the step program and the two conversions (``_to_logical``/``_to_stored``,
+    ``_stored_maps``) speak it: every stripe padded at its end to
+    ``s_store`` rows, every block to ``cpb_store`` columns, the factors to
+    ``rank_store`` columns — the fused hop kernel's tiles
+    (``pallas_kernels.dense_mf_col_tile``). Pad cells are NaN (missing), pad
+    rows' and columns' counts 0, pad factor entries 0: they take no gradient
+    and no regulariser, add nothing to a product or to the RMSE, and stay 0.
+    Stripes are padded at the finest granularity (``nmb``), so a coarser
+    budget of ``fit_adaptive`` merges whole stored stripes."""
+
+    nmb: int
+    s_rows: int
+    cpb: int
+    rank: int
+    s_store: int
+    cpb_store: int
+    rank_store: int
+
+    @property
+    def rpw(self) -> int:
+        return self.nmb * self.s_rows
+
+    @property
+    def rpw_store(self) -> int:
+        return self.nmb * self.s_store
+
+    def store_row(self, r_loc):
+        """Stored row (within a worker) of logical row ``r_loc``."""
+        return r_loc // self.s_rows * self.s_store + r_loc % self.s_rows
+
+
+def _repad(a: np.ndarray, rows: int, rows_to: int, k_to: int) -> np.ndarray:
+    """``(..., n * rows, K)`` -> ``(..., n * rows_to, k_to)``: every run of
+    ``rows`` rows cut or zero-padded at its end to ``rows_to``, the last
+    axis to ``k_to``."""
+    a = np.asarray(a)
+    runs = a.reshape(-1, rows, a.shape[-1])
+    out = np.zeros((len(runs), rows_to, k_to), a.dtype)
+    r, k = min(rows, rows_to), min(a.shape[-1], k_to)
+    out[:, :r, :k] = runs[:, :r, :k]
+    return out.reshape(*a.shape[:-2], -1, k_to)
+
+
 # --------------------------------------------------------------------------- #
 # Model
 # --------------------------------------------------------------------------- #
@@ -391,20 +452,21 @@ class SGDMF:
 
     # -- dense (masked stripe-GEMM) program ------------------------------------ #
 
-    def _build_dense(self, w: int, nmb: int, nmb_fine: int, rpw: int,
-                     cpb: int, epochs: int):
+    def _hop_tile(self, g: DenseGeometry, nmb: int) -> int:
+        """Column tile of the fused hop kernel for the budget ``nmb`` over
+        the stored arrays; 0 = the XLA stripe scan runs on them."""
+        shape = (g.cpb_store, g.rpw_store // nmb, g.rank_store)
+        return (pallas_kernels.dense_mf_col_tile(*shape)
+                if pallas_kernels.use_dense_mf_pallas(*shape) else 0)
+
+    def _build_dense(self, w: int, nmb: int, g: DenseGeometry, epochs: int):
         lr, lam = self.config.lr, self.config.lam
+        # the program runs on the STORED geometry throughout
+        nmb_fine, rpw, cpb = g.nmb, g.rpw_store, g.cpb_store
         s_rows = rpw // nmb
         bf = jnp.bfloat16
-        # dense-stripe tiling rides the shared lane engine's constant:
-        # a fused-hop column tile must be a whole number of 128-lane
-        # MXU tiles AND divide the column block
-        col_tile = next((ct for ct in (4 * lane_pack.LANES,
-                                       2 * lane_pack.LANES,
-                                       lane_pack.LANES)
-                         if cpb % ct == 0), 0)
-        fused = col_tile and pallas_kernels.use_dense_mf_pallas(
-            cpb, s_rows, self.config.rank)
+        col_tile = self._hop_tile(g, nmb)
+        fused = col_tile > 0
         # in-kernel ring hop (r10): fused dense kernel + fused_dma + a plain
         # (unquantized) multi-worker wire (quant takes the encode path) on
         # the 1-slice schedule ONLY — the kernel's blocking send+wait would
@@ -483,6 +545,10 @@ class SGDMF:
                 return w_new.reshape(rpw, -1), h_block, sse, cnt
 
             def update_bucket(w_local, h_block, sse, cnt, bucket_id):
+                # runs when jax traces, only: which update this program's
+                # hops run
+                metrics.DEFAULT.count(
+                    "sgd_mf.hops.fused" if fused else "sgd_mf.hops.xla")
                 with jax.named_scope("sgdmf.select"):
                     if v_slab.shape[0] == 1:
                         # single-block mesh (W=1, 1 slice): static index —
@@ -526,34 +592,40 @@ class SGDMF:
                 self._compiled[key] = self._build_sparse(
                     w, nmb, m_total // nmb, epochs)
         else:
-            nmb_fine, rpw, cpb = geom
-            if nmb_fine % nmb:
-                raise ValueError(f"budget {nmb} does not divide band {nmb_fine}")
-            key = ("dense", w, nmb, nmb_fine, rpw, cpb,
-                   self.config.num_slices, epochs)
+            if geom.nmb % nmb:
+                raise ValueError(f"budget {nmb} does not divide band {geom.nmb}")
+            key = ("dense", w, nmb, geom, self.config.num_slices, epochs)
             if key not in self._compiled:
-                self._compiled[key] = self._build_dense(
-                    w, nmb, nmb_fine, rpw, cpb, epochs)
+                self._compiled[key] = self._build_dense(w, nmb, geom, epochs)
         return key
 
     # -- preparation ----------------------------------------------------------- #
 
     def _dense_geometry(self, num_rows: int, num_cols: int
-                        ) -> Tuple[int, int, int]:
+                        ) -> Tuple[DenseGeometry, int]:
         w = self.session.num_workers
         n_blocks = self.config.num_slices * w
         nmb = self.config.minibatches_per_hop
-        rpw = -(-num_rows // w)
-        rpw = -(-rpw // nmb) * nmb          # stripes must split evenly
+        s_rows = -(-(-(-num_rows // w)) // nmb)   # stripes must split evenly
         cpb = -(-num_cols // n_blocks)
-        return rpw, cpb, n_blocks
+        rank = self.config.rank
+        # stored sizes follow the fused hop's tiles on every backend: a
+        # stripe's rows ride the 128 lanes of the transposed W blocks, the
+        # rank their 8 sublanes; a block is a whole number of 256-column
+        # tiles, since a hop at 128-column tiles takes a third longer than
+        # at 256 and 512 gains nothing on 256 (PERF.md, Findings, PR 26)
+        return DenseGeometry(
+            nmb, s_rows, cpb, rank,
+            s_store=lane_pack.round_up(s_rows, lane_pack.LANES),
+            cpb_store=lane_pack.round_up(cpb, 2 * lane_pack.LANES),
+            rank_store=lane_pack.round_up(rank, 8)), n_blocks
 
     def _choose_layout(self, num_rows: int, num_cols: int) -> str:
         cfg = self.config
         if cfg.layout in ("dense", "sparse"):
             return cfg.layout
-        rpw, cpb, n_blocks = self._dense_geometry(num_rows, num_cols)
-        slab_elems = rpw * cpb * n_blocks
+        g, n_blocks = self._dense_geometry(num_rows, num_cols)
+        slab_elems = g.rpw_store * g.cpb_store * n_blocks
         # budget densify's PEAK: the NaN-encoded bf16 value slab plus the
         # transient bf16 mask slab alive at the same time (4 B/elem total);
         # and the int32 scatter-index limit must hold for auto to pick dense
@@ -641,16 +713,19 @@ class SGDMF:
         sess = self.session
         w = sess.num_workers
         nmb = cfg.minibatches_per_hop
-        rpw, cpb, n_blocks = self._dense_geometry(num_rows, num_cols)
+        g, n_blocks = self._dense_geometry(num_rows, num_cols)
+        rpw, cpb = g.rpw, g.cpb
         row_assign = identity_assign(w * rpw, w)
         col_assign = identity_assign(num_cols, n_blocks)
 
+        # who holds a rating is logical; where it lies there is stored
         owner = rows // rpw
-        r_loc = rows % rpw
+        r_loc = g.store_row(rows % rpw)
         block = cols // cpb
         c_loc = cols % cpb
+        rpw_st, cpb_st = g.rpw_store, g.cpb_store
         # flat slab index within a worker: ((b * rpw) + r) * cpb + c
-        flat = (block.astype(np.int64) * rpw + r_loc) * cpb + c_loc
+        flat = (block.astype(np.int64) * rpw_st + r_loc) * cpb_st + c_loc
 
         # group per worker, pad to a common capacity for the SPMD densify
         order = np.argsort(owner, kind="stable")
@@ -667,7 +742,7 @@ class SGDMF:
             val_p[wi, :hi - lo] = vo[lo:hi]
             msk_p[wi, :hi - lo] = 1.0
 
-        slab_elems = n_blocks * rpw * cpb
+        slab_elems = n_blocks * rpw_st * cpb_st
         if slab_elems >= 2 ** 31:
             # device indices are int32 (jax x64 off): a bigger slab would
             # silently wrap and drop entries in the scatter
@@ -687,7 +762,7 @@ class SGDMF:
                 (val * msk).astype(bf))
             m = jnp.zeros((slab_elems,), bf).at[idx].add(msk.astype(bf))
             v = jnp.where(m > 0, v, jnp.asarray(jnp.nan, bf))
-            return v.reshape((1, n_blocks, rpw, cpb))
+            return v.reshape((1, n_blocks, rpw_st, cpb_st))
 
         # one-shot prepare-time program, routed through session.run — the
         # documented build-and-invoke-once entry point (jaxlint JL103). It
@@ -703,29 +778,68 @@ class SGDMF:
 
         # regularizer counts (host): per-(worker, block, row) and
         # per-(worker, block, stripe, col)
-        s_rows = rpw // nmb
         wb = owner.astype(np.int64) * n_blocks + block
-        row_cnt = np.bincount(wb * rpw + r_loc,
-                              minlength=w * n_blocks * rpw
-                              ).reshape(w, n_blocks, rpw).astype(np.float32)
-        stripe = r_loc // s_rows
-        col_cnt = np.bincount((wb * nmb + stripe) * cpb + c_loc,
-                              minlength=w * n_blocks * nmb * cpb
-                              ).reshape(w, n_blocks, nmb, cpb
+        row_cnt = np.bincount(wb * rpw_st + r_loc,
+                              minlength=w * n_blocks * rpw_st
+                              ).reshape(w, n_blocks, rpw_st
+                                        ).astype(np.float32)
+        stripe = r_loc // g.s_store
+        col_cnt = np.bincount((wb * nmb + stripe) * cpb_st + c_loc,
+                              minlength=w * n_blocks * nmb * cpb_st
+                              ).reshape(w, n_blocks, nmb, cpb_st
                                         ).astype(np.float32)
 
+        col_tile = self._hop_tile(g, nmb)
         self.last_layout_stats = {
             "layout": "dense", "padded": int(w) * slab_elems,
             "nnz": len(vals), "overhead": w * slab_elems / max(len(vals), 1),
+            # stored over logical slab cells, and which update the hops of
+            # the configured budget run
+            "pad_overhead": rpw_st * cpb_st / (rpw * cpb),
+            "fused_hop": col_tile > 0, "col_tile": col_tile,
         }
-        geom = (nmb, rpw, cpb)
 
+        # the first model is drawn at the LOGICAL sizes (what the
+        # configuration's `init` states), then embedded
         rng = np.random.default_rng(seed)
-        w0, h0 = self._init_factors(rng, w * rpw, n_blocks * cpb)
+        meta = (num_rows, num_cols, row_assign, col_assign, rpw, cpb, g)
+        w0, h0 = self._to_stored(
+            *self._init_factors(rng, w * rpw, n_blocks * cpb), meta)
         return ("dense",
                 (v_slab, sess.scatter(row_cnt), sess.scatter(col_cnt)),
-                sess.scatter(w0), self._place_h0(h0, w, cpb),
-                (num_rows, num_cols, row_assign, col_assign, rpw, cpb, geom))
+                sess.scatter(w0), self._place_h0(h0, w, cpb_st), meta)
+
+    # -- logical <-> stored (DenseGeometry; the sparse layout has one) --------- #
+
+    @staticmethod
+    def _to_logical(w_tab, h_tab, meta):
+        """Host factor tables as the device holds them -> the logical tables
+        (``(W * rpw, rank)``, H in its fetched shape with ``cpb`` rows a
+        block)."""
+        g = meta[6]
+        if not isinstance(g, DenseGeometry):
+            return w_tab, h_tab
+        return (_repad(w_tab, g.s_store, g.s_rows, g.rank),
+                _repad(h_tab, g.cpb_store, g.cpb, g.rank))
+
+    @staticmethod
+    def _to_stored(w_tab, h_tab, meta):
+        """Logical host factor tables -> what the device holds."""
+        g = meta[6]
+        if not isinstance(g, DenseGeometry):
+            return w_tab, h_tab
+        return (_repad(w_tab, g.s_rows, g.s_store, g.rank_store),
+                _repad(h_tab, g.cpb, g.cpb_store, g.rank_store))
+
+    @staticmethod
+    def _stored_maps(meta):
+        """``(row_assign, col_assign, rpw, cpb)`` addressing the arrays on
+        the device (``meta[2:6]`` address the logical tables)."""
+        row_assign, col_assign, rpw, cpb, g = meta[2:7]
+        if not isinstance(g, DenseGeometry):
+            return row_assign, col_assign, rpw, cpb
+        return ((row_assign[0], g.store_row(row_assign[1])), col_assign,
+                g.rpw_store, g.cpb_store)
 
     # -- training -------------------------------------------------------------- #
 
@@ -733,8 +847,8 @@ class SGDMF:
         """Device factor blocks → (num_rows, K)/(num_cols, K) in original id
         order (undo the worker/block permutation)."""
         num_rows, num_cols, row_assign, col_assign, rpw, cpb = meta[:6]
-        out_w = fetch(out_w)         # gathers sharded blocks across a gang
-        out_h = fetch(out_h)
+        # fetch gathers sharded blocks across a gang
+        out_w, out_h = self._to_logical(fetch(out_w), fetch(out_h), meta)
         if self.config.num_slices == 2:
             # (W, 2, cpb, K) worker-major → block-id-major (2W*cpb, K)
             w_, _, cpb_, k = out_h.shape
@@ -891,8 +1005,11 @@ class SGDMF:
         # them through the legacy template so same-world resume of an old
         # work dir keeps working (a world CHANGE on one raises the clear
         # no-metadata error in _repartition_saved)
-        legacy_like = {"w": np.zeros(w0.shape, w0.dtype),
-                       "h": np.zeros(h0.shape, h0.dtype)}
+        # (a checkpoint holds the LOGICAL tables: stored padding stays on
+        # the device, so a step reads the same under any layout)
+        w_like, h_like = self._to_logical(
+            np.zeros(w0.shape, w0.dtype), np.zeros(h0.shape, h0.dtype), meta)
+        legacy_like = {"w": w_like, "h": h_like}
         # verified resume, single read: manifest-checksummed steps only (a
         # corrupt newest checkpoint falls back to the previous step,
         # utils.checkpoint). `like` only conveys tree structure + dtypes:
@@ -925,8 +1042,11 @@ class SGDMF:
             # meta-less legacy steps
             if (int(ck_meta["world"]) != world if ck_meta
                     and "world" in ck_meta
-                    else np.shape(saved["w"]) != tuple(w0.shape)):
+                    else np.shape(saved["w"]) != legacy_like["w"].shape):
                 saved = self._repartition_saved(saved, ck_meta, state)
+            else:
+                w_tab, h_tab = self._to_stored(saved["w"], saved["h"], meta)
+                saved = {**saved, "w": w_tab, "h": h_tab}
             # the device reshard path hands back already-placed arrays in
             # this session's sharding — no host round trip to undo
             w_cur = (saved["w"] if isinstance(saved["w"], jax.Array)
@@ -955,8 +1075,9 @@ class SGDMF:
                                    wall_s=wall, ledger=ledger)
             if (epoch + 1) % save_every == 0 or epoch + 1 == epochs:
                 with telemetry.phase("sgd_mf.checkpoint"):
-                    save_state = {"w": fetch(w_cur), "h": fetch(h_cur),
-                                  **assign_leaves}
+                    w_tab, h_tab = self._to_logical(
+                        fetch(w_cur), fetch(h_cur), meta)
+                    save_state = {"w": w_tab, "h": h_tab, **assign_leaves}
                     checkpointer.save(
                         epoch + 1, save_state,
                         meta=ckpt_lib.state_meta(
@@ -999,7 +1120,9 @@ class SGDMF:
         from harp_tpu.collectives import reshard as rs
 
         layout, data, w0, h0, meta = state
-        num_rows, num_cols, row_assign, col_assign, rpw, cpb = meta[:6]
+        num_rows, num_cols = meta[:2]
+        # the saved tables are logical, their new home is the device's
+        row_assign, col_assign, rpw, cpb = self._stored_maps(meta)
         if ck_meta is None or "world" not in ck_meta:
             raise ValueError(
                 "checkpoint does not match this session's factor shapes and "
@@ -1016,8 +1139,14 @@ class SGDMF:
                 f"rating matrix; this run prepared {num_rows}x{num_cols} — "
                 f"not the same dataset")
         w = self.session.num_workers
-        saved_w = np.asarray(saved["w"])
-        saved_h = np.asarray(saved["h"])
+
+        def stored_rank(a):
+            a = np.asarray(a)
+            return np.pad(a, [(0, 0)] * (a.ndim - 1)
+                          + [(0, w0.shape[-1] - a.shape[-1])])
+
+        saved_w = stored_rank(saved["w"])
+        saved_h = stored_rank(saved["h"])
         old_rpw = saved_w.shape[0] // old_world
         # 2-slice checkpoints hold H as fetched: worker-major
         # (W_old, 2, cpb_old, K) — already flat device order when raveled

@@ -159,12 +159,17 @@ def kmeans_stats_pallas(
 #
 # XLA's lowering of the masked stripe-GEMM hop (models/sgd_mf._build_dense)
 # materializes pred and G — two (s_rows, cpb) bf16 intermediates — to HBM and
-# re-reads G for the dW/dH GEMMs: ~5 slab-sized HBM passes per epoch, which IS
-# the measured roofline (~11-13 ms/epoch at 32768², PERF.md r3). This kernel
-# fuses the whole stripe update: pred and G live only in VMEM, so the epoch's
-# HBM traffic collapses to one slab read plus factor-sized I/O. Factors are
+# re-reads G for the dW/dH GEMMs: ~5 slab-sized HBM passes per epoch (16.7 ms
+# an epoch at MovieLens-10M's shape: ledger, PR 25, `sgdmf-k100.ml10m`). This
+# kernel fuses the whole stripe update: pred and G live only in VMEM, so the
+# epoch's HBM traffic collapses to one slab read plus factor-sized I/O (2.86
+# ms there, 85 % of the MXU's peak: PERF.md, Findings, PR 26). Factors are
 # carried TRANSPOSED — (K, rows) — so every block's lane dimension is a
 # 128-multiple (K rides the sublane dimension, where 8 | K suffices).
+#
+# That puts a stripe's ROWS on the lanes of every W block, so a stripe must
+# be a whole number of 128-lane tiles; the dense layout stores its stripes,
+# column blocks and rank at such sizes (models/sgd_mf.DenseGeometry).
 #
 # Grid: (nmb stripes, n_ct column tiles), sequential on TPU with j innermost.
 # Per step: pred = W_sᵀ·H_j (MXU, bf16), G = where(isnan(V), 0, V − pred),
@@ -244,6 +249,36 @@ def _dense_mf_hop_kernel(v_ref, wt_ref, rc_ref, cc_ref, ht_in_ref,
                                1).wait()
 
 
+# what the kernel may ask of VMEM (v5e: 128 MiB physical)
+DENSE_MF_VMEM_LIMIT = 100 * 1024 * 1024
+
+
+def dense_mf_hop_vmem_bytes(k: int, cpb: int, s: int, col_tile: int) -> int:
+    """VMEM the fused hop needs at these shapes, from above: 2.5 copies of
+    the resident H, 7 of a stripe's (K, s) factor block (in and out double
+    buffered, the dW scratch, the bf16 operand and a temporary), 6 bytes a
+    cell of the (s, col_tile) slab tile (its two bf16 buffers plus what
+    mosaic keeps of pred/G), 4 MiB. Fitted to the least ``vmem_limit_bytes``
+    at which the v5e compiler accepts the kernel, bisected at ten shapes
+    (s 1024-17920, cpb 2048-32768, K 16-128, every tile): 2 to 10 MiB over
+    it at each (PERF.md, Findings, PR 26)."""
+    return (10 * k * cpb + 28 * k * s + 6 * s * col_tile) + (4 << 20)
+
+
+def dense_mf_col_tile(cpb: int, s_rows: int, k: int) -> int:
+    """The fused hop's column tile at these (stored) shapes: the widest of
+    512 / 256 / 128 lanes that divides the column block and whose VMEM
+    estimate fits :data:`DENSE_MF_VMEM_LIMIT`; 0 where the shapes do not
+    tile (stripe rows ride the lanes of the transposed W blocks, rank the
+    sublanes) or nothing fits."""
+    if s_rows % lane_pack.LANES or k % 8:
+        return 0
+    return next((ct for ct in (4 * lane_pack.LANES, 2 * lane_pack.LANES,
+                               lane_pack.LANES)
+                 if cpb % ct == 0 and dense_mf_hop_vmem_bytes(
+                     k, cpb, s_rows, ct) <= DENSE_MF_VMEM_LIMIT), 0)
+
+
 def dense_mf_hop_pallas(vb: jax.Array, w_t: jax.Array, h_t: jax.Array,
                         rc2: jax.Array, cc2: jax.Array, lr: float, lam: float,
                         col_tile: int = 256, interpret: bool = False,
@@ -267,7 +302,7 @@ def dense_mf_hop_pallas(vb: jax.Array, w_t: jax.Array, h_t: jax.Array,
     cpb = vb.shape[1]
     if rpw != nmb * s or vb.shape[0] != rpw or h_t.shape[1] != cpb:
         raise ValueError("dense_mf_hop_pallas: inconsistent shapes")
-    if cpb % col_tile or s % 8 or k % 8 or col_tile % 128:
+    if cpb % col_tile or s % 128 or k % 8 or col_tile % 128:
         raise ValueError("dense_mf_hop_pallas: tiling constraints violated")
     n_ct = cpb // col_tile
     ring = None
@@ -285,12 +320,6 @@ def dense_mf_hop_pallas(vb: jax.Array, w_t: jax.Array, h_t: jax.Array,
     rc8 = jnp.broadcast_to(rc2[:, None, :], (nmb, 8, s)).reshape(nmb * 8, s)
     cc8 = jnp.broadcast_to(cc2[:, None, :],
                            (nmb, 8, cpb)).reshape(nmb * 8, cpb)
-    # VMEM budget: resident H (in + out copies) + per-step blocks + pred/g,
-    # with 30% headroom for mosaic's own temporaries (measured: the compiler
-    # asks a few MB beyond the naive sum at K=128)
-    vmem_bytes = 1.3 * (2 * k * cpb * 4 + s * col_tile * 2 + 2 * k * s * 4
-                        + k * s * 2 + 4 * s * col_tile
-                        + 2 * k * col_tile * 4) + (8 << 20)
     out_specs = [
         pl.BlockSpec((k, s), lambda i, j: (0, i)),              # w_t_new
         pl.BlockSpec((k, cpb), lambda i, j: (0, 0)),            # h_t_new
@@ -302,7 +331,7 @@ def dense_mf_hop_pallas(vb: jax.Array, w_t: jax.Array, h_t: jax.Array,
         jax.ShapeDtypeStruct((1, 128), jnp.float32),
     ]
     scratch_shapes = [pltpu.VMEM((k, s), jnp.float32)]
-    params = {"vmem_limit_bytes": min(int(vmem_bytes), 100 * 1024 * 1024)}
+    params = {"vmem_limit_bytes": DENSE_MF_VMEM_LIMIT}
     if ring is not None:
         out_specs.append(pl.BlockSpec(memory_space=pl.ANY))     # h_t_next
         out_shape.append(jax.ShapeDtypeStruct((k, cpb), jnp.float32))
@@ -877,15 +906,15 @@ def use_spd_solve_pallas(k: int) -> bool:
 
 def use_dense_mf_pallas(cpb: int, s_rows: int, k: int) -> bool:
     """Dispatch predicate for the fused dense-MF hop: default ON for TPU
-    (measured multi-x win over the XLA lowering — module doc), opt out with
-    HARP_DENSE_PALLAS=0. Shapes must satisfy the kernel's tiling."""
+    where a column tile fits (:func:`dense_mf_col_tile`), opt out with
+    HARP_DENSE_PALLAS=0."""
     import os
 
     if os.environ.get("HARP_DENSE_PALLAS", "1") == "0":
         return False
     if jax.default_backend() != "tpu":
         return False
-    return cpb % 128 == 0 and s_rows % 8 == 0 and k % 8 == 0
+    return dense_mf_col_tile(cpb, s_rows, k) > 0
 
 
 def kmeans_stats(x: jax.Array, c: jax.Array, block_n: int = 256,

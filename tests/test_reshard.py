@@ -265,6 +265,76 @@ def test_sgd_mf_slice_count_change_resume(tmp_path, sess8, sess4):
     np.testing.assert_array_equal(h_b, h_a)
 
 
+@pytest.mark.parametrize("mode, num_slices", [
+    ("device", 1), ("ring", 1), ("host", 1), ("device", 2)])
+def test_sgd_mf_dense_unaligned_resume_under_another_layout(
+        tmp_path, sess4, mode, num_slices):
+    """The dense layout at a shape off every tile (rank 12, 13-row stripes,
+    27- and 53-column blocks): a W=4 run's checkpoints hold the LOGICAL
+    tables (no stored pad row, column or rank column), and a W=2 resume of
+    them finalizes bitwise what the uninterrupted W=4 run returned, through
+    every reshard path."""
+    from harp_tpu.models import sgd_mf
+    from harp_tpu.utils import checkpoint as ckpt_lib
+
+    m, n = 103, 105
+    rows, cols, vals = datagen.sparse_ratings(m, n, rank=4, density=0.3,
+                                              seed=5)
+    cfg = dict(rank=12, layout="dense", minibatches_per_hop=2, epochs=3,
+               lr=0.02)
+    m4 = sgd_mf.SGDMF(sess4, sgd_mf.SGDMFConfig(**cfg))
+    st4 = m4.prepare(rows, cols, vals, m, n, seed=0)
+    g4 = st4[4][6]
+    assert (g4.s_rows, g4.cpb, g4.rank) == (13, 27, 12)
+    assert (g4.s_store, g4.cpb_store, g4.rank_store) == (128, 256, 16)
+    w_a, h_a, rmse_a, _ = m4.fit_checkpointed(
+        st4, Checkpointer(str(tmp_path / "ck")), save_every=1)
+    assert w_a.shape == (m, 12) and h_a.shape == (n, 12)
+    assert rmse_a[-1] < rmse_a[0]
+
+    _, saved, meta = Checkpointer(str(tmp_path / "ck")).restore_latest_valid(
+        like_from_meta=lambda mt: ckpt_lib.meta_like(mt), return_meta=True)
+    assert np.shape(saved["w"]) == (4 * g4.rpw, 12)
+    assert np.shape(saved["h"]) == (4 * g4.cpb, 12)
+
+    sess2 = HarpSession(num_workers=2)
+    m2 = sgd_mf.SGDMF(sess2, sgd_mf.SGDMFConfig(
+        **cfg, reshard=mode, num_slices=num_slices))
+    st2 = m2.prepare(rows, cols, vals, m, n, seed=0)
+    assert st2[4][6].cpb == -(-n // (2 * num_slices))
+    w_b, h_b, rmse_b, start = m2.fit_checkpointed(
+        st2, Checkpointer(str(tmp_path / "ck")), save_every=1)
+    assert start == 3 and len(rmse_b) == 0
+    np.testing.assert_array_equal(w_b, w_a)
+    np.testing.assert_array_equal(h_b, h_a)
+
+
+def test_sgd_mf_dense_unaligned_same_world_resume_is_bitwise(tmp_path,
+                                                             sess4):
+    """Interrupted after 2 of 4 epochs and resumed in the same world: the
+    logical tables go back into the stored arrays, and the run ends bitwise
+    where the uninterrupted one does."""
+    from harp_tpu.models import sgd_mf
+
+    m, n = 103, 105
+    rows, cols, vals = datagen.sparse_ratings(m, n, rank=4, density=0.3,
+                                              seed=5)
+    cfg = sgd_mf.SGDMFConfig(rank=12, layout="dense", minibatches_per_hop=2,
+                             epochs=4, lr=0.02)
+    model = sgd_mf.SGDMF(sess4, cfg)
+    state = model.prepare(rows, cols, vals, m, n, seed=0)
+    w_a, h_a, rmse_a, _ = model.fit_checkpointed(
+        state, Checkpointer(str(tmp_path / "whole")), save_every=2)
+    model.fit_checkpointed(state, Checkpointer(str(tmp_path / "cut")),
+                           epochs=2, save_every=2)
+    w_b, h_b, rmse_b, start = model.fit_checkpointed(
+        state, Checkpointer(str(tmp_path / "cut")), save_every=2)
+    assert start == 2
+    np.testing.assert_array_equal(rmse_b, rmse_a[2:])
+    np.testing.assert_array_equal(w_b, w_a)
+    np.testing.assert_array_equal(h_b, h_a)
+
+
 def test_sgd_mf_device_resume_never_gathers_factors(tmp_path, sess8, sess4,
                                                     monkeypatch):
     # the acceptance assert: the device reshard path never fetches a

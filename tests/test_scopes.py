@@ -70,20 +70,31 @@ def _kmeans_text(topo, workers: int) -> str:
         return model._fit.lower(points, centroids).compile().as_text()
 
 
-def _sgdmf_text(topo, workers: int, rows: int = 1024) -> str:
-    """The dense SGD-MF step at rank 100 and ML-10M's columns per block,
-    the rows per worker cut."""
+def _sgdmf_text(topo, workers: int, fused: bool = False) -> str:
+    """The dense SGD-MF step at the cells' widths as the layout stores them
+    (rank 100 as 104, ML-10M's or ML-20M's columns per block padded to 256),
+    the stripes cut to 128 rows. ``fused``: the program the chip
+    runs, with the fused hop kernel (the dispatch asks ``jax`` for its
+    backend, which is the CPU here)."""
+    from harp_tpu.ops import pallas_kernels
+
     sess = HarpSession(num_workers=workers, devices=topo.devices[:workers])
     model = sgd_mf.SGDMF(sess, sgd_mf.SGDMFConfig(
         rank=100, lam=0.05, lr=1e-4, minibatches_per_hop=8, epochs=5))
-    nmb, cpb = 8, -(-10681 // workers)
-    key = model._program("dense", nmb, 5, (nmb, rows, cpb))
+    g, n_blocks = model._dense_geometry(
+        8 * 128 * workers, 10681 if workers == 1 else 26744)
+    assert (g.s_rows, g.rank_store, n_blocks) == (128, 104, workers)
+    rows, cpb, k = g.rpw_store, g.cpb_store, g.rank_store
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pallas_kernels, "use_dense_mf_pallas",
+                      lambda *shape: fused)
+        key = model._program("dense", g.nmb, 5, g)
     shard = sess.shard()
     args = (_shaped(sess, (workers, workers, rows, cpb), jnp.bfloat16, shard),
             _shaped(sess, (workers, workers, rows), jnp.float32, shard),
-            _shaped(sess, (workers, workers, nmb, cpb), jnp.float32, shard),
-            _shaped(sess, (workers * rows, 100), jnp.float32, shard),
-            _shaped(sess, (workers * cpb, 100), jnp.float32, shard))
+            _shaped(sess, (workers, workers, g.nmb, cpb), jnp.float32, shard),
+            _shaped(sess, (workers * rows, k), jnp.float32, shard),
+            _shaped(sess, (workers * cpb, k), jnp.float32, shard))
     return model._compiled[key].lower(*args).compile().as_text()
 
 
@@ -117,6 +128,11 @@ PROGRAMS = {
     "sgdmf-4": (lambda t: _sgdmf_text(t, 4),
                 {"sgdmf.select", "sgdmf.stripes", "sgdmf.rmse",
                  "rotation.hop"}),
+    "sgdmf-1-fused": (lambda t: _sgdmf_text(t, 1, fused=True),
+                      {"sgdmf.stripes", "sgdmf.rmse", "rotation.hop"}),
+    "sgdmf-4-fused": (lambda t: _sgdmf_text(t, 4, fused=True),
+                      {"sgdmf.select", "sgdmf.stripes", "sgdmf.rmse",
+                       "rotation.hop"}),
 }
 
 
@@ -141,7 +157,14 @@ def test_every_kernel_of_the_loop_bodies_has_a_listed_scope(compiled, program):
     bare = [(name, op) for name, op in kernels
             if mapped[name] not in scopes.SCOPES]
     assert not bare, bare
-    assert {op for _, op in kernels} >= {"fusion", "dynamic-update-slice"}
+    if program.endswith("-fused"):
+        # the hop is the one kernel; what stands around it (the transposes
+        # of W and H, the count broadcasts) is fusions
+        assert "tpu_custom_call" in text
+        assert {op for _, op in kernels} >= {"fusion", "custom-call"}
+    else:
+        assert "tpu_custom_call" not in text
+        assert {op for _, op in kernels} >= {"fusion", "dynamic-update-slice"}
 
 
 @pytest.mark.parametrize("program", sorted(PROGRAMS))
@@ -156,8 +179,41 @@ def test_each_scope_the_program_reaches_is_present(compiled, program):
     if program.startswith("sgdmf"):
         assert not any(s and s.startswith("kmeans") for s in everywhere)
     # a ring of one picks its single block by a static index: no select
-    if program == "sgdmf-1":
+    if program.startswith("sgdmf-1"):
         assert "sgdmf.select" not in loops
+
+
+@pytest.mark.parametrize("workers, num_rows, num_cols", [
+    (1, 71_567, 10_681), (4, 138_493, 26_744)])
+def test_the_fused_hop_compiles_at_the_cells_stored_geometry(
+        topo, no_compile_cache, workers, num_rows, num_cols):
+    """Mosaic accepts the hop kernel at the MovieLens cells' full stored
+    stripes and blocks, at the tile the dispatch picks and under the VMEM
+    limit it is given: the estimate that picks the tile is not short."""
+    from jax.sharding import SingleDeviceSharding
+
+    from harp_tpu.ops import pallas_kernels as pk
+
+    model = sgd_mf.SGDMF(
+        HarpSession(num_workers=workers, devices=topo.devices[:workers]),
+        sgd_mf.SGDMFConfig(rank=100, minibatches_per_hop=8))
+    g, _ = model._dense_geometry(num_rows, num_cols)
+    tile = pk.dense_mf_col_tile(g.cpb_store, g.s_store, g.rank_store)
+    assert tile >= 256
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    hop = jax.jit(lambda vb, wt, ht, rc, cc: pk.dense_mf_hop_pallas(
+        vb, wt, ht, rc, cc, 1e-4, 0.05, col_tile=tile))
+    text = hop.lower(
+        shaped((g.rpw_store, g.cpb_store), jnp.bfloat16),
+        shaped((g.rank_store, g.rpw_store)),
+        shaped((g.rank_store, g.cpb_store)),
+        shaped((g.nmb, g.s_store)),
+        shaped((g.nmb, g.cpb_store))).compile().as_text()
+    assert "tpu_custom_call" in text
 
 
 def test_the_ring_hop_is_a_collective_permute_under_its_own_name(compiled):

@@ -13,6 +13,7 @@ import numpy as np
 
 from harp_tpu.io import datagen
 from harp_tpu.models import sgd_mf
+from harp_tpu.session import HarpSession
 
 
 def test_sgd_mf_converges(session):
@@ -240,25 +241,37 @@ def test_nan_ratings_rejected_and_auto_dense_respects_int32_guard(session):
     assert big._choose_layout(512, 512) == "dense"
 
 
-def test_dense_mf_hop_pallas_matches_xla_stripes():
+@pytest.mark.parametrize("col_tile", [128, 256, 512])
+def test_dense_mf_hop_pallas_matches_xla_stripes(col_tile):
     """The fused pallas hop (interpret mode on CPU) is bit-comparable to the
-    XLA stripe loop in models/sgd_mf._build_dense."""
+    XLA stripe loop in models/sgd_mf._build_dense at the tiles the stored
+    layout reaches: rank 104 with four zero rank columns, pad rows at every
+    stripe's end and pad columns at the block's (NaN cells, zero counts,
+    zero factors), which a hop must leave bitwise as they were."""
     import jax
     import jax.numpy as jnp
 
     from harp_tpu.ops import pallas_kernels as pk
 
     rng = np.random.default_rng(0)
-    NMB, S, CPB, K = 2, 16, 256, 8
+    NMB, S, CPB, K = 2, 128, 1024, 104
+    S_LIVE, CPB_LIVE, K_LIVE = 121, 1001, 100
     RPW = NMB * S
     LR, LAM = 0.05, 0.01
+    live_row = np.tile(np.arange(S) < S_LIVE, NMB)
+    live_col = np.arange(CPB) < CPB_LIVE
     v = rng.random((RPW, CPB)).astype(np.float32)
     v[rng.random((RPW, CPB)) < 0.9] = np.nan
+    v[~live_row] = np.nan
+    v[:, ~live_col] = np.nan
     vb = jnp.asarray(v, jnp.bfloat16)
-    w0 = jnp.asarray(rng.random((RPW, K)), jnp.float32)
-    h0 = jnp.asarray(rng.random((CPB, K)), jnp.float32)
-    rc = jnp.asarray(rng.integers(1, 5, RPW), jnp.float32)
-    cc = jnp.asarray(rng.integers(1, 5, (NMB, CPB)), jnp.float32)
+    w_np = (0.1 * rng.standard_normal((RPW, K))).astype(np.float32)
+    h_np = (0.1 * rng.standard_normal((CPB, K))).astype(np.float32)
+    w_np[~live_row], h_np[~live_col] = 0.0, 0.0
+    w_np[:, K_LIVE:], h_np[:, K_LIVE:] = 0.0, 0.0
+    w0, h0 = jnp.asarray(w_np), jnp.asarray(h_np)
+    rc = jnp.asarray(rng.integers(1, 5, RPW) * live_row, jnp.float32)
+    cc = jnp.asarray(rng.integers(1, 5, (NMB, CPB)) * live_col, jnp.float32)
     bf = jnp.bfloat16
 
     def stripe(state, xs):
@@ -284,10 +297,172 @@ def test_dense_mf_hop_pallas_matches_xla_stripes():
         (w0.reshape(NMB, S, K), vb.reshape(NMB, S, CPB),
          rc.reshape(NMB, S), cc))
     w_t, h_t, sse_pl = pk.dense_mf_hop_pallas(
-        vb, w0.T, h0.T, rc.reshape(NMB, S), cc, LR, LAM, col_tile=128,
+        vb, w0.T, h0.T, rc.reshape(NMB, S), cc, LR, LAM, col_tile=col_tile,
         interpret=True)
-    np.testing.assert_allclose(np.asarray(w_ref.reshape(RPW, K)),
-                               np.asarray(w_t.T), rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(h_ref), np.asarray(h_t.T),
+    w_new, h_new = np.asarray(w_t.T), np.asarray(h_t.T)
+    np.testing.assert_allclose(np.asarray(w_ref.reshape(RPW, K)), w_new,
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(h_ref), h_new,
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(float(sse_ref), float(sse_pl), rtol=1e-4)
+    # the live part moved, the pads did not (both paths)
+    assert np.abs(w_new[live_row, :K_LIVE] - w_np[live_row, :K_LIVE]).max() > 0
+    for got in (w_new, np.asarray(w_ref.reshape(RPW, K))):
+        assert not got[~live_row].any() and not got[:, K_LIVE:].any()
+    for got in (h_new, np.asarray(h_ref)):
+        assert not got[~live_col].any() and not got[:, K_LIVE:].any()
+
+
+# --------------------------------------------------------------------------- #
+# the dense layout's two geometries (DenseGeometry): the job is logical, the
+# device arrays are stored at the fused hop's tiles
+# --------------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("workers, num_rows, num_cols", [
+    (1, 71_567, 10_681),        # MovieLens-10M on one chip
+    (4, 138_493, 26_744),       # MovieLens-20M on four
+])
+def test_storage_geometry_of_the_public_shapes_reaches_the_fused_hop(
+        workers, num_rows, num_cols):
+    """Pure shapes: at rank 100 the stored geometry of both MovieLens cells
+    tiles the kernel and fits its VMEM estimate, while the logical geometry
+    (the mini-batches of the benchmark's reference) stays as it was and
+    satisfies none of the three."""
+    from harp_tpu.ops import pallas_kernels as pk
+
+    model = sgd_mf.SGDMF(HarpSession(num_workers=workers), sgd_mf.SGDMFConfig(
+        rank=100, minibatches_per_hop=8))
+    g, n_blocks = model._dense_geometry(num_rows, num_cols)
+    rpw = -(-(-(-num_rows // workers)) // 8) * 8
+    assert (g.rpw, g.cpb, g.rank) == (rpw, -(-num_cols // workers), 100)
+    assert g.cpb % 128 and g.rank % 8 and g.s_rows % 128
+    assert pk.dense_mf_col_tile(g.cpb, g.s_rows, g.rank) == 0
+    tile = pk.dense_mf_col_tile(g.cpb_store, g.s_store, g.rank_store)
+    assert tile == (512 if workers == 1 else 256) and g.cpb_store % tile == 0
+    assert pk.dense_mf_hop_vmem_bytes(
+        g.rank_store, g.cpb_store, g.s_store, tile) <= pk.DENSE_MF_VMEM_LIMIT
+    cells = n_blocks * g.rpw_store * g.cpb_store
+    assert cells < 2 ** 31 and cells / (n_blocks * g.rpw * g.cpb) < 1.04
+    assert model._choose_layout(num_rows, num_cols) == "dense"
+    # a coarser budget of fit_adaptive merges whole stored stripes
+    for nmb in (4, 2, 1):
+        assert (g.rpw_store // nmb) % 128 == 0
+
+
+@pytest.fixture(scope="module")
+def unaligned_bench(tmp_path_factory):
+    """The benchmark's tiny tree with both SGD-MF cells at a shape that is a
+    small analogue of ML-10M's: no stripe, block or rank on a tile."""
+    import os
+
+    from tests.benchmark import tiny
+
+    root = tiny.build(str(tmp_path_factory.mktemp("bench")))
+    for cell in (tiny.ML10M, tiny.ML20M_X4):
+        tiny._rewrite(
+            os.path.join(root, "benchmark", "workloads", cell + ".json"),
+            lambda doc: doc["params"].update(rows=710, cols=301))
+    return root
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_unaligned_shape_trains_the_reference_job(unaligned_bench, chips):
+    """``s_rows % 8``, ``cpb % 128`` and ``rank % 8`` all non-zero, on one
+    worker and on four: the first model is ``default_rng(seed)``'s draw at
+    the LOGICAL sizes, the way out returns the logical tables, and 15
+    epochs follow the benchmark's plain reference within the configuration's
+    limits (as the tiny tree holds them on the CPU)."""
+    import os
+
+    import jax
+
+    from benchmark import compare, harness
+    from tests.benchmark import tiny
+
+    precision = jax.config.jax_default_matmul_precision
+    try:
+        cell, _, _ = harness.open_cell(
+            tiny.ML10M if chips == 1 else tiny.ML20M_X4, unaligned_bench,
+            require_accelerator=False)
+    finally:
+        jax.config.update("jax_default_matmul_precision", precision)
+    assert cell.chips == chips
+    data = harness.make_data(cell, tiny.SEED + 26)
+    driver = cell.part("driver").Driver(cell.config, cell.traffic, data, chips)
+    driver.prepare()
+    g = driver._state[4][6]
+    rank = cell.config["rank"]
+    assert (g.nmb * g.s_rows * chips >= 710 and g.cpb * chips >= 301
+            and g.rank == rank == 100)
+    assert g.s_rows % 8 and g.cpb % 128 and g.rank % 8
+    assert (g.s_store % 128, g.cpb_store % 256, g.rank_store % 8) == (0, 0, 0)
+
+    rng = np.random.default_rng(data["init_seed"])
+    scale = 1.0 / np.sqrt(rank)
+    w_draw = (scale * rng.standard_normal(
+        (chips * g.rpw, rank))).astype(np.float32)
+    h_draw = (scale * rng.standard_normal(
+        (chips * g.cpb, rank))).astype(np.float32)
+    first = driver.finalize(driver.initial())
+    assert first["W"].shape == (710, rank) and first["H"].shape == (301, rank)
+    np.testing.assert_array_equal(first["W"], w_draw[:710])
+    np.testing.assert_array_equal(first["H"], h_draw[:301])
+    # the stored arrays: the draw embedded, every pad 0
+    w_dev, h_dev = (np.asarray(a) for a in driver.initial())
+    assert w_dev.shape == (chips * g.rpw_store, g.rank_store)
+    assert h_dev.shape == (chips * g.cpb_store, g.rank_store)
+    meta = driver._state[4]
+    w_log, h_log = sgd_mf.SGDMF._to_logical(w_dev, h_dev, meta)
+    np.testing.assert_array_equal(w_log, w_draw)
+    np.testing.assert_array_equal(h_log, h_draw)
+    assert np.count_nonzero(w_dev) == np.count_nonzero(w_draw)
+    assert np.count_nonzero(h_dev) == np.count_nonzero(h_draw)
+
+    record = harness.first_calls(driver, harness.Spans())
+    driver.free()
+    first_ref, reference = harness.follow_reference(cell, data)
+    np.testing.assert_array_equal(first_ref["W"], first["W"])
+    np.testing.assert_array_equal(first_ref["H"], first["H"])
+    read = compare.numbers(first_ref, record, reference)
+    ok, compared = compare.verdict(read, cell.limits)
+    assert ok, compared
+    assert len(record["quality"]) == 15
+    # ... and within the five limits the committed configuration holds on
+    # the chip, which the tiny tree loosens to two
+    committed = harness.load_json(os.path.join(
+        tiny.BENCH, "configs", "sgdmf-k100.json"))["limits"]
+    assert len(committed) == 5
+    ok, compared = compare.verdict(read, committed)
+    assert ok, compared
+
+
+def test_layout_stats_and_hop_counter_say_which_update_runs(session):
+    """``last_layout_stats`` says what engaged and what it cost; the
+    ``sgd_mf.hops.*`` counter moves when jax traces a hop body and never on
+    a cached call. Off the TPU the stripe scan runs on the stored arrays."""
+    from harp_tpu.utils import metrics
+
+    rows, cols, vals = datagen.sparse_ratings(
+        num_users=96, num_items=80, rank=4, density=0.25, seed=3)
+    model = sgd_mf.SGDMF(session, sgd_mf.SGDMFConfig(
+        rank=6, epochs=2, layout="dense", minibatches_per_hop=2))
+    state = model.prepare(rows, cols, vals, 96, 80)
+    g = state[4][6]
+    stats = model.last_layout_stats
+    assert (stats["fused_hop"], stats["col_tile"]) == (False, 0)
+    assert stats["pad_overhead"] == pytest.approx(
+        g.rpw_store * g.cpb_store / (g.rpw * g.cpb))
+    assert stats["padded"] == (session.num_workers ** 2
+                               * g.rpw_store * g.cpb_store)
+
+    def hops():
+        return dict(metrics.DEFAULT.counters).get("sgd_mf.hops.xla", 0)
+
+    before = hops()
+    model.fit_prepared(state)
+    traced = hops() - before
+    assert traced >= 1
+    model.fit_prepared(state)
+    assert hops() - before == traced
+    assert model.last_layout_stats["fused_hop"] is False
+    assert "sgd_mf.hops.fused" not in dict(metrics.DEFAULT.counters)
