@@ -36,7 +36,7 @@ reference ingested exactly such power-law CSR data
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,8 +44,11 @@ import numpy as np
 
 from harp_tpu import telemetry
 from harp_tpu.collectives import lax_ops
+from harp_tpu.ops import pallas_kernels
+from harp_tpu.ops.lane_pack import LANES, round_up
 from harp_tpu.parallel.mesh import WORKERS
 from harp_tpu.session import HarpSession
+from harp_tpu.utils import metrics
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,8 +70,9 @@ class ALSConfig:
     #   lane-vectorized batched Cholesky (ops/pallas_kernels.spd_solve_pallas:
     #   batch on the 128-lane axis, unrolled outer-product factorization +
     #   substitutions, pure full-width VPU work) that makes the solve
-    #   HBM-bound. "auto" = pallas on TPU at k ≤ 64, else cholesky (exact
-    #   XLA path); "newton" (pure batched GEMMs, Precision.HIGHEST — TPU's
+    #   HBM-bound. "auto" = pallas on TPU wherever a lane tile of the
+    #   (k, k, 128) working set fits VMEM (k up to ~250), else cholesky
+    #   (exact XLA path); "newton" (pure batched GEMMs, Precision.HIGHEST — TPU's
     #   default bf16 multiply floors its quadratic convergence at ~1e-1) is
     #   kept as the measured alternative.
     newton_iters: int = 30
@@ -178,12 +182,18 @@ def _resolve_solver(cfg: ALSConfig) -> str:
                          f"{cfg.solver!r}")
     if cfg.solver != "auto":
         return cfg.solver
-    from harp_tpu.ops.pallas_kernels import use_spd_solve_pallas
-
     # measured on v5e (PERF.md r4): the lane-vectorized pallas Cholesky
     # breaks the XLA batched-solve plateau; where it doesn't apply,
     # cholesky ties or beats newton at every batch shape tried and is exact
-    return "pallas" if use_spd_solve_pallas(cfg.rank) else "cholesky"
+    return ("pallas" if pallas_kernels.use_spd_solve_pallas(cfg.rank)
+            else "cholesky")
+
+
+def _interpret(cfg: ALSConfig) -> bool:
+    """An explicit ``solver="pallas"`` off-TPU runs the kernel in interpret
+    mode (slow but exact — the path CI and the CPU mesh exercise); 'auto'
+    resolves to the kernel only where it is compiled."""
+    return cfg.solver == "pallas" and jax.default_backend() != "tpu"
 
 
 def _spd_solve(a, b, cfg: ALSConfig):
@@ -205,13 +215,8 @@ def _spd_solve(a, b, cfg: ALSConfig):
         return b + 0.0 * a[..., 0]
     solver = _resolve_solver(cfg)
     if solver == "pallas":
-        from harp_tpu.ops import pallas_kernels
-
-        # explicit request off-TPU runs the kernel in interpret mode (slow
-        # but exact — the path CI and the CPU mesh exercise); 'auto' never
-        # resolves here off-TPU
-        interpret = jax.default_backend() != "tpu"
-        return pallas_kernels.spd_solve_pallas(a, b, interpret=interpret)
+        return pallas_kernels.spd_solve_pallas(a, b,
+                                               interpret=_interpret(cfg))
     if solver == "cholesky":
         return jax.scipy.linalg.solve(a, b[..., None], assume_a="pos")[..., 0]
     k = a.shape[-1]
@@ -256,6 +261,7 @@ def _half_step(factor_other, idx, val, mask, chunk_row, rpw: int,
     if cfg.implicit:
         gram = jax.lax.dot_general(              # V'V over ALL entities
             factor_other, factor_other, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         a = a + gram[None]
     a = a + cfg.lam * jnp.eye(k, dtype=a.dtype)[None]
@@ -266,6 +272,7 @@ def _train(u_data, i_data, u0, v0, u_rpw: int, i_rpw: int, cfg: ALSConfig,
            axis_name: str = WORKERS):
     u_idx, u_val, u_mask, u_crow = u_data
     i_idx, i_val, i_mask, i_crow = i_data
+    telemetry.traced("als.fit")          # runs when jax traces, only
 
     def iteration(carry, _):
         u, v = carry                             # both replicated (E, K)
@@ -291,49 +298,171 @@ def _train(u_data, i_data, u0, v0, u_rpw: int, i_rpw: int, cfg: ALSConfig,
 # Dense layout: normal equations as GEMMs (the dense-SGD-MF trick for ALS)
 # --------------------------------------------------------------------------- #
 
-def _half_step_dense(factor_other, val_plane, rpw: int, cfg: ALSConfig):
+# What one dense half-step may hold beside the resident planes. A side's
+# normal equations are K² float32 a row (2.9 GB for MovieLens-10M's users at
+# rank 100) and its outer-product operand K² bfloat16 a row of the OTHER
+# side (1.5 GB for the items' half-step there), so a half-step runs in row
+# blocks, each contracting the other side in chunks; both sizes are derived
+# from the shapes and this budget.
+DENSE_SCRATCH_BYTES = 2 * 1024 ** 3
+_CHUNK_COLS = 16384         # widest contraction chunk (K² x 16384 bf16 =
+#   0.35 GB at rank 100)
+
+
+def _row_block(rows: int, per_row: int, fixed: int = 0) -> Tuple[int, int]:
+    """``(block, blocks)``: the fewest equal row blocks, a multiple of the
+    512 lanes the solve kernel's widest tile carries its batch on, whose
+    ``per_row`` bytes a row fit :data:`DENSE_SCRATCH_BYTES` beside
+    ``fixed``. One block is the side as it stands, whatever its length."""
+    tile = pallas_kernels.SPD_SOLVE_TILES[0]
+    most = max(tile, (DENSE_SCRATCH_BYTES - fixed) // per_row // tile * tile)
+    blocks = -(-rows // most)
+    if blocks == 1:
+        return rows, 1
+    return round_up(-(-rows // blocks), tile), blocks
+
+
+def _dense_blocks(rows: int, other: int, k: int) -> Tuple[int, int, int, int]:
+    """``(row block, row blocks, chunk, chunks)`` of one dense half-step
+    over a ``(rows, other)`` plane at rank ``k``. A row of a block costs its
+    normal equations twice (the GEMM's result and the solver's operand) and
+    two bf16 weights per chunk column; a chunk costs its outer products."""
+    kp = round_up(k, 8)
+    chunks = -(-other // _CHUNK_COLS)
+    chunk = other if chunks == 1 else round_up(-(-other // chunks), LANES)
+    block, blocks = _row_block(rows, 8 * kp * kp + 4 * chunk,
+                               fixed=2 * kp * kp * chunk)
+    return block, blocks, chunk, chunks
+
+
+def _spd_solve_lanes(at, bt, cfg: ALSConfig):
+    """Solve batch-last systems: ``at`` (Kp, Kp, B) float32, ``bt`` (Kp, B),
+    the rank padded to Kp by an identity block → x (B, K). The Pallas kernel
+    reads them as they lie; any other solver takes them batch-first."""
+    k = cfg.rank
+    pallas = not cfg.ablate_solve and _resolve_solver(cfg) == "pallas"
+    # runs when jax traces, only: which solve this program's half-steps run
+    metrics.DEFAULT.count("als.solve.pallas" if pallas else "als.solve.xla")
+    if pallas:
+        xt = pallas_kernels.spd_solve_lanes(at, bt, interpret=_interpret(cfg))
+        return xt[:k].T
+    return _spd_solve(jnp.transpose(at, (2, 0, 1))[:, :k, :k], bt[:k].T, cfg)
+
+
+def _half_step_dense(factor_other, val_plane, rpw: int, cfg: ALSConfig,
+                     blocks: Optional[Tuple[int, int, int, int]] = None):
     """One side's normal equations from a dense NaN-encoded value plane.
 
     ``val_plane``: (rpw, E_other) bf16, NaN = unobserved (0 is a VALID
     observed value in explicit mode). A_u = Σ_i w_ui v_i v_iᵀ collapses to
-    one (rpw, E) @ (E, K²) GEMM against the factor's row-wise outer products
-    — MXU matrix-matrix rates instead of 128-byte row gathers. bf16 operands,
-    f32 accumulation (the dense SGD-MF precision contract)."""
+    one GEMM of the factor's row-wise outer products against the weights —
+    MXU matrix-matrix rates instead of 128-byte row gathers. bf16 operands,
+    f32 accumulation (the dense SGD-MF precision contract); V'V from the
+    float32 factors at ``Precision.HIGHEST``.
+
+    The product is taken TRANSPOSED, (K², E) x (rows, E)ᵀ → (K², rows): the
+    systems leave the MXU batch-last, as the solve kernel reads them, with
+    the rank padded to a sublane multiple by zero outer products (the
+    regulariser puts 1 on the padded diagonal). Runs in row blocks, each
+    contracting E in chunks (:func:`_dense_blocks`; ``blocks`` overrides
+    them); the last block and chunk are taken flush with the end, and what a
+    chunk then shares with the one before is weighted 0."""
     k = cfg.rank
-    obs = jnp.isfinite(val_plane)
-    vz = jnp.where(obs, val_plane, 0).astype(jnp.bfloat16)
-    f_b = factor_other.astype(jnp.bfloat16)
+    kp = round_up(k, 8)
     e = factor_other.shape[0]
-    vv = (f_b[:, :, None] * f_b[:, None, :]).reshape(e, k * k)
-    f32 = jnp.float32
-    if cfg.implicit:
-        # Hu-Koren: A = V'V + V'(C−I)V + λI, C−I = alpha*r on observed
-        conf = (cfg.alpha * vz).astype(jnp.bfloat16)
-        a = jax.lax.dot_general(conf, vv, (((1,), (0,)), ((), ())),
-                                preferred_element_type=f32)
-        gram = jax.lax.dot_general(factor_other, factor_other,
-                                   (((0,), (0,)), ((), ())),
-                                   preferred_element_type=f32)
-        a = a.reshape(rpw, k, k) + gram[None]
-        bw = jnp.where(obs, 1.0 + cfg.alpha * vz.astype(f32), 0.0)
-        b = jax.lax.dot_general(bw.astype(jnp.bfloat16), f_b,
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=f32)
-    else:
-        a = jax.lax.dot_general(obs.astype(jnp.bfloat16), vv,
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=f32)
-        a = a.reshape(rpw, k, k)
-        b = jax.lax.dot_general(vz, f_b, (((1,), (0,)), ((), ())),
-                                preferred_element_type=f32)
-    a = a + cfg.lam * jnp.eye(k, dtype=a.dtype)[None]
-    return _spd_solve(a, b, cfg)
+    rb, n_rb, ce, n_ce = blocks or _dense_blocks(rpw, e, k)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    # runs when jax traces, only
+    metrics.DEFAULT.count("als.row_blocks", n_rb)
+    with jax.named_scope("als.outer"):
+        f_t = jnp.pad(factor_other.T, ((0, kp - k), (0, 0))).astype(bf16)
+    with jax.named_scope("als.gram"):
+        shift = jnp.diag(jnp.where(jnp.arange(kp) < k, cfg.lam, 1.0)
+                         .astype(f32))
+        if cfg.implicit:
+            # Hu-Koren: A = V'V + V'(C−I)V + λI, C−I = alpha*r on observed
+            gram = jax.lax.dot_general(
+                factor_other, factor_other, (((0,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=f32)
+            shift = shift + jnp.pad(gram, ((0, kp - k), (0, kp - k)))
+
+    def normal_equations(r0, c0, lo):
+        """(K², rb) and (Kp, rb) of the plane's block at (r0, c0); columns
+        before ``lo`` belong to the chunk before."""
+        blk = jax.lax.dynamic_slice(val_plane, (r0, c0), (rb, ce))
+        f_c = jax.lax.dynamic_slice_in_dim(f_t, c0, ce, 1)
+        with jax.named_scope("als.outer"):
+            vv = (f_c[:, None, :] * f_c[None, :, :]).reshape(kp * kp, ce)
+        obs = jnp.isfinite(blk) & (c0 + jnp.arange(ce) >= lo)[None, :]
+        vz = jnp.where(obs, blk, 0).astype(bf16)
+        if cfg.implicit:
+            w_a = (cfg.alpha * vz).astype(bf16)
+            w_b = jnp.where(obs, 1.0 + cfg.alpha * vz.astype(f32),
+                            0.0).astype(bf16)
+        else:
+            w_a, w_b = obs.astype(bf16), vz
+        with jax.named_scope("als.gram"):
+            a = jax.lax.dot_general(vv, w_a, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=f32)
+        with jax.named_scope("als.rhs"):
+            b = jax.lax.dot_general(f_c, w_b, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=f32)
+        return a, b
+
+    def block(i, out):
+        r0 = jnp.minimum(i * rb, rpw - rb)
+
+        def chunk(c, acc):
+            da, db = normal_equations(r0, jnp.minimum(c * ce, e - ce), c * ce)
+            return acc[0] + da, acc[1] + db
+
+        with jax.named_scope("als.gram"):
+            if n_ce == 1:
+                a, b = normal_equations(r0, 0, 0)
+            else:
+                a, b = jax.lax.fori_loop(
+                    0, n_ce, chunk,
+                    (jnp.zeros((kp * kp, rb), f32), jnp.zeros((kp, rb), f32)))
+        with jax.named_scope("als.solve"):
+            x = _spd_solve_lanes(a.reshape(kp, kp, rb) + shift[:, :, None],
+                                 b, cfg)
+            return jax.lax.dynamic_update_slice_in_dim(out, x, r0, 0)
+
+    return jax.lax.fori_loop(0, n_rb, block, jnp.zeros((rpw, k), f32))
+
+
+def _monitor_dense(u_block, v, u_plane, cfg: ALSConfig):
+    """Squared error and count over the observed cells of this worker's
+    plane (against 1 in implicit mode), in row blocks: the predictions of
+    all rows at once are another plane in float32."""
+    rpw, e = u_plane.shape
+    rb, n_rb = _row_block(rpw, 8 * e)
+    v_b = v.astype(jnp.bfloat16)
+
+    def block(i, acc):
+        lo = i * rb
+        r0 = jnp.minimum(lo, rpw - rb)
+        blk = jax.lax.dynamic_slice_in_dim(u_plane, r0, rb, 0)
+        rows = jax.lax.dynamic_slice_in_dim(u_block, r0, rb, 0)
+        obs = jnp.isfinite(blk) & (r0 + jnp.arange(rb) >= lo)[:, None]
+        pred = jax.lax.dot_general(
+            rows.astype(jnp.bfloat16), v_b, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        tgt = (jnp.where(obs, blk, 0).astype(jnp.float32)
+               if not cfg.implicit else 1.0)
+        return (acc[0] + jnp.sum(jnp.where(obs, (tgt - pred) ** 2, 0.0)),
+                acc[1] + jnp.sum(obs.astype(jnp.float32)))
+
+    zero = jnp.zeros((), jnp.float32)
+    return jax.lax.fori_loop(0, n_rb, block, (zero, zero))
 
 
 def _train_dense(u_plane, i_plane, u0, v0, u_rpw: int, i_rpw: int,
                  cfg: ALSConfig, axis_name: str = WORKERS):
     """Dense-layout training loop: same allgather choreography as _train,
     with the dense half-step and a GEMM-based RMSE monitor."""
+    telemetry.traced("als.fit")          # runs when jax traces, only
 
     def iteration(carry, _):
         u, v = carry
@@ -341,16 +470,12 @@ def _train_dense(u_plane, i_plane, u0, v0, u_rpw: int, i_rpw: int,
         u = lax_ops.allgather(u_block, axis_name)
         v_block = _half_step_dense(u, i_plane, i_rpw, cfg)
         v = lax_ops.allgather(v_block, axis_name)
-        obs = jnp.isfinite(u_plane)
-        pred = jax.lax.dot_general(
-            u_block.astype(jnp.bfloat16), v.astype(jnp.bfloat16),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        tgt = (jnp.where(obs, u_plane, 0).astype(jnp.float32)
-               if not cfg.implicit else 1.0)
-        sse = jax.lax.psum(jnp.sum(jnp.where(obs, (tgt - pred) ** 2, 0.0)),
-                           axis_name)
-        cnt = jax.lax.psum(jnp.sum(obs.astype(jnp.float32)), axis_name)
-        return (u, v), jnp.sqrt(sse / jnp.maximum(cnt, 1.0))
+        with jax.named_scope("als.monitor"):
+            sse, cnt = _monitor_dense(u_block, v, u_plane, cfg)
+            sse = jax.lax.psum(sse, axis_name)
+            cnt = jax.lax.psum(cnt, axis_name)
+            rmse = jnp.sqrt(sse / jnp.maximum(cnt, 1.0))
+        return (u, v), rmse
 
     (u, v), rmse = jax.lax.scan(iteration, (u0, v0), None,
                                 length=cfg.iterations)
@@ -371,6 +496,11 @@ class ALS:
         """Host layout + H2D ONCE; returns an opaque state for
         :meth:`fit_prepared` (the KMeans/SGDMF prepare idiom — keeps host
         prep and transfers out of timed regions)."""
+        with telemetry.phase("als.prepare"):
+            return self._prepare(rows, cols, vals, num_users, num_items, seed)
+
+    def _prepare(self, rows, cols, vals, num_users: int, num_items: int,
+                 seed: int):
         from harp_tpu.models.sgd_mf import _validate_coo
 
         sess, cfg = self.session, self.config
@@ -481,12 +611,12 @@ class ALS:
         u_rpw = -(-num_users // w)
         i_rpw = -(-num_items // w)
         u_pad, i_pad = w * u_rpw, w * i_rpw
-        # build straight in bf16 (host peak = exactly the budgeted bytes);
-        # entries are already deduped, and the item plane is the transpose
-        # by construction — no second fill pass
+        # build the user plane straight in bf16 on the host; the item plane
+        # is its transpose by construction and is made ON THE DEVICE (entries
+        # are already deduped): a strided host transpose of 1.5 GB and a
+        # second transfer were half of `prepare` at the MovieLens-10M shape
         u_plane = np.full((u_pad, i_pad), np.nan, ml_dtypes.bfloat16)
         u_plane[rows, cols] = vals.astype(ml_dtypes.bfloat16)
-        i_plane = np.ascontiguousarray(u_plane.T)
         self.last_layout_stats = {
             "layout": "dense",
             "plane_bytes": 2 * u_pad * i_pad * 2,
@@ -499,7 +629,21 @@ class ALS:
         v0 = (scale * rng.random((i_pad, cfg.rank))).astype(np.float32)
         u0[num_users:] = 0.0
         v0[num_items:] = 0.0
-        key = ("dense", u_rpw, i_rpw, w, cfg.implicit)
+        key = self._dense_program(u_rpw, i_rpw)
+        u_dev = sess.scatter(jnp.asarray(u_plane, jnp.bfloat16))
+        if "transpose" not in self._fns:
+            self._fns["transpose"] = jax.jit(
+                jnp.transpose, out_shardings=sess.sharding(sess.shard()))
+        with telemetry.phase("session.run"):
+            i_dev = self._fns["transpose"](u_dev)
+        placed = (u_dev, i_dev, sess.replicate_put(u0), sess.replicate_put(v0))
+        return (key, placed, np.arange(num_users), np.arange(num_items))
+
+    def _dense_program(self, u_rpw: int, i_rpw: int):
+        """Key of the dense SPMD program at these rows per worker (built on
+        first use): planes sharded by rows, factors replicated."""
+        sess, cfg = self.session, self.config
+        key = ("dense", u_rpw, i_rpw, sess.num_workers, cfg.implicit)
         if key not in self._fns:
             self._fns[key] = sess.spmd(
                 lambda up, ip, u, v: _train_dense(up, ip, u, v, u_rpw,
@@ -507,29 +651,35 @@ class ALS:
                 in_specs=(sess.shard(), sess.shard(),
                           sess.replicate(), sess.replicate()),
                 out_specs=(sess.replicate(),) * 3)
-        placed = (sess.scatter(jnp.asarray(u_plane, jnp.bfloat16)),
-                  sess.scatter(jnp.asarray(i_plane, jnp.bfloat16)),
-                  sess.replicate_put(u0), sess.replicate_put(v0))
-        return (key, placed, np.arange(num_users), np.arange(num_items))
+        return key
 
     def train_prepared(self, state):
         """Run the compiled train program; factors stay ON DEVICE. Returns
         (u_dev, v_dev, rmse ndarray) — the benchmark timing surface (the
-        rmse fetch forces execution; the factor D2H is a one-time cost)."""
+        rmse fetch forces execution; the factor D2H is a one-time cost).
+        The last two entries of ``state[1]`` are the factors the call starts
+        from: a caller that trains in several calls hands back what the call
+        before returned."""
         import time as _time
 
         key, placed, _, _ = state
-        t0 = _time.perf_counter()
-        u, v, rmse = self._fns[key](*placed)
-        rmse = np.asarray(rmse)
-        # telemetry at the rmse fetch that was already here (per-iteration
-        # events, wall amortized over the scanned program); the manifest row
-        # pins the explicit path only — implicit jobs get no comm row
-        telemetry.record_chunk(
-            "als", start=0, losses=rmse.tolist(),
-            wall_s=_time.perf_counter() - t0,
-            ledger=(telemetry.ledger_for("als")
-                    if not self.config.implicit else None))
+        with telemetry.phase("als.call"):
+            step = self._fns[key]
+            t0 = _time.perf_counter()
+            with telemetry.phase("step.dispatch"):
+                u, v, rmse = step(*placed)
+            telemetry.record_program("als.fit", step, placed)
+            with telemetry.phase("step.fetch"):
+                rmse = np.asarray(rmse)
+            # telemetry at the rmse fetch that was already here
+            # (per-iteration events, wall amortized over the scanned
+            # program); the manifest row pins the explicit path only —
+            # implicit jobs get no comm row
+            telemetry.record_chunk(
+                "als", start=0, losses=rmse.tolist(),
+                wall_s=_time.perf_counter() - t0,
+                ledger=(telemetry.ledger_for("als")
+                        if not self.config.implicit else None))
         return u, v, rmse
 
     def fit_prepared(self, state

@@ -794,114 +794,201 @@ def use_flash_head_pack(h: int, dh: int, dv: int) -> bool:
 # 30 ms per (8192, 32, 32) solve pair on v5e — yet the solve is only ~180
 # MFLOP, i.e. the lowering runs at ~0.006 TFLOP/s. The fix is a LAYOUT move,
 # not a FLOP move: put the BATCH on the 128-lane axis ((K, K, B) tiles,
-# matrices ride sublanes/leading dim) so every step of an unrolled
-# outer-product Cholesky + the two substitutions is a full-width VPU
-# elementwise op across B independent systems. No MXU involvement at all —
-# the MXU was never the right unit for K≤64 systems; the VPU at full lane
-# occupancy is. HBM traffic is one read of A (the only O(N·K²) term), so the
-# kernel is bandwidth-bound at ~40 µs for the bench shape.
+# matrices ride sublanes/leading dim) so every step of an outer-product
+# Cholesky + the two substitutions is a full-width VPU elementwise op across
+# B independent systems. No MXU involvement at all — the MXU was never the
+# right unit for K x K systems of this size; the VPU at full lane occupancy
+# is.
+#
+# Only the sublane groups of 8 columns are unrolled; the columns of a group
+# and the trailing update of a column (the rows below it: a dynamic index on
+# the leading dim is free) are loops, and the update touches only the groups
+# from the column's own on: at K = 104 that is 52 k vreg updates per 128
+# systems where the whole-matrix rank-1 update of the r4 kernel made 140 k,
+# in a program of 13 + 13 loop bodies where the r4 kernel unrolled every
+# column (with the columns unrolled the executable took 8 s to load from the
+# compile cache at every start, PERF.md, Findings, PR 27).
 #
 # Reference role: DAAL's cblas/LAPACK POTRF+POTRS behind
 # daal_als/ALSDaalCollectiveMapper.java:49's train steps.
 
-
-def _chol_solve_kernel(a_ref, b_ref, x_ref, *, k: int):
-    """One batch tile: A (k, k, B) SPD, b (k, B) → x (k, B).
-
-    Unrolled outer-product Cholesky: at step j, column j of the running
-    Schur complement IS column j of L (after scaling); the rank-1 update
-    A ← A − l_j l_jᵀ touches only unfinished rows/cols because l_j is
-    masked to zero above the diagonal. Forward/backward substitution reuse
-    the same columns; every op is (k, B) or (k, k, B) elementwise."""
-    a = a_ref[...].astype(jnp.float32)            # (k, k, B)
-    b = b_ref[...].astype(jnp.float32)            # (k, B)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)  # (k, 1) row index
-
-    cols = []
-    for j in range(k):
-        col = a[:, j, :]                          # (k, B) Schur column j
-        dinv = jax.lax.rsqrt(col[j:j + 1, :])     # (1, B); SPD ⇒ diag > 0
-        lj = jnp.where(rows >= j, col * dinv, 0.0)
-        cols.append(lj)
-        if j + 1 < k:
-            a = a - lj[:, None, :] * lj[None, :, :]
-
-    # forward substitution  L y = b  (l_j[j] is the diag entry sqrt(d))
-    r = b
-    ys = []
-    for j in range(k):
-        yj = r[j:j + 1, :] / cols[j][j:j + 1, :]  # (1, B)
-        ys.append(yj)
-        if j + 1 < k:
-            r = r - cols[j] * yj
-    y = jnp.concatenate(ys, axis=0)               # (k, B)
-
-    # backward substitution  Lᵀ x = y: equation i is Σ_p L[p, i] x_p, so when
-    # x_p lands, subtract ROW p of L (over column index i) from the residual
-    lfull = jnp.stack(cols, axis=1)               # (k_row, k_col, B)
-    r = y
-    xs = [None] * k
-    for p in range(k - 1, -1, -1):
-        xp = r[p:p + 1, :] / cols[p][p:p + 1, :]
-        xs[p] = xp
-        if p:
-            r = r - lfull[p, :, :] * xp
-    x_ref[...] = jnp.concatenate(xs, axis=0)
+# what the kernel may ask of VMEM (v5e: 128 MiB physical)
+SPD_SOLVE_VMEM_LIMIT = 100 * 1024 * 1024
+SPD_SOLVE_NAME = "als_spd_solve"
+# systems per grid step, widest first
+SPD_SOLVE_TILES = (4 * lane_pack.LANES, 2 * lane_pack.LANES, lane_pack.LANES)
 
 
-def spd_solve_pallas(a: jax.Array, b: jax.Array, tile_b: int = 256,
+def spd_solve_vmem_bytes(k: int, tile_b: int) -> int:
+    """VMEM one grid step of the solve holds, from above: the (K, K, B)
+    operand block twice (the pipeline's two buffers) and once more as the
+    working copy the factorisation overwrites, a dozen (K, B) rows (b, x,
+    the reciprocal diagonal, each double-buffered or live), 2 MiB."""
+    kp = lane_pack.round_up(k, 8)
+    return 4 * tile_b * (3 * kp * kp + 12 * kp) + (2 << 20)
+
+
+def spd_solve_tile(k: int) -> int:
+    """Systems per grid step: the widest of 512 / 256 / 128 lanes whose
+    estimate fits :data:`SPD_SOLVE_VMEM_LIMIT`, 0 where none does. Wider is
+    faster (8,192 systems of 100 x 100 on a v5e: 5.70 / 3.94 / 3.18 ms at
+    128 / 256 / 512; XLA's ``solve(assume_a="pos")`` 113 ms; PERF.md,
+    Findings, PR 27): a row's loop overhead is shared by more systems."""
+    return next((t for t in SPD_SOLVE_TILES
+                 if spd_solve_vmem_bytes(k, t) <= SPD_SOLVE_VMEM_LIMIT), 0)
+
+
+def _chol_solve_kernel(a_ref, b_ref, x_ref, l_ref, dinv_ref, col_ref, *,
+                       k: int):
+    """One batch tile: A (k, k, B) SPD with both triangles, b (k, B) →
+    x (k, B); k a multiple of 8.
+
+    Right-looking Cholesky on a working copy ``l_ref``. At column j, row j of
+    the running Schur complement (= its column j: the trailing block is kept
+    symmetric) scaled by 1/sqrt(d_j) is column j of L; it overwrites row j,
+    which nothing reads again, so ``l_ref[j, c]`` ends as ``L[c, j]``. Row
+    i > j then loses ``L[i, j] * L[:, j]`` on the sublane groups that hold
+    columns >= j. ``x_ref`` carries the right-hand side through the forward
+    substitution in the same pass (``x_ref[j]`` ends as ``y_j * sqrt(d_j)``);
+    the backward substitution is a masked dot of row p with what is solved.
+
+    The sublane GROUPS of 8 columns are unrolled, the 8 columns of a group
+    and the row groups below a column are loops: a row is a dynamic index on
+    the leading dim, a group a static aligned slice. Mosaic neither loads
+    nor stores a single DYNAMIC sublane, so the one sublane of a column
+    inside its group is taken by a mask (``pick``) and written by a select,
+    and the column's multipliers L[i, j] are laid out a group per leading
+    index in ``col_ref`` (k/8, 8, B), where row i's is a static sublane."""
+    tb = b_ref.shape[-1]
+    groups = k // 8
+    l_ref[...] = a_ref[...].astype(jnp.float32)
+    x_ref[...] = b_ref[...].astype(jnp.float32)
+    lane8 = jax.lax.broadcasted_iota(jnp.int32, (8, tb), 0)
+
+    def pick(group, t):
+        """Sublane t of an (8, B) group, as (1, B)."""
+        return jnp.sum(jnp.where(lane8 == t, group, 0.0), axis=0,
+                       keepdims=True)
+
+    for g in range(groups):                   # a sublane group of columns
+        c0 = 8 * g
+        head = slice(c0, c0 + 8)
+        sub = c0 + jax.lax.broadcasted_iota(jnp.int32, (k - c0, tb), 0)
+
+        def column(t, carry, g=g, c0=c0, head=head, sub=sub):
+            j = c0 + t
+            dinv = jax.lax.rsqrt(pick(l_ref[j, head, :], t))  # SPD: diag > 0
+            lj = jnp.where(sub >= j, l_ref[j, c0:, :] * dinv, 0.0)
+            l_ref[j, c0:, :] = lj
+            dinv_ref[head, :] = jnp.where(lane8 == t, dinv, dinv_ref[head, :])
+            below = jnp.where(sub > j, lj, 0.0)           # L[i, j], i > j
+            # forward substitution: r_i -= L[i, j] y_j below the diagonal
+            x_ref[c0:, :] = x_ref[c0:, :] - below * (
+                pick(x_ref[head, :], t) * dinv)
+            for gi in range(g, groups):
+                col_ref[gi] = below[8 * (gi - g):8 * (gi - g + 1), :]
+
+            def trailing(gi, carry):
+                for s in range(8):            # rows up to j lose nothing
+                    i = 8 * gi + s
+                    l_ref[i, c0:, :] = (l_ref[i, c0:, :]
+                                        - col_ref[gi, s:s + 1, :] * lj)
+                return carry
+
+            return jax.lax.fori_loop(g, groups, trailing, carry)
+
+        jax.lax.fori_loop(0, 8, column, 0)
+
+    # backward substitution  Lᵀ x = y: x_p = (y_p − Σ_{c>p} L[c, p] x_c)/L[p, p]
+    for c0 in range(k - 8, -1, -8):
+        head = slice(c0, c0 + 8)
+        sub = c0 + jax.lax.broadcasted_iota(jnp.int32, (k - c0, tb), 0)
+
+        def unknown(step, carry, c0=c0, head=head, sub=sub):
+            t = 7 - step
+            p = c0 + t
+            dinv = pick(dinv_ref[head, :], t)
+            solved = jnp.where(sub > p, l_ref[p, c0:, :] * x_ref[c0:, :], 0.0)
+            xp = (pick(x_ref[head, :], t) * dinv
+                  - jnp.sum(solved, axis=0, keepdims=True)) * dinv
+            x_ref[head, :] = jnp.where(lane8 == t, xp, x_ref[head, :])
+            return carry
+
+        jax.lax.fori_loop(0, 8, unknown, 0)
+
+
+def spd_solve_lanes(at: jax.Array, bt: jax.Array, tile_b: Optional[int] = None,
+                    interpret: bool = False) -> jax.Array:
+    """Solve batch-last SPD systems: at (K, K, N) float32 with both
+    triangles, bt (K, N) → (K, N); K a multiple of 8. The layout the kernel
+    reads: a caller that builds its systems batch-last (ALS's dense
+    half-step) pays no relayout. N is padded up to the lane tile with
+    identity systems."""
+    k, n = bt.shape
+    if at.shape != (k, k, n) or k % 8:
+        raise ValueError(f"spd_solve_lanes: at {at.shape} vs bt {bt.shape}")
+    tile_b = tile_b or spd_solve_tile(k)
+    if not tile_b:
+        raise ValueError(f"spd_solve_lanes: k = {k} does not fit VMEM")
+    npad = lane_pack.round_up(n, tile_b)
+    if npad != n:
+        at = jnp.concatenate([at, jnp.broadcast_to(
+            jnp.eye(k, dtype=at.dtype)[:, :, None], (k, k, npad - n))], axis=2)
+        bt = jnp.pad(bt, ((0, 0), (0, npad - n)))
+    xt = pl.pallas_call(
+        functools.partial(_chol_solve_kernel, k=k),
+        grid=(npad // tile_b,),
+        in_specs=[
+            pl.BlockSpec((k, k, tile_b), lambda i: (0, 0, i)),
+            pl.BlockSpec((k, tile_b), lambda i: (0, i)),
+        ],
+        out_specs=pl.BlockSpec((k, tile_b), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((k, npad), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((k, k, tile_b), jnp.float32),
+                        pltpu.VMEM((k, tile_b), jnp.float32),
+                        pltpu.VMEM((k // 8, 8, tile_b), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=min(SPD_SOLVE_VMEM_LIMIT, max(
+                32 << 20, spd_solve_vmem_bytes(k, tile_b)))),
+        interpret=interpret,
+        name=SPD_SOLVE_NAME,
+    )(at.astype(jnp.float32), bt.astype(jnp.float32))
+    return xt[:, :n]
+
+
+def spd_solve_pallas(a: jax.Array, b: jax.Array, tile_b: Optional[int] = None,
                      interpret: bool = False) -> jax.Array:
     """Solve batched SPD systems ``a @ x = b``: a (N, K, K), b (N, K) → (N, K).
 
     Pads K up to a sublane multiple (identity diagonal, zero rhs — padded
-    components solve to 0 and never couple) and N up to a lane-tile multiple
-    (identity systems). The (N, K, K) → (K, K, N) transpose that puts the
-    batch on lanes is one HBM-bound XLA pass, ~µs at ALS shapes."""
+    components solve to 0 and never couple) and moves the batch onto the
+    lanes: the (N, K, K) → (K, K, N) transpose is one HBM-bound XLA pass
+    (:func:`spd_solve_lanes` takes operands that are built batch-last)."""
     n, k = b.shape
     if a.shape != (n, k, k):
         raise ValueError(f"spd_solve_pallas: a {a.shape} vs b {b.shape}")
-    kp = max(8, -(-k // 8) * 8)
-    npad = -(-n // tile_b) * tile_b
+    kp = lane_pack.round_up(k, 8)
     if kp != k:
         a = jnp.pad(a, ((0, 0), (0, kp - k), (0, kp - k)))
-        a = a + jnp.pad(jnp.zeros((k,), a.dtype), (0, kp - k),
-                        constant_values=1.0) * jnp.eye(kp, dtype=a.dtype)[None]
+        a = a + jnp.diag((jnp.arange(kp) >= k).astype(a.dtype))[None]
         b = jnp.pad(b, ((0, 0), (0, kp - k)))
-    if npad != n:
-        eye_tail = jnp.broadcast_to(jnp.eye(kp, dtype=a.dtype),
-                                    (npad - n, kp, kp))
-        a = jnp.concatenate([a, eye_tail], axis=0)
-        b = jnp.pad(b, ((0, npad - n), (0, 0)))
-    at = jnp.transpose(a, (1, 2, 0)).astype(jnp.float32)  # (K, K, N)
-    bt = jnp.transpose(b, (1, 0)).astype(jnp.float32)     # (K, N)
-    grid = npad // tile_b
-    kernel = functools.partial(_chol_solve_kernel, k=kp)
-    xt = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((kp, kp, tile_b), lambda i: (0, 0, i)),
-            pl.BlockSpec((kp, tile_b), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((kp, tile_b), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((kp, npad), jnp.float32),
-        interpret=interpret,
-    )(at, bt)
-    return jnp.transpose(xt, (1, 0))[:n, :k]
+    xt = spd_solve_lanes(jnp.transpose(a, (1, 2, 0)), jnp.transpose(b, (1, 0)),
+                         tile_b, interpret)
+    return jnp.transpose(xt, (1, 0))[:, :k]
 
 
 def use_spd_solve_pallas(k: int) -> bool:
-    """Dispatch predicate: default ON for TPU at the small ranks where the
-    XLA batched-solve lowering craters (K ≤ 64 unrolls to a modest op count
-    and the (K, K, B) working set stays in VMEM); opt out with
-    HARP_ALS_PALLAS=0."""
+    """Dispatch predicate: default ON for TPU wherever a lane tile of the
+    (K, K, B) working set fits VMEM (:func:`spd_solve_tile`: K up to ~250);
+    opt out with HARP_ALS_PALLAS=0."""
     import os
 
     if os.environ.get("HARP_ALS_PALLAS", "1") == "0":
         return False
     if jax.default_backend() != "tpu":
         return False
-    return k <= 64
+    return spd_solve_tile(k) > 0
 
 
 def use_dense_mf_pallas(cpb: int, s_rows: int, k: int) -> bool:

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding
 
-from harp_tpu.models import kmeans, sgd_mf
+from harp_tpu.models import als, kmeans, sgd_mf
 from harp_tpu.session import HarpSession
 from harp_tpu.telemetry import scopes
 
@@ -98,6 +98,29 @@ def _sgdmf_text(topo, workers: int, fused: bool = False) -> str:
     return model._compiled[key].lower(*args).compile().as_text()
 
 
+ALS_SHAPE = (71_567, 10_681, 100)     # the cell als-k100.ml10m, whole
+
+
+def _als_step(topo):
+    """The compiled dense ALS iteration at the cell's full shape on one
+    described chip, with the solve kernel ``solver="auto"`` picks there (the
+    dispatch asks ``jax`` for its backend, which is the CPU here)."""
+    from harp_tpu.ops import pallas_kernels
+
+    m, n, k = ALS_SHAPE
+    sess = HarpSession(num_workers=1, devices=topo.devices[:1])
+    model = als.ALS(sess, als.ALSConfig(
+        rank=k, lam=0.05, alpha=40.0, iterations=1, layout="dense"))
+    key = model._dense_program(m, n)
+    args = (_shaped(sess, (m, n), jnp.bfloat16, sess.shard()),
+            _shaped(sess, (n, m), jnp.bfloat16, sess.shard()),
+            _shaped(sess, (m, k), jnp.float32, sess.replicate()),
+            _shaped(sess, (n, k), jnp.float32, sess.replicate()))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pallas_kernels, "use_spd_solve_pallas", lambda k: True)
+        return model._fns[key].lower(*args).compile()
+
+
 def _loop_kernels(text: str):
     """``(instruction, opcode)`` of the kernels that stand directly in a
     ``while`` body of the compiled text, nested loops included."""
@@ -133,6 +156,10 @@ PROGRAMS = {
     "sgdmf-4-fused": (lambda t: _sgdmf_text(t, 4, fused=True),
                       {"sgdmf.select", "sgdmf.stripes", "sgdmf.rmse",
                        "rotation.hop"}),
+    # the ALS iteration at the cell's full shape; its one kernel is the solve
+    "als-1-fused": (lambda t: _als_step(t).as_text(),
+                    {"als.outer", "als.gram", "als.rhs", "als.solve",
+                     "als.monitor"}),
 }
 
 
@@ -214,6 +241,52 @@ def test_the_fused_hop_compiles_at_the_cells_stored_geometry(
         shaped((g.nmb, g.s_store)),
         shaped((g.nmb, g.cpb_store))).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_the_als_iteration_fits_the_chip_at_the_cells_full_shape(
+        topo, no_compile_cache):
+    """The row-blocked iteration lowers for a v5e at 71,567 x 10,681, rank
+    100: the step's scratch stays inside the budget the blocks are derived
+    from, planes and scratch inside the chip, and the systems reach the
+    solve kernel batch-last from the product itself (no relayout of the
+    normal equations stands between them)."""
+    from harp_tpu.ops import pallas_kernels as pk
+
+    step = _als_step(topo)
+    stats = step.memory_analysis()
+    assert stats.temp_size_in_bytes <= als.DENSE_SCRATCH_BYTES
+    assert (stats.temp_size_in_bytes + stats.argument_size_in_bytes
+            + stats.output_size_in_bytes) < 8e9
+    text = step.as_text()
+    solves = [line for line in text.splitlines()
+              if "custom-call(" in line and pk.SPD_SOLVE_NAME in line]
+    assert len(solves) == 2, solves                 # one a side
+    mapped = scopes.scope_map(text)
+    for line in solves:
+        name, _ = scopes._instruction(line.strip())
+        assert mapped[name] == "als.solve"
+        operand = re.search(r"custom-call\(%([\w.\-]+)", line).group(1)
+        assert "convolution" in operand or "pad" in operand, line
+
+
+@pytest.mark.parametrize("rows", [14_336, 10_752])
+def test_the_solve_kernel_compiles_at_rank_100(topo, no_compile_cache, rows):
+    """Mosaic accepts the batched Cholesky at k = 100 (stored 104) on a
+    row block of the cell, at the lane tile the dispatch picks and under the
+    VMEM limit its estimate gives."""
+    from jax.sharding import SingleDeviceSharding
+
+    from harp_tpu.ops import pallas_kernels as pk
+
+    tile = pk.spd_solve_tile(100)
+    assert tile >= 128
+    assert pk.spd_solve_vmem_bytes(100, tile) <= pk.SPD_SOLVE_VMEM_LIMIT
+    one = SingleDeviceSharding(topo.devices[0])
+    text = jax.jit(pk.spd_solve_lanes).lower(
+        jax.ShapeDtypeStruct((104, 104, rows), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((104, rows), jnp.float32, sharding=one)
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and pk.SPD_SOLVE_NAME in text
 
 
 def test_the_ring_hop_is_a_collective_permute_under_its_own_name(compiled):
