@@ -45,9 +45,11 @@ Two data layouts, selected by density (``SGDMFConfig.layout``):
   stripe, column block and the rank padded to the fused hop kernel's tiles
   (:class:`DenseGeometry`: the mini-batches, the first model and every table
   that leaves the device keep the logical sizes), so that on TPU a hop is
-  ``pallas_kernels.dense_mf_hop_pallas``' single pass over the slab; where
-  no tile fits VMEM, and off the TPU, the XLA stripe scan runs on the same
-  arrays.
+  ``pallas_kernels.dense_mf_hop_pallas``' single pass over the resident
+  block, which the kernel reads straight out of the worker's whole slab;
+  where no tile fits VMEM, and off the TPU, the XLA stripe scan runs on the
+  same arrays, behind a ``jnp.take`` of the block
+  (``last_layout_stats["slab_pick"]``).
 * **sparse** (padded COO buckets): for data too sparse/large to densify. Ratings
   are pre-sorted on the host into a (W workers × B column-blocks) grid of padded
   COO buckets; the inner loop is gather → rank-K dot → two scatter-adds. Hot
@@ -293,6 +295,17 @@ class DenseGeometry:
         return r_loc // self.s_rows * self.s_store + r_loc % self.s_rows
 
 
+def _slab_pick(fused: bool, n_blocks: int) -> str:
+    """Where a dense hop picks its resident block out of the worker's slab:
+    ``in_kernel`` (the fused hop's index map reads the block index: nothing
+    is copied), ``copy`` (the XLA stripe scan behind a ``jnp.take``, which
+    materialises the block every hop) or ``static`` (the XLA scan over a
+    slab of one block)."""
+    if fused:
+        return "in_kernel"
+    return "copy" if n_blocks > 1 else "static"
+
+
 def _repad(a: np.ndarray, rows: int, rows_to: int, k_to: int) -> np.ndarray:
     """``(..., n * rows, K)`` -> ``(..., n * rows_to, k_to)``: every run of
     ``rows`` rows cut or zero-padded at its end to ``rows_to``, the last
@@ -467,6 +480,7 @@ class SGDMF:
         bf = jnp.bfloat16
         col_tile = self._hop_tile(g, nmb)
         fused = col_tile > 0
+        slab_pick = _slab_pick(fused, self.config.num_slices * w)
         # in-kernel ring hop (r10): fused dense kernel + fused_dma + a plain
         # (unquantized) multi-worker wire (quant takes the encode path) on
         # the 1-slice schedule ONLY — the kernel's blocking send+wait would
@@ -486,26 +500,23 @@ class SGDMF:
             v_slab, row_cnt, col_cnt = data
 
             @scoped("sgdmf.stripes")
-            def _run_stripes_pallas(w_local, h_block, sse, cnt, vb, rcnt,
+            def _run_stripes_pallas(w_local, h_block, sse, cnt, block, rcnt,
                                     ccnt, col_tile, ring_hop):
                 # fused hop kernel: pred/G stay in VMEM → one slab read per
                 # hop instead of XLA's ~5 slab-sized passes (pallas_kernels
-                # module doc). Factors ride transposed (K, rows). With
-                # ring_hop the kernel ALSO ships the updated H to the ring
-                # neighbor (VMEM → remote HBM, ops/ring_dma) and the
+                # module doc). It takes the WHOLE slab and picks the
+                # resident block in its own index map: no copy of the block
+                # stands in front of it. Factors ride transposed (K, rows).
+                # With ring_hop the kernel ALSO ships the updated H to the
+                # ring neighbor (VMEM → remote HBM, ops/ring_dma) and the
                 # returned block is the received one — the rotation scan
                 # then runs shift=0 (body_hops).
-                if ring_hop:
-                    w_t, _h_t, hop_sse, h_next = (
-                        pallas_kernels.dense_mf_hop_pallas(
-                            vb, w_local.T, h_block.T,
-                            rcnt.reshape(nmb, s_rows), ccnt, lr, lam,
-                            col_tile=col_tile, ring_hop=True))
-                    return (w_t.T, h_next.T, sse + hop_sse,
-                            cnt + jnp.sum(ccnt))
-                w_t, h_t, hop_sse = pallas_kernels.dense_mf_hop_pallas(
-                    vb, w_local.T, h_block.T, rcnt.reshape(nmb, s_rows),
-                    ccnt, lr, lam, col_tile=col_tile)
+                outs = pallas_kernels.dense_mf_hop_pallas(
+                    v_slab, block, w_local.T, h_block.T,
+                    rcnt.reshape(nmb, s_rows), ccnt, lr, lam,
+                    col_tile=col_tile, ring_hop=ring_hop)
+                w_t, hop_sse, h_t = outs[0], outs[2], outs[-1 if ring_hop
+                                                           else 1]
                 return (w_t.T, h_t.T, sse + hop_sse,
                         cnt + jnp.sum(ccnt))
 
@@ -546,27 +557,33 @@ class SGDMF:
 
             def update_bucket(w_local, h_block, sse, cnt, bucket_id):
                 # runs when jax traces, only: which update this program's
-                # hops run
+                # hops run, and where the resident block is picked
                 metrics.DEFAULT.count(
                     "sgd_mf.hops.fused" if fused else "sgd_mf.hops.xla")
+                if slab_pick != "static":
+                    metrics.DEFAULT.count(
+                        "sgd_mf.picks.in_kernel" if fused
+                        else "sgd_mf.picks.copied")
+                # single-block mesh (W=1, 1 slice): static index — the
+                # dynamic-slice would copy the full slab (GBs) every hop
+                single = v_slab.shape[0] == 1
+
+                def pick(a):
+                    return a[0] if single else jnp.take(a, bucket_id, axis=0)
+
                 with jax.named_scope("sgdmf.select"):
-                    if v_slab.shape[0] == 1:
-                        # single-block mesh (W=1, 1 slice): static index —
-                        # the dynamic-slice would copy the full slab (GBs)
-                        # every hop
-                        vb, rcnt, ccnt = v_slab[0], row_cnt[0], col_cnt[0]
-                    else:
-                        vb = jnp.take(v_slab, bucket_id, axis=0)  # (rpw, cpb)
-                        rcnt = jnp.take(row_cnt, bucket_id, axis=0)
-                        ccnt = jnp.take(col_cnt, bucket_id, axis=0)
+                    # the fused kernel reads its block out of the whole slab
+                    vb = None if fused else pick(v_slab)      # (rpw, cpb)
+                    rcnt, ccnt = pick(row_cnt), pick(col_cnt)
                     # col counts are stored at the finest stripe granularity
                     # (nmb_fine, cpb); coarser budgets sum adjacent fine
                     # stripes
                     ccnt = ccnt.reshape(nmb, nmb_fine // nmb, cpb).sum(axis=1)
                 if fused:
-                    return _run_stripes_pallas(w_local, h_block, sse, cnt,
-                                               vb, rcnt, ccnt, col_tile,
-                                               ring_hop)
+                    return _run_stripes_pallas(
+                        w_local, h_block, sse, cnt,
+                        0 if single else bucket_id, rcnt, ccnt, col_tile,
+                        ring_hop)
                 return _run_stripes(w_local, h_block, sse, cnt, vb, rcnt,
                                     ccnt)
 
@@ -797,6 +814,7 @@ class SGDMF:
             # the configured budget run
             "pad_overhead": rpw_st * cpb_st / (rpw * cpb),
             "fused_hop": col_tile > 0, "col_tile": col_tile,
+            "slab_pick": _slab_pick(col_tile > 0, n_blocks),
         }
 
         # the first model is drawn at the LOGICAL sizes (what the

@@ -179,12 +179,23 @@ def kmeans_stats_pallas(
 # H lives ENTIRELY in VMEM for the whole kernel (full-array out block,
 # initialized from the input at step 0): stripe i+1 reads stripe i's updates
 # with no HBM round trip and no reliance on write-back/prefetch ordering.
+#
+# The kernel is handed the worker's WHOLE slab, (n_blocks, rpw, cpb), and
+# the resident block's index as a prefetched scalar: the slab's index map
+# reads the leading (squeezed) block coordinate from it, so the tile DMAs
+# are the ones a single block would get, from an offset base. Which block is
+# resident changes every hop with (wid - t) % W, a value only the device
+# knows: picked in front of the kernel (`jnp.take`) XLA materialises the
+# block, 481 MB read and 481 MB written a hop at MovieLens-20M's shape on
+# four chips, which took longer than the hop itself (5.84 against 4.20 ms
+# an epoch: PERF.md, Findings, PR 28). The body sees an (s, col_tile) tile.
 
 
-def _dense_mf_hop_kernel(v_ref, wt_ref, rc_ref, cc_ref, ht_in_ref,
+def _dense_mf_hop_kernel(block_ref, v_ref, wt_ref, rc_ref, cc_ref, ht_in_ref,
                          wt_out_ref, ht_ref, sse_ref, *refs,
                          lr: float, lam: float, col_tile: int, n_ct: int,
                          nmb: int = 1, ring: Optional[dict] = None):
+    del block_ref                                 # the index maps read it
     if ring is not None:
         hn_ref, dw_ref, send_sem, recv_sem = refs
     else:
@@ -279,13 +290,21 @@ def dense_mf_col_tile(cpb: int, s_rows: int, k: int) -> int:
                      k, cpb, s_rows, ct) <= DENSE_MF_VMEM_LIMIT), 0)
 
 
-def dense_mf_hop_pallas(vb: jax.Array, w_t: jax.Array, h_t: jax.Array,
-                        rc2: jax.Array, cc2: jax.Array, lr: float, lam: float,
-                        col_tile: int = 256, interpret: bool = False,
-                        ring_hop: bool = False, axis_name: str = "workers"):
-    """One dense-MF hop. vb (rpw, cpb) bf16 NaN-encoded; w_t (K, rpw) f32;
-    h_t (K, cpb) f32; rc2 (nmb, s_rows) and cc2 (nmb, cpb) regularizer
+def dense_mf_hop_pallas(v_slab: jax.Array, block, w_t: jax.Array,
+                        h_t: jax.Array, rc2: jax.Array, cc2: jax.Array,
+                        lr: float, lam: float, col_tile: int = 256,
+                        interpret: bool = False, ring_hop: bool = False,
+                        axis_name: str = "workers"):
+    """One dense-MF hop over block ``block`` of the worker's slab. v_slab
+    (n_blocks, rpw, cpb) bf16 NaN-encoded, the WHOLE slab; block an int32
+    scalar (traced or not) in [0, n_blocks); w_t (K, rpw) f32; h_t (K, cpb)
+    f32; rc2 (nmb, s_rows) and cc2 (nmb, cpb) the picked block's regularizer
     counts. Returns (w_t_new, h_t_new, sse). nmb = rc2.shape[0].
+
+    The block is picked in the slab's index map, from the prefetched scalar
+    (kernel comment): no copy of the block stands in front of the kernel,
+    and a block the index does not name is never read. A ring of one passes
+    its (1, rpw, cpb) slab and 0.
 
     ``ring_hop`` (TPU only, inside shard_map over ``axis_name``): also
     ring-ship the UPDATED H block to the right neighbor from inside the
@@ -299,8 +318,8 @@ def dense_mf_hop_pallas(vb: jax.Array, w_t: jax.Array, h_t: jax.Array,
 
     nmb, s = rc2.shape
     k, rpw = w_t.shape
-    cpb = vb.shape[1]
-    if rpw != nmb * s or vb.shape[0] != rpw or h_t.shape[1] != cpb:
+    _, slab_rows, cpb = v_slab.shape
+    if rpw != nmb * s or slab_rows != rpw or h_t.shape[1] != cpb:
         raise ValueError("dense_mf_hop_pallas: inconsistent shapes")
     if cpb % col_tile or s % 128 or k % 8 or col_tile % 128:
         raise ValueError("dense_mf_hop_pallas: tiling constraints violated")
@@ -320,10 +339,12 @@ def dense_mf_hop_pallas(vb: jax.Array, w_t: jax.Array, h_t: jax.Array,
     rc8 = jnp.broadcast_to(rc2[:, None, :], (nmb, 8, s)).reshape(nmb * 8, s)
     cc8 = jnp.broadcast_to(cc2[:, None, :],
                            (nmb, 8, cpb)).reshape(nmb * 8, cpb)
+    # every index map takes the prefetched block index last; the slab's
+    # alone reads it
     out_specs = [
-        pl.BlockSpec((k, s), lambda i, j: (0, i)),              # w_t_new
-        pl.BlockSpec((k, cpb), lambda i, j: (0, 0)),            # h_t_new
-        pl.BlockSpec((1, 128), lambda i, j: (0, 0)),            # sse
+        pl.BlockSpec((k, s), lambda i, j, b: (0, i)),           # w_t_new
+        pl.BlockSpec((k, cpb), lambda i, j, b: (0, 0)),         # h_t_new
+        pl.BlockSpec((1, 128), lambda i, j, b: (0, 0)),         # sse
     ]
     out_shape = [
         jax.ShapeDtypeStruct((k, rpw), jnp.float32),
@@ -339,23 +360,28 @@ def dense_mf_hop_pallas(vb: jax.Array, w_t: jax.Array, h_t: jax.Array,
         from harp_tpu.ops import ring_dma as _rd
 
         params["collective_id"] = _rd.COLLECTIVE_IDS["dense_mf_ring"]
-    outs = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                                  # block
         grid=(nmb, n_ct),
         in_specs=[
-            pl.BlockSpec((s, col_tile), lambda i, j: (i, j)),       # vb
-            pl.BlockSpec((k, s), lambda i, j: (0, i)),              # w_t
-            pl.BlockSpec((8, s), lambda i, j: (i, 0)),              # rc8
-            pl.BlockSpec((8, col_tile), lambda i, j: (i, j)),       # cc8
-            pl.BlockSpec((k, cpb), lambda i, j: (0, 0)),            # h_t full
+            pl.BlockSpec((None, s, col_tile),
+                         lambda i, j, b: (b[0], i, j)),         # v_slab
+            pl.BlockSpec((k, s), lambda i, j, b: (0, i)),       # w_t
+            pl.BlockSpec((8, s), lambda i, j, b: (i, 0)),       # rc8
+            pl.BlockSpec((8, col_tile), lambda i, j, b: (i, j)),  # cc8
+            pl.BlockSpec((k, cpb), lambda i, j, b: (0, 0)),     # h_t full
         ],
         out_specs=out_specs,
-        out_shape=out_shape,
         scratch_shapes=scratch_shapes,
+    )
+    outs = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(**params),
         interpret=interpret,
         name="dense_mf_hop",
-    )(vb, w_t, rc8, cc8, h_t)
+    )(jnp.asarray(block, jnp.int32).reshape(1), v_slab, w_t, rc8, cc8, h_t)
     if ring is not None:
         w_t_new, h_t_new, sse128, h_next = outs
         return w_t_new, h_t_new, jnp.sum(sse128), h_next

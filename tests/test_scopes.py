@@ -13,6 +13,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding
 
@@ -121,21 +122,53 @@ def _als_step(topo):
         return model._fns[key].lower(*args).compile()
 
 
-def _loop_kernels(text: str):
-    """``(instruction, opcode)`` of the kernels that stand directly in a
-    ``while`` body of the compiled text, nested loops included."""
+def _loop_lines(text: str):
+    """The instruction lines of every ``while`` body of the compiled text,
+    nested loops included."""
     bodies = set(re.findall(r"body=%([\w.\-]+)", text))
     out, computation = [], None
     for line in text.splitlines():
-        m = scopes._INSTRUCTION.match(line)
-        if m is None:
+        if scopes._INSTRUCTION.match(line) is None:
             c = scopes._COMPUTATION.match(line)
             computation = c.group(1) if c else computation
-            continue
+        elif computation in bodies:
+            out.append(line)
+    return out
+
+
+def _loop_kernels(text: str):
+    """``(instruction, opcode)`` of the kernels that stand directly in a
+    ``while`` body of the compiled text."""
+    out = []
+    for line in _loop_lines(text):
         name, opcode = scopes._instruction(line.strip())
         opcode = re.sub(r"-(start|done)$", "", opcode)
-        if computation in bodies and opcode in KERNELS:
+        if opcode in KERNELS:
             out.append((name, opcode))
+    return out
+
+
+def _bf16_shape(shape) -> str:
+    return "bf16[" + ",".join(str(n) for n in shape) + "]"
+
+
+def _block_sized_bf16(lines, cells: int):
+    """The instructions among ``lines`` that yield a bf16 array of at least
+    ``cells`` elements: a copy of a slab's block, by whatever name (``(name,
+    opcode, shape)`` each). What only renames a buffer (a parameter, a
+    tuple or its element, a loop's carry, a bitcast) yields nothing new."""
+    out = []
+    for line in lines:
+        if scopes._INSTRUCTION.match(line) is None:
+            continue
+        name, opcode = scopes._instruction(line.strip())
+        if opcode in ("parameter", "get-tuple-element", "bitcast", "tuple",
+                      "while"):
+            continue
+        result = line.split(" = ", 1)[1].split(opcode + "(", 1)[0]
+        for dims in re.findall(r"bf16\[([\d,]+)\]", result):
+            if np.prod([int(n) for n in dims.split(",")]) >= cells:
+                out.append((name, opcode, dims))
     return out
 
 
@@ -232,15 +265,25 @@ def test_the_fused_hop_compiles_at_the_cells_stored_geometry(
     def shaped(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    hop = jax.jit(lambda vb, wt, ht, rc, cc: pk.dense_mf_hop_pallas(
-        vb, wt, ht, rc, cc, 1e-4, 0.05, col_tile=tile))
+    # the worker's whole slab (on four chips its four blocks: 1.9 GB
+    # described, nothing allocated) and a traced block index
+    hop = jax.jit(lambda slab, b, wt, ht, rc, cc: pk.dense_mf_hop_pallas(
+        slab, b, wt, ht, rc, cc, 1e-4, 0.05, col_tile=tile))
+    slab_shape = (workers, g.rpw_store, g.cpb_store)
     text = hop.lower(
-        shaped((g.rpw_store, g.cpb_store), jnp.bfloat16),
+        shaped(slab_shape, jnp.bfloat16),
+        shaped((), jnp.int32),
         shaped((g.rank_store, g.rpw_store)),
         shaped((g.rank_store, g.cpb_store)),
         shaped((g.nmb, g.s_store)),
         shaped((g.nmb, g.cpb_store))).compile().as_text()
     assert "tpu_custom_call" in text
+    # the kernel reads the slab itself: no copy of a block in front of it
+    (call,) = [line for line in text.splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    assert _bf16_shape(slab_shape) in call.split("custom-call(")[1], call
+    assert not _block_sized_bf16(text.splitlines(),
+                                 g.rpw_store * g.cpb_store)
 
 
 def test_the_als_iteration_fits_the_chip_at_the_cells_full_shape(
@@ -287,6 +330,30 @@ def test_the_solve_kernel_compiles_at_rank_100(topo, no_compile_cache, rows):
         jax.ShapeDtypeStruct((104, rows), jnp.float32, sharding=one)
     ).compile().as_text()
     assert "tpu_custom_call" in text and pk.SPD_SOLVE_NAME in text
+
+
+def test_the_fused_hop_picks_its_block_out_of_the_whole_slab(compiled):
+    """On four workers the resident block changes every hop. The fused
+    program holds no block-sized bf16 result in a loop body (neither the
+    pick's copy nor a layout copy of the slab) and its kernel reads an
+    operand of the slab's whole shape; the XLA stripe scan still copies its
+    block, under ``sgdmf.select``."""
+    workers, rows, cpb = 4, 8 * 128, 6912       # _sgdmf_text's shapes
+    block = rows * cpb
+    fused = compiled("sgdmf-4-fused")
+    assert not _block_sized_bf16(_loop_lines(fused), block)
+    hops = [line for line in _loop_lines(fused) if "custom-call(" in line
+            and "tpu_custom_call" in line]
+    assert hops
+    for line in hops:
+        assert _bf16_shape((workers, rows, cpb)) in line.split(
+            "custom-call(")[1], line
+
+    xla = compiled("sgdmf-4")
+    mapped = scopes.scope_map(xla)
+    copies = [name for name, _, dims in _block_sized_bf16(
+        _loop_lines(xla), block) if dims.endswith(f"{rows},{cpb}")]
+    assert copies and {mapped[name] for name in copies} == {"sgdmf.select"}
 
 
 def test_the_ring_hop_is_a_collective_permute_under_its_own_name(compiled):

@@ -296,9 +296,10 @@ def test_dense_mf_hop_pallas_matches_xla_stripes(col_tile):
         stripe, (h0, jnp.zeros(())),
         (w0.reshape(NMB, S, K), vb.reshape(NMB, S, CPB),
          rc.reshape(NMB, S), cc))
+    # a ring of one: its (1, rpw, cpb) slab and block 0
     w_t, h_t, sse_pl = pk.dense_mf_hop_pallas(
-        vb, w0.T, h0.T, rc.reshape(NMB, S), cc, LR, LAM, col_tile=col_tile,
-        interpret=True)
+        vb[None], 0, w0.T, h0.T, rc.reshape(NMB, S), cc, LR, LAM,
+        col_tile=col_tile, interpret=True)
     w_new, h_new = np.asarray(w_t.T), np.asarray(h_t.T)
     np.testing.assert_allclose(np.asarray(w_ref.reshape(RPW, K)), w_new,
                                rtol=1e-5, atol=1e-5)
@@ -311,6 +312,50 @@ def test_dense_mf_hop_pallas_matches_xla_stripes(col_tile):
         assert not got[~live_row].any() and not got[:, K_LIVE:].any()
     for got in (h_new, np.asarray(h_ref)):
         assert not got[~live_col].any() and not got[:, K_LIVE:].any()
+
+
+@pytest.mark.parametrize("block", [0, 1, 2])
+def test_dense_mf_hop_pallas_reads_the_block_its_index_names(block):
+    """The kernel picks the resident block in its own index map (interpret
+    mode on the CPU, a traced index): on ``(slab, b)`` it gives bitwise the
+    factors and the SSE of the kernel on ``(slab[b:b+1], 0)``, three blocks
+    with different NaN patterns giving three different results, and a block
+    the index does not name leaves no trace: rewritten, it changes no bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from harp_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.default_rng(28)
+    NB, NMB, S, CPB, K = 3, 2, 128, 512, 16
+    RPW = NMB * S
+    v = rng.random((NB, RPW, CPB)).astype(np.float32)
+    for b in range(NB):
+        v[b][rng.random((RPW, CPB)) < 0.5 + 0.2 * b] = np.nan
+    slab = jnp.asarray(v, jnp.bfloat16)
+    w_t = jnp.asarray(0.1 * rng.standard_normal((K, RPW)), jnp.float32)
+    h_t = jnp.asarray(0.1 * rng.standard_normal((K, CPB)), jnp.float32)
+    rc = jnp.asarray(rng.integers(1, 5, (NMB, S)), jnp.float32)
+    cc = jnp.asarray(rng.integers(1, 5, (NMB, CPB)), jnp.float32)
+
+    hop = jax.jit(lambda slab, b: pk.dense_mf_hop_pallas(
+        slab, b, w_t, h_t, rc, cc, 0.05, 0.01, col_tile=256, interpret=True))
+
+    def same(got, want):
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    picked = hop(slab, jnp.int32(block))
+    same(picked, hop(slab[block:block + 1], jnp.int32(0)))
+    # the other blocks' ratings are not this hop's
+    for other in range(NB):
+        if other != block:
+            assert float(hop(slab, jnp.int32(other))[2]) != float(picked[2])
+    # ... and nothing of them reaches it: one all missing, one all rated
+    others = [b for b in range(NB) if b != block]
+    rewritten = slab.at[others[0]].set(jnp.nan).at[others[1]].set(7.0)
+    same(hop(rewritten, jnp.int32(block)), picked)
+    assert np.isfinite(float(picked[2])) and float(picked[2]) > 0
 
 
 # --------------------------------------------------------------------------- #
@@ -455,14 +500,77 @@ def test_layout_stats_and_hop_counter_say_which_update_runs(session):
     assert stats["padded"] == (session.num_workers ** 2
                                * g.rpw_store * g.cpb_store)
 
-    def hops():
-        return dict(metrics.DEFAULT.counters).get("sgd_mf.hops.xla", 0)
+    def hops(which="xla"):
+        return dict(metrics.DEFAULT.counters).get("sgd_mf.hops." + which, 0)
 
-    before = hops()
+    before, fused_before = hops(), hops("fused")
     model.fit_prepared(state)
     traced = hops() - before
     assert traced >= 1
     model.fit_prepared(state)
     assert hops() - before == traced
     assert model.last_layout_stats["fused_hop"] is False
-    assert "sgd_mf.hops.fused" not in dict(metrics.DEFAULT.counters)
+    assert hops("fused") == fused_before
+
+
+def test_slab_pick_says_where_the_resident_block_is_picked(monkeypatch):
+    """On a mesh of four the resident block changes every hop. The XLA
+    stripe scan copies it (``slab_pick == "copy"``, ``sgd_mf.picks.copied``
+    per traced hop body); with the fused hop switched on (the kernel in
+    interpret mode, at a small shape the stored layout tiles) the kernel
+    reads it out of the whole slab (``"in_kernel"``,
+    ``sgd_mf.picks.in_kernel``) and the four-worker factors are the XLA
+    path's, to the tolerance the kernel is held to against the stripe scan
+    above. A slab of one block is picked by a static index, and counts
+    neither."""
+    import functools
+
+    from harp_tpu.ops import pallas_kernels as pk
+    from harp_tpu.utils import metrics
+
+    rows, cols, vals = datagen.sparse_ratings(
+        num_users=96, num_items=80, rank=4, density=0.25, seed=3)
+    cfg = sgd_mf.SGDMFConfig(rank=6, lam=0.01, lr=0.05, epochs=2,
+                             layout="dense", minibatches_per_hop=2)
+
+    def counters():
+        return dict(metrics.DEFAULT.counters)
+
+    def picks(before):
+        return {k: v - before.get(k, 0) for k, v in counters().items()
+                if k.startswith("sgd_mf.picks.") and v != before.get(k, 0)}
+
+    before = counters()
+    xla = sgd_mf.SGDMF(HarpSession(num_workers=4), cfg)
+    state = xla.prepare(rows, cols, vals, 96, 80)
+    assert xla.last_layout_stats["slab_pick"] == "copy"
+    w_xla, h_xla, rmse_xla = xla.fit_prepared(state)
+    traced = picks(before)
+    assert set(traced) == {"sgd_mf.picks.copied"}
+    assert traced["sgd_mf.picks.copied"] >= 1
+    xla.fit_prepared(state)                  # a cached call traces nothing
+    assert picks(before) == traced
+
+    before = counters()
+    one = sgd_mf.SGDMF(HarpSession(num_workers=1), cfg)
+    one.fit_prepared(one.prepare(rows, cols, vals, 96, 80))
+    assert one.last_layout_stats["slab_pick"] == "static"
+    assert picks(before) == {}
+
+    monkeypatch.setattr(pk, "use_dense_mf_pallas", lambda *shape: True)
+    monkeypatch.setattr(pk, "dense_mf_hop_pallas", functools.partial(
+        pk.dense_mf_hop_pallas, interpret=True))
+    before = counters()
+    fused = sgd_mf.SGDMF(HarpSession(num_workers=4), cfg)
+    state = fused.prepare(rows, cols, vals, 96, 80)
+    stats = fused.last_layout_stats
+    assert (stats["slab_pick"], stats["fused_hop"]) == ("in_kernel", True)
+    assert stats["col_tile"] == 256
+    w_f, h_f, rmse_f = fused.fit_prepared(state)
+    traced = picks(before)
+    assert set(traced) == {"sgd_mf.picks.in_kernel"}
+    assert counters()["sgd_mf.hops.fused"] >= 1
+    np.testing.assert_allclose(w_f, w_xla, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(h_f, h_xla, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(rmse_f, rmse_xla, rtol=1e-4)
+    assert np.abs(w_f).max() > 0 and rmse_f[-1] < rmse_f[0]
