@@ -62,8 +62,8 @@ LEGS = {
 MULTICHIP_LEGS = ("multichip", "multichip_ring")
 PLATFORM = "tpu"          # what every leg must find
 
-# the sizes the repo calls its flagship (bench.py tpu_kmeans / tpu_sgd_mf,
-# README "Performance"); the restart leg runs reduced
+# smoke sizes (the benchmark's cells are larger: BENCHMARK.json); the
+# restart leg runs reduced
 KMEANS = {"n": 1_000_000, "k": 100, "d": 100, "iterations": 20}
 SGD_MF = {"users": 32768, "items": 32768, "density": 0.01, "rank": 32,
           "nmb": 8, "epochs": 3}
@@ -370,11 +370,10 @@ def leg_kernels() -> dict:
     enable_compile_cache()
     out = {"device": info}
 
-    # flash attention through its dispatcher at the bench shape
-    # (bench.py tpu_attention). Reference: the XLA scan path with f32
-    # matmuls. Tolerance: the repo's bf16-operand tolerance
-    # (tests/test_aux_sp.py) — the chip multiplies f32 operands in bf16
-    # passes by default.
+    # flash attention through its dispatcher at FLASH's shape. Reference:
+    # the XLA scan path with f32 matmuls. Tolerance: the repo's bf16-operand
+    # tolerance (tests/test_aux_sp.py) — the chip multiplies f32 operands in
+    # bf16 passes by default.
     tol = 3e-2
     l, h = FLASH["l"], FLASH["h"]
     flash = jax.jit(lambda q, k, v: ra.blocked_attention(q, k, v, True))

@@ -105,7 +105,9 @@ def layout_of(args) -> str:
 # same registry exported from different entry points differs ONLY in loc
 # lines).
 _LOC_DEF = re.compile(r"^#loc\d* = loc\(.*\)$\n?", re.MULTILINE)
-_LOC_REF = re.compile(r" loc\((?:#loc\d*|unknown|\".*?\"(?:\(.*?\))?)\)")
+_LOC_REF = re.compile(
+    r" loc\((?:#loc\d*|unknown|callsite\(#loc\d* at #loc\d*\)"
+    r"|\".*?\"(?:\(.*?\))?)\)")
 
 
 def canonical_program_text(mlir_text: str) -> str:

@@ -10,8 +10,8 @@ BenchmarkMapper). Run as::
     python -m harp_tpu.benchmark.scaling
 
 prints ONE JSON line:
-``{"scaling_efficiency": {...}, "collectives": {...}}`` — consumed by bench.py
-and by ``__graft_entry__.dryrun_multichip``.
+``{"scaling_efficiency": {...}, "collectives": {...}}`` — consumed by
+``__graft_entry__.dryrun_multichip``.
 """
 
 from __future__ import annotations
@@ -103,8 +103,7 @@ def measure(widths=(1, 2, 4, 8, 16, 32, 64), n=65536, d=64, k=64, iters=20,
         # multi-worker ring attention (VERDICT r4 #10's bench-row half):
         # the ring schedule (ppermute KV hops + streaming softmax merge)
         # over 8 workers; the pallas flash inner kernel only engages on TPU
-        # backends, so this row prices the SCHEDULE, the 1-chip bench.py
-        # attention row prices the kernel
+        # backends, so this row prices the SCHEDULE, not the kernel
         import jax.numpy as jnp
 
         from harp_tpu.parallel import ring_attention as ra

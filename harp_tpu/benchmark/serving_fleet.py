@@ -916,8 +916,8 @@ def measure(session=None, *, recovery_kw: Optional[dict] = None,
             hotkey_kw: Optional[dict] = None,
             restart_kw: Optional[dict] = None,
             autoscale_kw: Optional[dict] = None) -> dict:
-    """All fleet rows (the ``bench.py --only serving`` extension);
-    per-scenario kwargs forward to their measure_* functions. The ISSUE
+    """All fleet rows; per-scenario kwargs forward to their measure_*
+    functions. The ISSUE
     15 comparison rides as ``restart`` (cold start off/on artifacts) and
     ``recovery_aot`` (the scripted-kill recovery re-run with a pre-warmed
     store — the elastic replacement loads instead of compiling); the
@@ -943,11 +943,11 @@ def main(argv=None) -> None:
     """Subprocess entry for the autoscale ramp: ``python -m
     harp_tpu.benchmark.serving_fleet [--ramp_hold_s=N] [--mesh_workers=N]``
     prints the :func:`measure_autoscale` row as the last stdout line.
-    bench.py spawns this on the 8-device virtual CPU mesh — the fleet
-    topology where the reshard-restore builder path and the AOT store's
-    traced layouts agree (the bench controller's own process may expose a
-    single device, where a restore-built table commits a replicated
-    layout and every artifact load would miss into a warm-compile)."""
+    Run it on the 8-device virtual CPU mesh — the fleet topology where the
+    reshard-restore builder path and the AOT store's traced layouts agree
+    (in a process that exposes a single device a restore-built table
+    commits a replicated layout and every artifact load would miss into a
+    warm-compile)."""
     import json
     import sys
 

@@ -35,14 +35,14 @@ Observability plane (r13): every Nth request is TRACED
 gains ``stage_breakdown`` (per-stage p50/p99/mean over the sampled spans
 — the six stages partition each span's end-to-end latency) plus
 ``reconciliation`` (stage sums vs the measured end-to-end: the mean ratio
-is ~1.0 by construction, the p50 ratio is checked within a stated 25%
-band), ``lookup_skew`` (the TopK endpoint's per-owner histogram), and a
-per-mix ``deadline_expired`` count (``deadline_s`` attaches deadlines to
-every request so expiry behavior is measurable).
+is ~1.0 by construction; the p50 ratio is reported, medians being not
+additive across stages), ``lookup_skew`` (the TopK endpoint's per-owner
+histogram), and a per-mix ``deadline_expired`` count (``deadline_s``
+attaches deadlines to every request so expiry behavior is measurable).
 
 Latency on a CPU-mesh session prices the ROUTER + BATCHER + dispatch stack
-with CPU dispatch times; the driver's on-chip ``bench.py --only serving``
-re-measures with real TPU dispatches (the row carries ``device`` so the two
+with CPU dispatch times; ``chip_smoke.py``'s serving leg drives the same
+generator with real TPU dispatches (the row carries ``device`` so the two
 never get confused).
 """
 
@@ -277,8 +277,8 @@ def measure(session=None, *, requests_per_mix: int = 900,
         # mixes): the six stage durations PARTITION each span's end-to-end
         # latency exactly, so the stage MEAN sum reconciles with the span
         # mean to float noise; percentile sums are sub/super-additive
-        # across differently-skewed stages, so the p50 ratio is checked
-        # against a stated 25% band rather than equality
+        # across differently-skewed stages, so the p50 ratio is reported,
+        # not held to a band
         for reg in span_regs:
             metrics.merge(reg)
         stage_breakdown = {}
@@ -310,7 +310,7 @@ def measure(session=None, *, requests_per_mix: int = 900,
                 if tot["mean_ms"] else None,
                 "note": "stage durations partition each span exactly; "
                         "mean_ratio ~ 1.0 by construction, p50_ratio "
-                        "checked within 25% (percentiles are not "
+                        "reported only (percentiles are not "
                         "additive across stages)",
             }
             telemetry.record_timing("serve.span.total", metrics=metrics,
@@ -338,7 +338,6 @@ def measure(session=None, *, requests_per_mix: int = 900,
     if device != "tpu":
         row["note"] = (
             f"{device}-mesh session: latency prices the router + "
-            f"micro-batcher + {device} dispatch stack; the driver's "
-            f"on-chip `bench.py --only serving` re-measures with real TPU "
-            f"dispatches (same schema, device='tpu')")
+            f"micro-batcher + {device} dispatch stack, not a TPU's "
+            f"(same schema, device='tpu' there)")
     return row

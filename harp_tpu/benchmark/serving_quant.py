@@ -197,8 +197,6 @@ def measure(session=None, *, num_users: int = 2048, num_items: int = 512,
     if device != "tpu":
         row["note"] = (
             f"{device}-mesh session: the QPS/p99 columns price the router "
-            f"+ micro-batcher + {device} dispatch stack; the driver's "
-            f"on-chip `bench.py --only serving_quant` re-measures latency "
-            f"with real TPU dispatches (resident_bytes and topk_overlap "
-            f"are device-independent)")
+            f"+ micro-batcher + {device} dispatch stack, not a TPU's "
+            f"(resident_bytes and topk_overlap are device-independent)")
     return row

@@ -166,9 +166,9 @@ def rotate_scan(
     block is home again. This is Harp's plain ``rotate()`` loop
     (LocalGlobalSyncCollective.rotate:710 called per iteration).
 
-    ``shift=0`` skips the permute entirely — a timing ablation that keeps the
-    compute schedule but removes the collective (the block never moves, so the
-    RESULT is wrong); used only to measure the rotation's share of hop time.
+    ``shift=0`` skips the permute entirely: for a ``body`` that performs the
+    hop itself (the dense-MF in-kernel ring epilogue returns the block
+    already hopped).
 
     ``comm``/``link_class``: wire-format options (module docstring). The EF
     residual rides in the scan carry; with ``comm`` active the returned
@@ -227,8 +227,8 @@ def pipelined_rotation(
     Returns (carry, slice_a', slice_b') with both slices at their original
     positions when num_micro_steps is a multiple of 2*num_workers.
 
-    ``shift=0``: timing ablation, see :func:`rotate_scan` (slices still swap
-    resident/inflight roles but never cross workers).
+    ``shift=0``: see :func:`rotate_scan` (slices still swap
+    resident/inflight roles; the body moves them between workers).
 
     ``comm``/``link_class``: wire-format options (module docstring). One EF
     residual per (sender, slice family): sends alternate the two slice
@@ -295,9 +295,9 @@ class Rotator:
         self.comm = comm
         self.link_class = link_class
         self.fused_dma = fused_dma
-        # shift=0: the scan never permutes — either a timing ablation
-        # (rotate_scan doc) or a body that performs the hop ITSELF (the
-        # dense-MF in-kernel ring epilogue returns the already-hopped block)
+        # shift=0: the scan never permutes — the body performs the hop
+        # ITSELF (the dense-MF in-kernel ring epilogue returns the
+        # already-hopped block)
         self.shift = shift
 
     def run(self, body, carry, slices, epochs: int = 1):

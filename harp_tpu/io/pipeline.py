@@ -27,8 +27,7 @@ part-file set into a BOUNDED chunk stream instead:
 
 Every stage (list/count/read/parse/chunk/regroup/H2D/compute) runs under a
 :class:`utils.metrics.Metrics` timer and is flushed to the telemetry step
-log as ``kind: "timing"`` events via :func:`flush_stage_timings` — the
-``bench.py --only ingest`` row carries the resulting per-stage table.
+log as ``kind: "timing"`` events via :func:`flush_stage_timings`.
 """
 
 from __future__ import annotations

@@ -91,11 +91,6 @@ class ALSConfig:
     #   layout="dense" explicitly to accept the quantization there
     dense_max_bytes: int = 2 * 1024 ** 3  # per-WORKER budget for the two
     #   bf16 plane shards (the SGDMFConfig.dense_max_bytes convention)
-    ablate_solve: bool = False  # timing ablation ONLY (r10, the ALS stage
-    #   budget bench row): skip the batched k×k SPD solve — x = b rides
-    #   through identity — so bench.py can price the solve stage by
-    #   difference (the r3/r4 PERF ablation, now a reproducible row instead
-    #   of a one-off). Results are WRONG; never use outside timing.
 
 
 def pad_csr_lists(rows, cols, vals, num_rows, num_workers):
@@ -208,11 +203,6 @@ def _spd_solve(a, b, cfg: ALSConfig):
     MXU for both, ~30 ms per solve pair either way (ALSConfig.solver note,
     PERF.md r3). Kept as the measured alternative and for platforms where
     batched triangular solves lower worse."""
-    if cfg.ablate_solve:
-        # stage-budget ablation: keep A's construction live (consume it so
-        # XLA cannot dead-code the gram/normal-equation stages) but skip
-        # the solve itself — identity plus a free first-column touch
-        return b + 0.0 * a[..., 0]
     solver = _resolve_solver(cfg)
     if solver == "pallas":
         return pallas_kernels.spd_solve_pallas(a, b,
@@ -340,7 +330,7 @@ def _spd_solve_lanes(at, bt, cfg: ALSConfig):
     the rank padded to Kp by an identity block → x (B, K). The Pallas kernel
     reads them as they lie; any other solver takes them batch-first."""
     k = cfg.rank
-    pallas = not cfg.ablate_solve and _resolve_solver(cfg) == "pallas"
+    pallas = _resolve_solver(cfg) == "pallas"
     # runs when jax traces, only: which solve this program's half-steps run
     metrics.DEFAULT.count("als.solve.pallas" if pallas else "als.solve.xla")
     if pallas:

@@ -42,7 +42,7 @@ import numpy as np
 from harp_tpu import combiner as cb
 from harp_tpu import telemetry
 from harp_tpu.collectives import lax_ops, quantize, rotation, table_ops
-from harp_tpu.ops import distance, lane_pack, pallas_kernels
+from harp_tpu.ops import distance, lane_pack
 from harp_tpu.session import HarpSession
 from harp_tpu.table import Table
 from harp_tpu.telemetry.scopes import scoped
@@ -127,10 +127,9 @@ class KMeans:
             cfg.compute_dtype)
 
         def estep(points, centroids, x_sq_sum=None):
-            # dispatches to the fused pallas kernel when HARP_USE_PALLAS=1;
             # centroids carry k_pad rows, valid_k masks the phantoms
-            sums, counts, sq = pallas_kernels.kmeans_stats(
-                points, centroids, compute_dtype=cdtype, x_sq_sum=x_sq_sum,
+            sums, counts, sq = distance.partial_sums_counts(
+                points, centroids, cdtype, x_sq_sum,
                 valid_k=cfg.num_centroids)
             with jax.named_scope("kmeans.stats"):
                 stats = jnp.concatenate([sums, counts[:, None]], axis=1)  # (K, D+1)

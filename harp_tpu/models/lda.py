@@ -35,9 +35,7 @@ TPU-native reformulation (SURVEY §7 "hard parts" — async semantics under SPMD
   with the next hop's leading compute) and ``num_model_slices=2``
   (half-width blocks on collectives.rotation.pipelined_rotation — while one
   half-slice is being sampled the other is in flight, the reference's exact
-  schedule). ``ablate_rotation=True`` keeps the compute schedule but drops
-  the ppermute — a timing-only ablation benchmark/lda_overlap.py uses to
-  measure the rotation's share of hop time (results in PERF.md).
+  schedule).
 
 Likelihood monitor: the REFERENCE formula, exactly (CalcLikelihoodTask.run:56 +
 the topic-sum completion in printLikelihood, LDAMPCollectiveMapper.java:731-748
@@ -121,13 +119,6 @@ class LDAConfig:
     num_model_slices: int = 1   # 1 = plain rotate_scan; 2 = the reference's
     #   numModelSlices=2 double-buffered schedule (half-width vocab blocks on
     #   pipelined_rotation: sample one half-slice while the other rotates)
-    ablate_rotation: bool = False  # timing ablation ONLY: keep the exact
-    #   compute schedule but skip the ppermute (results are wrong — blocks
-    #   never move); lets benchmark/lda_overlap.py price the rotation
-    ablate_stage: str = ""      # timing ablation ONLY ("gather" | "scatter" |
-    #   "sample" | "gather+scatter"): drop that stage of the per-group update
-    #   (results are wrong) so benchmark/lda_stages.py can price each stage of
-    #   the hop by difference — the per-stage budget VERDICT r4 asked for
     minibatches_per_hop: int = 4  # sequential doc-group sub-steps per hop:
     #   fully-parallel draws let every token of a word resample against the
     #   SAME stale word-topic row each round (a word's tokens can never
@@ -241,15 +232,6 @@ class LDA:
         if config.num_model_slices not in (1, 2):
             raise ValueError(f"num_model_slices must be 1 or 2, got "
                              f"{config.num_model_slices}")
-        if config.ablate_stage not in ("", "gather", "scatter", "sample",
-                                       "gather+scatter"):
-            raise ValueError(
-                f"ablate_stage must be ''|gather|scatter|sample|"
-                f"gather+scatter, got {config.ablate_stage!r}")
-        if config.ablate_stage == "sample" and config.method == "cvb0":
-            raise ValueError(
-                "ablate_stage='sample' only supports method='cgs' (the "
-                "cheap-shift replacement needs integer topic assignments)")
         if config.wt_access == "gemm_scatter" and config.method != "cgs":
             raise ValueError(
                 "wt_access='gemm_scatter' requires method='cgs' (CVB0's "
@@ -287,7 +269,6 @@ class LDA:
         ns = cfg.num_model_slices
         nb = w * ns                           # rotating vocab blocks in total
         vpb = v_pad // nb                     # vocab per block
-        shift = 0 if cfg.ablate_rotation else 1
         comm = (quantize.CommConfig(quant=cfg.quant)
                 if cfg.quant is not None else None)
         nmb = self._effective_minibatches(d_local)
@@ -356,14 +337,10 @@ class LDA:
                     cur = (jax.nn.one_hot(zs_g, k, dtype=jnp.float32)
                            * ms_g[..., None])
                 nd = dt_g[:, None, :] - cur                   # exclude self
-                no_gather = "gather" in cfg.ablate_stage
-                no_scatter = "scatter" in cfg.ablate_stage
                 oh = None
 
                 def apply_scatter(wt_b, delta):
-                    """The ONE count-write path (shared by the full run and
-                    the sample ablation, whose stage budget by subtraction
-                    needs the unablated stages identical)."""
+                    """The count-write path."""
                     if use_gemm:
                         return wt_b + jax.lax.dot_general(
                             oh, delta.reshape(-1, k),
@@ -390,32 +367,13 @@ class LDA:
                     return wt_b + jax.ops.segment_sum(
                         delta.reshape(-1, k), wl_g.reshape(-1),
                         num_segments=vpb)
-                if use_gemm and not (no_gather and no_scatter):
-                    # the scatter GEMM needs the one-hot even when the
-                    # gather is ablated (building it is part of either
-                    # stage's cost in gemm mode)
+                if use_gemm:
                     oh = jax.nn.one_hot(wl_g.reshape(-1), vpb,
                                         dtype=jnp.float32)   # (dg*Lb, vpb)
-                if no_gather:
-                    nw = 1.0 - cur                # ablation: skip the wt read
-                elif use_gemm:
                     nw = (oh @ wt_block).reshape(cur.shape) - cur
                 else:
                     nw = wt_block[wl_g] - cur
                 nk = tt_local[None, None, :] - cur
-                if cfg.ablate_stage == "sample":
-                    # ablation: keep gather+scatter live (consume nw, emit a
-                    # nonzero delta) but skip the categorical build + draw
-                    gate = (nw.sum(-1) > 1e30).astype(jnp.int32)
-                    zs_cheap = (zs_g + 1 + gate) % k
-                    new = (jax.nn.one_hot(zs_cheap, k, dtype=jnp.float32)
-                           * ms_g[..., None])
-                    delta = new - cur
-                    if not no_scatter:
-                        wt_block = apply_scatter(wt_block, delta)
-                    d_k = delta.sum(axis=(0, 1))
-                    return (wt_block, tt_local + d_k, d_k, key,
-                            zs_cheap, dt_g + delta.sum(axis=1))
                 # PRODUCT space, not log space: p ∝ (nd+α)(nw+β)/(nk+Vβ)
                 # directly. The log form cost 3 transcendentals per (token,
                 # topic) and jax.random.categorical's gumbel trick 2 more —
@@ -444,8 +402,7 @@ class LDA:
                     new = (jax.nn.one_hot(zs_new, k, dtype=jnp.float32)
                            * ms_g[..., None])
                 delta = new - cur                             # (dg, Lb, K)
-                if not no_scatter:               # ablation: skip the wt write
-                    wt_block = apply_scatter(wt_block, delta)
+                wt_block = apply_scatter(wt_block, delta)
                 d_k = delta.sum(axis=(0, 1))
                 return (wt_block, tt_local + d_k, d_k, key,
                         zs_new, dt_g + delta.sum(axis=1))
@@ -555,12 +512,12 @@ class LDA:
                 if ns == 1:
                     if quant_wt:
                         hop_carry, wt, wt_res = rotation.rotate_scan(
-                            hop_body, hop_carry, wt, w, shift=shift,
+                            hop_body, hop_carry, wt, w,
                             comm=wt_comm, ef_state=wt_res,
                             fused_dma=cfg.fused_dma)
                     else:
                         hop_carry, wt = rotation.rotate_scan(
-                            hop_body, hop_carry, wt, w, shift=shift,
+                            hop_body, hop_carry, wt, w,
                             fused_dma=cfg.fused_dma)
                 else:
                     # local (2*vpb, K) block = [a-half; b-half]; 2w micro-steps
@@ -568,12 +525,12 @@ class LDA:
                     if quant_wt:
                         hop_carry, sa, sb, wt_res = rotation.pipelined_rotation(
                             micro_body, hop_carry, wt[:vpb], wt[vpb:], 2 * w,
-                            shift=shift, comm=wt_comm, ef_state=wt_res,
+                            comm=wt_comm, ef_state=wt_res,
                             fused_dma=cfg.fused_dma)
                     else:
                         hop_carry, sa, sb = rotation.pipelined_rotation(
                             micro_body, hop_carry, wt[:vpb], wt[vpb:], 2 * w,
-                            shift=shift, fused_dma=cfg.fused_dma)
+                            fused_dma=cfg.fused_dma)
                     wt = jnp.concatenate([sa, sb], axis=0)
                 if comm is None:
                     doc_topic, z, topic_tot, key = hop_carry

@@ -885,8 +885,9 @@ class SGDMF:
         Returns (w_dev, h_dev, rmse ndarray). The rmse fetch waits for the
         run to finish, but the factor blocks (MBs) are not transferred —
         this is the timing surface benchmarks use: steady-state epoch
-        throughput, not the one-time D2H of the final model (bench.py,
-        PERF.md). :meth:`fit_prepared` adds the fetch + de-permutation."""
+        throughput, not the one-time D2H of the final model
+        (``benchmark/configs/sgdmf-k100.driver.py``). :meth:`fit_prepared`
+        adds the fetch + de-permutation."""
         import time as _time
 
         layout, data, w0, h0, meta = state

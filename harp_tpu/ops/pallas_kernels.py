@@ -2,155 +2,25 @@
 
 Reference parity: the role Intel DAAL's hand-tuned AVX-512 kernels played
 (SURVEY §2.5 — third_party/daal-2018 libJavaAPI.so behind every ml/daal
-algorithm). Here the flagship fused op is the K-means assignment step: distance
-matrix + row argmin + partial-sum accumulation WITHOUT materializing the (N, K)
-distance matrix in HBM — the kernel tiles N, keeps the tile's distances in
-VMEM, and accumulates (K, D) sums / (K,) counts in-place across grid steps.
+algorithm): the dense SGD-MF hop, flash attention and the batched SPD solve.
 
 Each kernel has a dispatch predicate (``use_*``) that selects it on the TPU
 backend at the shapes it tiles and the XLA path elsewhere; CPU tests run the
 kernels in interpret mode. A kernel Mosaic refuses raises the compiler's own
 error — nothing here catches it and retries through XLA.
-
-Measured (v5e chip, K-means n=1M k=100 d=100, 200 in-program iterations):
-the fused kernel ties the XLA path (919 vs 925 iters/s) — XLA's own fusion of
-the two MXU matmuls + argmin already holds the working set in VMEM at these
-shapes, so the kernel stays OPT-IN (HARP_USE_PALLAS=1) as a template for ops
-the autofuser genuinely can't produce rather than a default win.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from harp_tpu.ops import distance as xla_path
 from harp_tpu.ops import lane_pack
-
-
-def _kmeans_tile_kernel(x_ref, c_ref, sums_ref, counts_ref, cost_ref,
-                        *, block_n: int, k: int, valid_k: int):
-    """One N-tile: distances in VMEM, stats accumulated across grid steps.
-
-    Mosaic constraints honed on real hardware: (1) the argmin/one-hot lowering
-    allocates a (block_n, K, 128lane) scoped temporary — block_n must stay
-    ≤ ~256 to fit the 16 MB scoped-vmem budget; (2) computing jnp.min AND
-    jnp.argmin of the same tensor crashes the compiler — the min comes from
-    the one-hot instead; (3) scalar accumulators need a lane-width (1, 128)
-    block."""
-    i = pl.program_id(0)
-    x = x_ref[...]                              # (block_n, D) f32 or bf16
-    c = c_ref[...]                              # (K, D) f32
-    # score = ‖c‖² − 2x·c (row-constant ‖x‖² dropped from the argmin; its sum
-    # is added back to the cost as a scalar). Avoids (block_n, 1) temporaries,
-    # which mosaic lowers poorly. bf16 points: MXU takes bf16 operands with
-    # f32 accumulation; norms/scores/stats all stay f32 (the kmeans.py
-    # compute_dtype contract).
-    cf = c.astype(jnp.float32)
-    c2 = jnp.sum(cf * cf, axis=1)[None, :]
-    c_mm = c.astype(x.dtype)                    # match operand dtypes
-    s = c2 - 2.0 * jax.lax.dot_general(
-        x, c_mm, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)        # (block_n, K) in VMEM
-    if valid_k < k:
-        # phantom centroid rows (lane padding / the kernel's own 8-mult
-        # pad): mask their score columns with a huge FINITE value — +inf
-        # would turn the one-hot min extraction's 0·inf into NaN — so no
-        # point ever assigns to padding regardless of data scale
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(col < valid_k, s, jnp.float32(1.7e38))
-    assign = jnp.argmin(s, axis=1)
-    onehot = jax.nn.one_hot(assign, k, dtype=x.dtype)
-    min_sum = jnp.sum(onehot * s)
-    xf = x.astype(jnp.float32)
-    x_sq = jnp.sum(xf * xf)
-
-    @pl.when(i == 0)
-    def _init():
-        sums_ref[...] = jnp.zeros_like(sums_ref)
-        counts_ref[...] = jnp.zeros_like(counts_ref)
-        cost_ref[...] = jnp.zeros_like(cost_ref)
-
-    sums_ref[...] += jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    # counts reduce in f32: a bf16 one-hot cannot represent integer sums
-    # past 256 (the same rule distance.py and kmeans.py state; the hardware
-    # block_n <= 256 bound masks it, interpret mode does not)
-    counts_ref[...] += jnp.sum(onehot.astype(jnp.float32), axis=0)[None, :]
-    cost_ref[...] += jnp.full((1, 128), min_sum + x_sq, jnp.float32)
-
-
-def kmeans_stats_pallas(
-    x: jax.Array, c: jax.Array, block_n: int = 256,
-    interpret: bool = False, valid_k: Optional[int] = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Fused E-step: returns (sums (K, D), counts (K,), cost scalar).
-
-    Equivalent to ops/distance.partial_sums_counts but never writes the (N, K)
-    distance matrix to HBM. ``x`` rows must be divisible by ``block_n`` (pad
-    with rows equal to centroid 0 and subtract, or pick block_n | N).
-
-    ``valid_k``: centroid rows >= valid_k are phantom lane padding
-    (ops/lane_pack) — masked out of the argmin in-kernel, exactly like the
-    rows this function's own 8-multiple padding adds.
-    """
-    n, d = x.shape
-    k = c.shape[0]
-    if n % block_n:
-        raise ValueError(f"N={n} must be divisible by block_n={block_n}")
-    if block_n % 8:
-        raise ValueError(f"block_n={block_n} must be divisible by 8 (sublanes)")
-    if block_n > 256 and not interpret:
-        raise ValueError(
-            f"block_n={block_n} exceeds 256: the mosaic argmin lowering "
-            "allocates a (block_n, K, 128)-lane scoped temporary and blows the "
-            "16 MB scoped-vmem budget (opaque compiler crash) — use <= 256")
-    valid = k if valid_k is None else min(valid_k, k)
-    # mosaic blocks need (8, 128)-aligned trailing dims: pad features with
-    # zeros (distances/sums unchanged) and centroid ROWS with zeros — the
-    # kernel masks every score column >= valid, so padding rows can never
-    # win the argmin at ANY data scale (r6: this replaces the old 1e6-fill,
-    # which a large-magnitude dataset could have out-scored)
-    d_pad = lane_pack.round_up(d, 128)
-    k_pad = lane_pack.round_up(k, 8)
-    k_orig, d_orig = k, d
-    c = c.astype(jnp.float32)       # centroids stay f32 (norm precision)
-    if d_pad != d:
-        x = jnp.pad(x, ((0, 0), (0, d_pad - d)))
-        c = jnp.pad(c, ((0, 0), (0, d_pad - d)))
-    if k_pad != k:
-        c = lane_pack.pad_rows(c, k_pad)
-    k, d = k_pad, d_pad
-    g = n // block_n
-    kernel = functools.partial(_kmeans_tile_kernel, block_n=block_n, k=k,
-                               valid_k=valid)
-    sums, counts2d, cost1 = pl.pallas_call(
-        kernel,
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((k, d), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((k, d), lambda i: (0, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-            pl.BlockSpec((1, 128), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k, d), jnp.float32),
-            jax.ShapeDtypeStruct((1, k), jnp.float32),
-            jax.ShapeDtypeStruct((1, 128), jnp.float32),
-        ],
-        interpret=interpret,
-        name="kmeans_stats",
-    )(x, c)
-    return (sums[:k_orig, :d_orig], counts2d[0, :k_orig], cost1[0, 0])
 
 
 # --------------------------------------------------------------------------- #
@@ -605,8 +475,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     rows are sliced off the output), so the win covers ragged lengths too
     (VERDICT r4 #10). Dh and Dv pad to lane multiples independently
     (Dv ≠ Dh is fine — cross-attention/Ulysses value heads). Dispatched by
-    ``parallel.ring_attention.blocked_attention`` on TPU (opt-out
-    HARP_FLASH_PALLAS=0).
+    ``parallel.ring_attention.blocked_attention`` on TPU.
 
     ``causal=True`` runs the block-sparse trapezoid grid (r7): above-diagonal
     KV blocks are not in the grid at all — never visited, never DMA'd.
@@ -785,12 +654,7 @@ def use_flash_pallas(l: int) -> bool:
     L ≥ 8192 (measured crossover — at L=4096 the XLA scan edges it 0.91×,
     from 8192 up the kernel wins 2.5×; per-tile scratch setup and the
     D-pad waste amortize with sequence length); any L — the kernel pads and
-    masks ragged lengths internally (r5). Opt out with
-    HARP_FLASH_PALLAS=0."""
-    import os
-
-    if os.environ.get("HARP_FLASH_PALLAS", "1") == "0":
-        return False
+    masks ragged lengths internally (r5)."""
     if jax.default_backend() != "tpu":
         return False
     return l >= 8192
@@ -801,13 +665,7 @@ def use_flash_head_pack(h: int, dh: int, dv: int) -> bool:
     tile when BOTH head dims fit a 64-lane half and H is even — at Dh=64
     the unpacked layout computes zeros on half the MXU contraction lanes
     AND ships a zero-padded 128-lane tile per head through HBM; packing
-    fixes both. At Dh > 64 the lanes are already full (the bench's Dh=128
-    row quantifies the no-padding case). Opt out with
-    HARP_FLASH_HEADPACK=0."""
-    import os
-
-    if os.environ.get("HARP_FLASH_HEADPACK", "1") == "0":
-        return False
+    fixes both. At Dh > 64 the lanes are already full."""
     return h % 2 == 0 and 0 < dh <= _PACK_LANES and 0 < dv <= _PACK_LANES
 
 
@@ -1006,12 +864,7 @@ def spd_solve_pallas(a: jax.Array, b: jax.Array, tile_b: Optional[int] = None,
 
 def use_spd_solve_pallas(k: int) -> bool:
     """Dispatch predicate: default ON for TPU wherever a lane tile of the
-    (K, K, B) working set fits VMEM (:func:`spd_solve_tile`: K up to ~250);
-    opt out with HARP_ALS_PALLAS=0."""
-    import os
-
-    if os.environ.get("HARP_ALS_PALLAS", "1") == "0":
-        return False
+    (K, K, B) working set fits VMEM (:func:`spd_solve_tile`: K up to ~250)."""
     if jax.default_backend() != "tpu":
         return False
     return spd_solve_tile(k) > 0
@@ -1019,39 +872,7 @@ def use_spd_solve_pallas(k: int) -> bool:
 
 def use_dense_mf_pallas(cpb: int, s_rows: int, k: int) -> bool:
     """Dispatch predicate for the fused dense-MF hop: default ON for TPU
-    where a column tile fits (:func:`dense_mf_col_tile`), opt out with
-    HARP_DENSE_PALLAS=0."""
-    import os
-
-    if os.environ.get("HARP_DENSE_PALLAS", "1") == "0":
-        return False
+    where a column tile fits (:func:`dense_mf_col_tile`)."""
     if jax.default_backend() != "tpu":
         return False
     return dense_mf_col_tile(cpb, s_rows, k) > 0
-
-
-def kmeans_stats(x: jax.Array, c: jax.Array, block_n: int = 256,
-                 compute_dtype=None, x_sq_sum=None,
-                 valid_k: Optional[int] = None
-                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Dispatch: pallas when opted in (HARP_USE_PALLAS=1) on TPU, else XLA.
-
-    This is the E-step entry the K-means model calls. Opt-in rather than
-    default: the XLA path fuses the two matmuls well and the kernel TIES
-    it at BOTH storage dtypes (measured r4 bench config: XLA 828 f32 /
-    918 bf16 iters/s vs pallas 877 / 895 — the hypothesis that XLA's
-    score materialization would dominate at bf16 did not survive
-    measurement), so the extra Mosaic compile buys nothing — pay it only
-    when you ask to. Accepts f32 or
-    bf16 ``x``; scores/stats always accumulate f32 and Σ‖x‖² derives
-    in-kernel (``x_sq_sum`` applies to the XLA path only).
-    """
-    import os
-
-    on_tpu = jax.default_backend() == "tpu"
-    opted = os.environ.get("HARP_USE_PALLAS", "") == "1"
-    if (on_tpu and opted and x.shape[0] % block_n == 0
-            and x.dtype in (jnp.float32, jnp.bfloat16)):
-        return kmeans_stats_pallas(x, c, block_n, valid_k=valid_k)
-    return xla_path.partial_sums_counts(x, c, compute_dtype, x_sq_sum,
-                                        valid_k=valid_k)

@@ -56,7 +56,6 @@ disjoint (same ID on every worker for the same logical collective).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -105,12 +104,10 @@ def _next_hop_id() -> int:
 
 
 def use_ring_dma() -> bool:
-    """Dispatch gate for the fused kernels: the TPU backend, opt-out
-    HARP_RING_DMA=0. Off TPU the engine ALWAYS takes the tagged lax
-    twin (the kernels have no remote-DMA lowering there), so tier-1 and the
-    budget traces run the identical schedule off-chip."""
-    if os.environ.get("HARP_RING_DMA", "1") == "0":
-        return False
+    """Dispatch gate for the fused kernels: the TPU backend. Off TPU the
+    engine ALWAYS takes the tagged lax twin (the kernels have no remote-DMA
+    lowering there), so tier-1 and the budget traces run the identical
+    schedule off-chip."""
     return jax.default_backend() == "tpu"
 
 

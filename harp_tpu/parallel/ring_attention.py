@@ -145,8 +145,7 @@ def ring_attention_mha(q: jax.Array, k: jax.Array, v: jax.Array,
                        causal: bool = False, axis_name: str = WORKERS,
                        use_flash: Optional[bool] = None,
                        interpret: bool = False,
-                       fused_dma: Optional[bool] = None,
-                       ablate_rotation: bool = False) -> jax.Array:
+                       fused_dma: Optional[bool] = None) -> jax.Array:
     """Multi-head ring attention: q/k/v (L/W, H, Dh) → (L/W, H, Dv).
 
     One ring hop per step carries all heads; each hop folds the resident
@@ -169,12 +168,7 @@ def ring_attention_mha(q: jax.Array, k: jax.Array, v: jax.Array,
     skips the ppermute staging round trip. Off TPU (or with the XLA einsum
     hop) the same schedule runs with :func:`~harp_tpu.ops.ring_dma.hop`
     per hop — bitwise the ppermute schedule, and the jaxpr budget books
-    the bytes as ``fused_dma``.
-
-    ``ablate_rotation``: timing ablation ONLY — keeps the per-hop compute
-    schedule but never moves the KV block (results are WRONG); used by the
-    ring_dma overlap bench to bound the non-overlapped hop share, exactly
-    like ``LDAConfig.ablate_rotation``."""
+    the bytes as ``fused_dma``."""
     w = jax.lax.axis_size(axis_name)
     wid = lax_ops.worker_id(axis_name)
     lq = q.shape[0]
@@ -187,8 +181,7 @@ def ring_attention_mha(q: jax.Array, k: jax.Array, v: jax.Array,
     if fused_dma is None:
         fused_dma = ring_dma.use_ring_dma()
     in_kernel = (fused_dma and use_flash and not interpret
-                 and ring_dma.use_ring_dma() and w > 1
-                 and not ablate_rotation)
+                 and ring_dma.use_ring_dma() and w > 1)
 
     def hop_valid(tm1, m_r):
         if causal:
@@ -228,10 +221,7 @@ def ring_attention_mha(q: jax.Array, k: jax.Array, v: jax.Array,
     m_run, num, den = _hop_stats(q, k, v, scale, causal, use_flash,
                                  interpret)
     if w > 1:
-        shift = 0 if ablate_rotation else 1
-        if ablate_rotation:
-            kv = (k, v)
-        elif fused_dma:
+        if fused_dma:
             kv = ring_dma.hop_tree((k, v), 1, axis_name)
         else:
             kv = jax.tree.map(lambda x: lax_ops.rotate(x, 1, axis_name),
@@ -247,7 +237,7 @@ def ring_attention_mha(q: jax.Array, k: jax.Array, v: jax.Array,
             return (m_r, nu, de), (kb, vb)
 
         (m_run, num, den), _ = rotation.rotate_scan(
-            body, (m_run, num, den), kv, w - 1, axis_name, shift=shift,
+            body, (m_run, num, den), kv, w - 1, axis_name,
             fused_dma=fused_dma)
     return num / jnp.maximum(den, 1e-30)[..., None]
 
@@ -302,8 +292,7 @@ def blocked_attention(qf: jax.Array, kf: jax.Array, vf: jax.Array,
     # query tile's running stats/accumulator in VMEM across the KV grid
     # (the XLA scan round-trips them through HBM every step) — measured
     # 2.5x at L>=8192 (14 TFLOP/s effective at L=16k); below the 8192
-    # crossover the XLA scan stays ahead and remains the path (PERF.md
-    # r4). Opt out with HARP_FLASH_PALLAS=0.
+    # crossover the XLA scan stays ahead and remains the path.
     from harp_tpu.ops import pallas_kernels as _pk
 
     if _pk.use_flash_pallas(qf.shape[0]):

@@ -36,9 +36,9 @@ holds too: the serve step is a single traced program over the same mesh
 primitives as the trainers — which is exactly what lets the jaxpr budget
 engine police it.
 
-Load generation lives in :mod:`harp_tpu.benchmark.serving_load`
-(``bench.py --only serving``): p50/p99 latency + QPS at >=3 traffic mixes,
-published through :mod:`harp_tpu.telemetry`.
+Load generation lives in :mod:`harp_tpu.benchmark.serving_load`: p50/p99
+latency + QPS at >=3 traffic mixes, published through
+:mod:`harp_tpu.telemetry`.
 
 The serving observability plane (r13) rides this package without touching
 a traced program: sampled requests carry per-stage span stamps

@@ -151,8 +151,6 @@ def test_the_dispatch_decides_from_backend_rank_and_vmem(monkeypatch):
     assert (pallas_kernels.spd_solve_vmem_bytes(
         100, pallas_kernels.spd_solve_tile(100))
         <= pallas_kernels.SPD_SOLVE_VMEM_LIMIT)
-    monkeypatch.setenv("HARP_ALS_PALLAS", "0")
-    assert not pallas_kernels.use_spd_solve_pallas(32)
 
 
 def test_dense_als_with_the_kernel_matches_the_exact_solver():

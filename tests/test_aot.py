@@ -75,6 +75,8 @@ def test_canonical_text_strips_locations():
             'module @jit_f {\n'
             '  %0 = stablehlo.add %a, %b : tensor<f32> loc(#loc1)\n'
             '  %1 = stablehlo.abs %0 : tensor<f32> loc(unknown)\n'
+            '  func.func private @g(%arg0: tensor<9xi32> '
+            'loc(callsite(#loc1 at #loc2))) -> tensor<9xi32>\n'
             '}\n')
     canon = canonical_program_text(text)
     assert "loc(" not in canon

@@ -1,7 +1,6 @@
 """Claims honesty check (tools/check_claims.py) — tier-1.
 
-VERDICT r5 #8: README/PERF headline throughput numbers must sit inside the
-latest committed BENCH record's bands (the "≥6×" vs 5.22/5.44 drift class).
+A number README.md quotes from a committed manifest must be the manifest's.
 """
 
 import os
@@ -13,7 +12,7 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 import check_claims  # noqa: E402
 
 
-def test_repo_claims_match_committed_bench_record():
+def test_repo_claims_match_committed_records():
     assert check_claims.check(REPO) == []
     assert check_claims.main([REPO]) == 0
 
@@ -46,8 +45,7 @@ def test_stale_entry_and_null_record_fail():
     # reworded prose: the pattern no longer matches → loud
     v = check_claims.check_claim(claim, "throughput: 103 tokens/s", {})
     assert v and "not found" in v
-    # null bench value (e.g. a pending on-chip row): a numeric claim on an
-    # unmeasured row must fail
+    # null recorded value: a numeric claim on an unmeasured row must fail
     v = check_claims.check_claim(claim, "rate is 103 tokens/s",
                                  {"row": {"rate": None}})
     assert v and "unmeasured" in v

@@ -437,8 +437,8 @@ class TestGroupByKeySharded:
 
 
 class TestQuantizedBenchRows:
-    """The collectives_quantized bench group (bench.py --only
-    collectives_quantized): row schema + wire-byte pricing."""
+    """``harp_tpu.benchmark.collectives``' quantized rows: row schema +
+    wire-byte pricing."""
 
     def test_quant_bytes_moved_prices_the_codec_wire_format(self):
         from harp_tpu.benchmark import collectives as bc

@@ -178,12 +178,6 @@ def test_host_sync_outside_loop_or_fit_is_clean():
     src2 = ("def load(paths):\n"
             "    return [np.asarray(read(p)) for p in paths]\n")
     assert _run(ca.check_host_sync, src2) == []
-    # timing.py is the sanctioned sync site
-    src3 = ("def fit_timed(self, xs):\n"
-            "    for x in xs:\n"
-            "        self._step(x).block_until_ready()\n")
-    assert ca.check_host_sync(ast.parse(src3),
-                              "harp_tpu/benchmark/timing.py", src3) == []
 
 
 # -- JL105 broad-except -----------------------------------------------------
@@ -1435,15 +1429,3 @@ def test_hlo_doctored_manifest_fails_jl502_in_json_stream(
             "allowlisted"} <= set(hits[0])
 
 
-def test_bench_list_groups_matches_only_validator():
-    # the satellite contract: --list-groups prints EXACTLY the names the
-    # --only validator accepts, one per line
-    import subprocess
-
-    import bench
-
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--list-groups"],
-        capture_output=True, text=True, cwd=REPO, timeout=120)
-    assert out.returncode == 0, out.stderr
-    assert out.stdout.split() == list(bench.ROW_GROUPS)

@@ -19,7 +19,7 @@ import pytest
 from harp_tpu.io import datagen
 from harp_tpu.models import kmeans as km
 from harp_tpu.models import lda, sparse
-from harp_tpu.ops import distance, lane_pack, pallas_kernels
+from harp_tpu.ops import distance, lane_pack, pallas_kernels, ring_dma
 
 
 # --------------------------------------------------------------------------- #
@@ -256,24 +256,6 @@ def test_partial_sums_counts_valid_k_masks_phantoms(rng):
     assert np.all(np.asarray(s2[:, 100:]) == 0)
 
 
-def test_pallas_kmeans_kernel_valid_k_interpret(rng):
-    """The fused pallas E-step masks lane-padding phantoms in-kernel
-    (interpret mode; zero phantom rows would otherwise capture points —
-    the old 1e6-fill is gone, masking is scale-independent)."""
-    x = jnp.asarray(rng.standard_normal((128, 16)), jnp.float32)
-    c = jnp.asarray(rng.standard_normal((6, 16)), jnp.float32)
-    s_ref, n_ref, cost_ref = distance.partial_sums_counts(x, c)
-    c_pad = lane_pack.pad_rows(c, 16)
-    sums, counts, cost = pallas_kernels.kmeans_stats_pallas(
-        x, c_pad, block_n=32, interpret=True, valid_k=6)
-    np.testing.assert_allclose(np.asarray(sums[:6]), np.asarray(s_ref),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(counts[:6]), np.asarray(n_ref),
-                               rtol=1e-6)
-    assert np.all(np.asarray(counts[6:]) == 0)
-    np.testing.assert_allclose(float(cost), float(cost_ref), rtol=1e-4)
-
-
 def test_sparse_kmeans_densify_rides_engine(session, rng):
     """CSR K-means 'densify' (now on lane_pack.densify_rows) still matches
     the dense trajectory on the equivalent matrix."""
@@ -289,3 +271,28 @@ def test_sparse_kmeans_densify_rides_engine(session, rng):
     ref = km.numpy_reference(dense.astype(np.float64),
                              cen0.astype(np.float64), 5)
     np.testing.assert_allclose(cen_sp, ref, rtol=1e-3, atol=1e-4)
+
+
+# --------------------------------------------------------------------------- #
+# kernel dispatch: the backend and the shape, nothing from outside the program
+# --------------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("predicate,shape,old_variable", [
+    (pallas_kernels.use_flash_pallas, (8192,), "HARP_FLASH_PALLAS"),
+    (pallas_kernels.use_flash_head_pack, (8, 64, 64), "HARP_FLASH_HEADPACK"),
+    (pallas_kernels.use_spd_solve_pallas, (100,), "HARP_ALS_PALLAS"),
+    (pallas_kernels.use_dense_mf_pallas, (10752, 8960, 104),
+     "HARP_DENSE_PALLAS"),
+    (ring_dma.use_ring_dma, (), "HARP_RING_DMA"),
+], ids=["use_flash_pallas", "use_flash_head_pack", "use_spd_solve_pallas",
+        "use_dense_mf_pallas", "use_ring_dma"])
+def test_kernel_dispatch_ignores_the_environment(monkeypatch, predicate,
+                                                 shape, old_variable):
+    """Each predicate picks its kernel on the TPU at a shape the kernel
+    fits, whatever the process environment says; the four that test the
+    backend pick XLA's path off the TPU."""
+    monkeypatch.setenv(old_variable, "0")
+    if predicate is not pallas_kernels.use_flash_head_pack:
+        assert not predicate(*shape)                          # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert predicate(*shape)

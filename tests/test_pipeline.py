@@ -326,32 +326,3 @@ def test_ingest_regroup_budget_drift_is_loud():
     clean = {"ingest_coo_regroup": (counts, [], dict(row["bytes_by_kind"]))}
     assert not any(f.func == "ingest_coo_regroup"
                    for f in checkers_jaxpr.check_budget(repo, clean))
-
-
-def test_bench_ingest_row_schema():
-    """The committed --only ingest row carries the acceptance fields (run
-    when BENCH_local.json has the group — tier-1 asserts schema, not
-    numbers)."""
-    import json
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = os.path.join(repo, "BENCH_local.json")
-    if not os.path.exists(path):
-        pytest.skip("no committed bench record")
-    with open(path) as f:
-        detail = json.load(f)
-    row = detail.get("ingest")
-    if not isinstance(row, dict) or "error" in row:
-        pytest.skip("no committed ingest row")
-    for key in ("stream_load_mb_per_sec", "serialized_wall_s",
-                "overlapped_wall_s", "overlap_efficiency", "overlap_gate",
-                "overlap_note", "e2e_stream_fit_wall_s", "stages",
-                "regroup"):
-        assert key in row, key
-    assert row["overlap_gate"] in ("on", "skipped")
-    if row["overlap_gate"] == "skipped":
-        assert row["overlap_pass"] is None
-    else:
-        assert isinstance(row["overlap_pass"], bool)
-    assert {"nnz", "wall_s", "wire_bytes", "rounds"} <= set(row["regroup"])
-    assert row["stages"].get("parse") or row["stages"].get("read")

@@ -612,24 +612,8 @@ def test_supervisor_straggler_ranks(tmp_path):
 
 
 # --------------------------------------------------------------------------- #
-# bench row + manifest schema
+# manifest schema
 # --------------------------------------------------------------------------- #
-
-def test_bench_reshard_row_schema():
-    with open(os.path.join(REPO, "BENCH_local.json")) as f:
-        rec = json.load(f)
-    row = rec["reshard"]
-    cpu = row["cpu_mesh"]
-    for key in ("reshard_seconds", "reshard_ring_seconds",
-                "reshard_bytes_moved", "host_gather_seconds", "rounds",
-                "parity", "device"):
-        assert key in cpu, key
-    assert cpu["reshard_bytes_moved"] > 0
-    # GB-scale on-chip leg: measured dict, or null WITH the note (the
-    # committed-null-with-note convention every on-chip row follows)
-    if row["gb_scale"] is None:
-        assert "gb_scale_note" in row
-
 
 def test_manifest_pins_reshard_targets():
     with open(os.path.join(REPO, "tools", "collective_budget.json")) as f:

@@ -2,12 +2,14 @@
 
 The engine's whole contract is "moves bytes, never rounds them": on the
 8-worker CPU mesh every fused schedule must be BITWISE the ppermute
-schedule (the TPU kernels share the same semantics — the driver's on-chip
-ring_dma_overlap bench run exercises those). Plus the budget-gate contract:
+schedule (the TPU kernels share the same semantics — ``chip_smoke.py --leg
+multichip_ring`` exercises those on four chips). Plus the budget-gate contract:
 fused hops trace as the tagged ``fused_dma`` kind, and a fused target
 silently reverting to bare ppermute fails JL201/JL203.
 """
 
+import dataclasses
+import inspect
 import json
 import os
 
@@ -17,7 +19,9 @@ import numpy as np
 import pytest
 
 from harp_tpu.collectives import lax_ops, rotation, table_ops
+from harp_tpu.models import als, lda, nn
 from harp_tpu.ops import ring_dma
+from harp_tpu.parallel import ring_attention
 
 W = 8
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -355,68 +359,17 @@ def test_fused_revert_to_ppermute_fails_budget_gate():
                    for f in findings if f.func == "lda_cgs_fused")
 
 
-# -- bench row schemas ------------------------------------------------------
+# -- no timing-only switch in a production config ---------------------------
 
 
-def test_ring_overlap_row_schema(session):
-    from harp_tpu.benchmark import ring_overlap
-
-    row = ring_overlap.measure(l_local=8, heads=2, dh=4, reps=1,
-                               use_flash=False)
-    for key in ("workers", "unfused_s", "no_rotation_s", "fused_s",
-                "hop_share", "fused_speedup", "fused_hidden_fraction"):
-        assert key in row, key
-    assert row["workers"] == W
-    assert 0.0 <= row["fused_hidden_fraction"] <= 1.0
-
-
-def test_lda_overlap_fused_row_schema(session):
-    from harp_tpu.benchmark import lda_overlap
-
-    row = lda_overlap.measure(num_docs=16, vocab=96, num_topics=4,
-                              doc_len=8, epochs=2, reps=1, fused=True)
-    for key in ("single_s", "no_rotation_s", "two_slice_s",
-                "fused_single_s", "fused_two_slice_s", "fused_speedup",
-                "fused_hidden_fraction"):
-        assert key in row, key
-    assert 0.0 <= row["fused_hidden_fraction"] <= 1.0
-
-
-def test_bench_local_carries_null_ring_dma_rows():
-    with open(os.path.join(REPO, "BENCH_local.json")) as f:
-        rec = json.load(f)
-    assert "ring_dma_overlap" in rec
-    assert "als_stage_budget" in rec
-    if rec["ring_dma_overlap"] is None:
-        assert "ring_dma_overlap" in rec["bench_schema_note_r10"]
-    if rec["als_stage_budget"] is None:
-        assert "als_stage_budget" in rec["bench_schema_note_r10"]
-
-
-def test_bench_ring_dma_group_registered():
-    import bench
-
-    assert "ring_dma_overlap" in bench.ROW_GROUPS
-
-
-# -- ALS stage-budget ablation ---------------------------------------------
-
-
-def test_als_ablate_solve_is_identity_through_solve(session):
-    from harp_tpu.models import als as als_mod
-
-    cfg = als_mod.ALSConfig(rank=4, ablate_solve=True)
-    a = jnp.stack([jnp.eye(4) * 2.0] * 3)
-    b = jnp.ones((3, 4))
-    out = als_mod._spd_solve(a, b, cfg)
-    # identity pass-through (a real solve would return 0.5s)
-    np.testing.assert_allclose(np.asarray(out), np.ones((3, 4)))
-    # and the ablated model still runs end-to-end (wrong but finite)
-    rng = np.random.default_rng(0)
-    rows = rng.integers(0, 32, size=200)
-    cols = rng.integers(0, 24, size=200)
-    vals = np.abs(rng.normal(size=200)).astype(np.float32)
-    m = als_mod.ALS(session, als_mod.ALSConfig(
-        rank=4, iterations=2, implicit=True, ablate_solve=True))
-    _, _, rmse = m.fit(rows, cols, vals, 32, 24)
-    assert np.all(np.isfinite(np.asarray(rmse)))
+@pytest.mark.parametrize("names", [
+    [f.name for f in dataclasses.fields(als.ALSConfig)],
+    [f.name for f in dataclasses.fields(lda.LDAConfig)],
+    [f.name for f in dataclasses.fields(nn.NNConfig)],
+    list(inspect.signature(ring_attention.ring_attention_mha).parameters),
+], ids=["ALSConfig", "LDAConfig", "NNConfig", "ring_attention_mha"])
+def test_no_ablation_switch_in_production_code(names):
+    """A switch that gives wrong results so that a stage can be priced by
+    difference has no place in a config users set: device time by scope
+    (``harp_tpu/telemetry/scopes.py``) prices a stage of the real program."""
+    assert not [n for n in names if n.startswith("ablate")]

@@ -474,7 +474,7 @@ def test_collective_in_classify_dispatch_fails_budget_gate():
 
 
 # --------------------------------------------------------------------------- #
-# Load generator row schema (bench.py --only serving)
+# Load generator row schema
 # --------------------------------------------------------------------------- #
 
 def test_serving_load_row_schema(session):
@@ -494,4 +494,4 @@ def test_serving_load_row_schema(session):
             v == 1 for v in occ["trace_counts"].values()), (name, occ)
     assert row["device"] in ("cpu", "tpu")
     if row["device"] != "tpu":
-        assert "re-measures" in row["note"]
+        assert "not a TPU's" in row["note"]

@@ -563,10 +563,16 @@ def test_serving_load_row_reconciles_spans_and_counts_expiry(session,
     assert set(sb) == {"total"} | set(spans.STAGES)
     rec = row["reconciliation"]
     assert rec["spans"] == sb["total"]["count"] > 0
-    # stage durations partition each span: means reconcile tightly, p50s
-    # within the stated 25% band
+    # stage durations partition each span, so the means reconcile tightly
+    # whatever the host's load. Medians are not additive (under six xdist
+    # workers the stage medians summed to 0.74 of the span's): the p50 row
+    # is held to the stage table it was reduced from, not to a band
     assert rec["mean_ratio"] == pytest.approx(1.0, abs=0.02)
-    assert rec["p50_ratio"] == pytest.approx(1.0, abs=0.25)
+    assert rec["span_p50_ms"] == sb["total"]["p50_ms"] > 0
+    assert rec["stage_p50_sum_ms"] == pytest.approx(
+        sum(sb[s]["p50_ms"] for s in spans.STAGES), abs=1e-3)
+    assert rec["p50_ratio"] == pytest.approx(
+        rec["stage_p50_sum_ms"] / rec["span_p50_ms"], abs=1e-4)
     skew = row["lookup_skew"]
     assert skew["total"] > 0 and len(skew["counts"]) == 8
     # the spans flowed THROUGH telemetry: kind:"span" events in the JSONL

@@ -69,7 +69,9 @@
 #                     gang program whose DCN bytes grow, or whose
 #                     per-process shard shape drifts, fails JL203/JL201
 #                     exactly like the single-process targets.
-#   4. check_claims — README/PERF headline numbers vs BENCH_local.json.
+#   4. check_claims — the numbers README.md quotes from the committed
+#                     manifests (tools/collective_budget.json,
+#                     tools/artifact_manifest.json) are the manifests'.
 #   5. tier-1       — the ROADMAP.md verify suite (which itself re-runs
 #                     jaxlint's clean-repo + budget checks as tests, so
 #                     DOTS_PASSED captures them).
@@ -114,9 +116,7 @@
 # int8 rows ride the route/route-back wire): an int8 endpoint silently
 # reverting to f32 payloads re-widens the wire at unchanged counts,
 # which is exactly the JL203 byte-drift signature (tier-1 doctors one in
-# tests/test_serve_quant.py to prove the gate fires, and stage 4 pins
-# the same bytes — plus the committed serving_quant resident-reduction/
-# overlap row — into the PERF.md/README prose). The int8 scoring dot
+# tests/test_serve_quant.py to prove the gate fires). The int8 scoring dot
 # accumulates in int32 via preferred_element_type, which the JL202 dtype
 # policy accepts by construction (it flags bf16-accumulating dots, not
 # integer dots).
@@ -185,9 +185,7 @@
 #                     pass; this pass gives compiled-contract failures
 #                     their own CI banner. The same hlo rows ride each
 #                     AOT artifact's meta (store metadata, never a key
-#                     axis), and stage 4 pins the PERF.md r21
-#                     compiled-collective table against the manifest at
-#                     tol 0.
+#                     axis).
 #
 # Any stage failing fails the script; all stages always run (a lint
 # finding must not hide a test regression or vice versa).

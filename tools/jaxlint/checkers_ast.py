@@ -19,9 +19,7 @@ Codes:
   JL104 host-sync-hot-loop     ``.item()`` / ``block_until_ready`` /
                                ``np.asarray`` inside a Python loop in a
                                fit/train path — a device→host sync per
-                               iteration serializes the dispatch pipeline
-                               (benchmark/timing.py is exempt: timing is the
-                               one place a sync is the point).
+                               iteration serializes the dispatch pipeline.
   JL105 broad-except           ``except Exception``/bare except without a
                                justified allowlist entry — swallows the
                                KeyboardInterrupt-adjacent world and hides
@@ -372,14 +370,10 @@ def check_retrace_hazard(mod: ast.AST, rel: str, src: str) -> List[Finding]:
 # JL104 host-sync-hot-loop
 # --------------------------------------------------------------------------
 
-_EXEMPT_SYNC_FILES = {"harp_tpu/benchmark/timing.py"}
 _HOT_FUNC_PREFIXES = ("fit", "train")
 
 
 def check_host_sync(mod: ast.AST, rel: str, src: str) -> List[Finding]:
-    if rel in _EXEMPT_SYNC_FILES:
-        return []
-
     class V(FuncStackVisitor):
         def __init__(self, rel_path):
             super().__init__(rel_path)
@@ -417,9 +411,7 @@ def check_host_sync(mod: ast.AST, rel: str, src: str) -> List[Finding]:
                         f"{sync} inside a Python loop in "
                         f"{'/'.join(self.func_stack)} — a device→host sync "
                         f"per iteration stalls the dispatch pipeline; keep "
-                        f"device values on device until after the loop "
-                        f"(benchmark/timing.py is the only sanctioned "
-                        f"timing-sync site)")
+                        f"device values on device until after the loop")
             self.generic_visit(node)
 
     v = V(rel)
