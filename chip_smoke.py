@@ -31,7 +31,7 @@ Legs (main path first):
              7157x1069 rank 100 (MovieLens-10M's geometry, on no tile): each
              compiled program contains the Mosaic call (the Pallas hop ran)
   kernels    flash attention L=16384 H=8 causal (Dh=64 packed, Dh=128) and
-             the rank-32 SPD solve: Mosaic in the compiled text, results
+             the rank-32 and rank-100 SPD solves: Mosaic in the compiled text, results
              within a stated tolerance of their XLA/numpy references
   restart    harp_tpu.run kmeans --max-restarts 1 with a scripted crash:
              the first child dies holding the chip, the second resumes
@@ -398,27 +398,30 @@ def leg_kernels() -> dict:
         out[f"flash_dh{dh}"] = {"max_abs_err": err, "tol": tol,
                                 "mosaic": True}
 
-    # the ALS normal-equation solve at its bench shape (8192 systems, rank
-    # 32), through the solver dispatch ALS itself calls; reference: numpy
-    # float64. Tolerance as tests/test_aux_sp.py.
-    n, rank, tol = 8192, 32, 2e-3
-    rng = np.random.default_rng(7)
-    vmat = rng.standard_normal((n, 64, rank)).astype(np.float32)
-    a = np.matmul(vmat.transpose(0, 2, 1), vmat) \
-        + 0.5 * np.eye(rank, dtype=np.float32)
-    b = rng.standard_normal((n, rank)).astype(np.float32)
-    cfg = als.ALSConfig(rank=rank)
-    solve = jax.jit(lambda a_, b_: als._spd_solve(a_, b_, cfg))
-    aj, bj = jnp.asarray(a), jnp.asarray(b)
-    exe = solve.lower(aj, bj).compile()
-    _assert_mosaic(exe.as_text(), "spd solve")
-    got = np.asarray(exe(aj, bj))
-    want = np.linalg.solve(a.astype(np.float64),
-                           b.astype(np.float64)[..., None])[..., 0]
-    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
-    out["spd_solve_rank32"] = {
-        "max_abs_err": float(np.max(np.abs(got - want))), "tol": tol,
-        "mosaic": True}
+    # the ALS normal-equation solve through the solver dispatch ALS itself
+    # calls, which packs the systems to the block-upper triangle the kernel
+    # reads: 8192 systems at rank 32 (4 sublane groups) and 4096 at the
+    # benchmark cell's rank 100 (stored 104: 13 groups, 5,824 packed rows);
+    # reference: numpy float64. Tolerance as tests/test_aux_sp.py.
+    tol = 2e-3
+    for n, rank in ((8192, 32), (4096, 100)):
+        rng = np.random.default_rng(7)
+        vmat = rng.standard_normal((n, 2 * rank, rank)).astype(np.float32)
+        a = np.matmul(vmat.transpose(0, 2, 1), vmat) \
+            + 0.5 * np.eye(rank, dtype=np.float32)
+        b = rng.standard_normal((n, rank)).astype(np.float32)
+        cfg = als.ALSConfig(rank=rank)
+        solve = jax.jit(lambda a_, b_, cfg=cfg: als._spd_solve(a_, b_, cfg))
+        aj, bj = jnp.asarray(a), jnp.asarray(b)
+        exe = solve.lower(aj, bj).compile()
+        _assert_mosaic(exe.as_text(), f"spd solve rank {rank}")
+        got = np.asarray(exe(aj, bj))
+        want = np.linalg.solve(a.astype(np.float64),
+                               b.astype(np.float64)[..., None])[..., 0]
+        np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+        out[f"spd_solve_rank{rank}"] = {
+            "max_abs_err": float(np.max(np.abs(got - want))), "tol": tol,
+            "mosaic": True}
     out["cache"] = stats.row()
     return out
 

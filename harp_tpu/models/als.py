@@ -289,14 +289,15 @@ def _train(u_data, i_data, u0, v0, u_rpw: int, i_rpw: int, cfg: ALSConfig,
 # --------------------------------------------------------------------------- #
 
 # What one dense half-step may hold beside the resident planes. A side's
-# normal equations are K² float32 a row (2.9 GB for MovieLens-10M's users at
-# rank 100) and its outer-product operand K² bfloat16 a row of the OTHER
-# side (1.5 GB for the items' half-step there), so a half-step runs in row
-# blocks, each contracting the other side in chunks; both sizes are derived
-# from the shapes and this budget.
+# normal equations are P float32 a row, P = 5,824 packed entries of the
+# 104 x 104 at rank 100 (pallas_kernels.spd_pack_rows; 1.7 GB for
+# MovieLens-10M's users), and its outer-product operand P bfloat16 a row of
+# the OTHER side (0.8 GB for the items' half-step there), so a half-step
+# runs in row blocks, each contracting the other side in chunks; both sizes
+# are derived from the shapes and this budget.
 DENSE_SCRATCH_BYTES = 2 * 1024 ** 3
-_CHUNK_COLS = 16384         # widest contraction chunk (K² x 16384 bf16 =
-#   0.35 GB at rank 100)
+_CHUNK_COLS = 16384         # widest contraction chunk (P x 16384 bf16 =
+#   0.19 GB at rank 100)
 
 
 def _row_block(rows: int, per_row: int, fixed: int = 0) -> Tuple[int, int]:
@@ -315,20 +316,23 @@ def _row_block(rows: int, per_row: int, fixed: int = 0) -> Tuple[int, int]:
 def _dense_blocks(rows: int, other: int, k: int) -> Tuple[int, int, int, int]:
     """``(row block, row blocks, chunk, chunks)`` of one dense half-step
     over a ``(rows, other)`` plane at rank ``k``. A row of a block costs its
-    normal equations twice (the GEMM's result and the solver's operand) and
-    two bf16 weights per chunk column; a chunk costs its outer products."""
-    kp = round_up(k, 8)
+    packed normal equations twice (the GEMM's result and the solver's
+    operand) and two bf16 weights per chunk column; a chunk costs its
+    packed outer products."""
+    packed = pallas_kernels.spd_pack_size(round_up(k, 8))
     chunks = -(-other // _CHUNK_COLS)
     chunk = other if chunks == 1 else round_up(-(-other // chunks), LANES)
-    block, blocks = _row_block(rows, 8 * kp * kp + 4 * chunk,
-                               fixed=2 * kp * kp * chunk)
+    block, blocks = _row_block(rows, 8 * packed + 4 * chunk,
+                               fixed=2 * packed * chunk)
     return block, blocks, chunk, chunks
 
 
 def _spd_solve_lanes(at, bt, cfg: ALSConfig):
-    """Solve batch-last systems: ``at`` (Kp, Kp, B) float32, ``bt`` (Kp, B),
-    the rank padded to Kp by an identity block → x (B, K). The Pallas kernel
-    reads them as they lie; any other solver takes them batch-first."""
+    """Solve batch-last systems: ``at`` (P, B) float32, the packed entries
+    the kernel reads (``pallas_kernels.spd_pack_rows``), ``bt`` (Kp, B), the
+    rank padded to Kp by an identity block → x (B, K). The Pallas kernel
+    reads them as they lie; any other solver takes the full matrices
+    batch-first."""
     k = cfg.rank
     pallas = _resolve_solver(cfg) == "pallas"
     # runs when jax traces, only: which solve this program's half-steps run
@@ -336,7 +340,9 @@ def _spd_solve_lanes(at, bt, cfg: ALSConfig):
     if pallas:
         xt = pallas_kernels.spd_solve_lanes(at, bt, interpret=_interpret(cfg))
         return xt[:k].T
-    return _spd_solve(jnp.transpose(at, (2, 0, 1))[:, :k, :k], bt[:k].T, cfg)
+    full = pallas_kernels.spd_unpack(at, bt.shape[0])
+    return _spd_solve(jnp.transpose(full, (2, 0, 1))[:, :k, :k], bt[:k].T,
+                      cfg)
 
 
 def _half_step_dense(factor_other, val_plane, rpw: int, cfg: ALSConfig,
@@ -350,10 +356,13 @@ def _half_step_dense(factor_other, val_plane, rpw: int, cfg: ALSConfig,
     f32 accumulation (the dense SGD-MF precision contract); V'V from the
     float32 factors at ``Precision.HIGHEST``.
 
-    The product is taken TRANSPOSED, (K², E) x (rows, E)ᵀ → (K², rows): the
-    systems leave the MXU batch-last, as the solve kernel reads them, with
-    the rank padded to a sublane multiple by zero outer products (the
-    regulariser puts 1 on the padded diagonal). Runs in row blocks, each
+    v vᵀ is symmetric and the solve kernel reads a block-upper triangle of
+    it, so only those P of the Kp² products are ever formed
+    (``pallas_kernels.spd_pack_rows``; 5,824 of 10,816 at rank 100). The
+    product is taken TRANSPOSED, (P, E) x (rows, E)ᵀ → (P, rows): the
+    systems leave the MXU packed and batch-last, as the solve kernel reads
+    them, with the rank padded to a sublane multiple by zero outer products
+    (the regulariser puts 1 on the padded diagonal). Runs in row blocks, each
     contracting E in chunks (:func:`_dense_blocks`; ``blocks`` overrides
     them); the last block and chunk are taken flush with the end, and what a
     chunk then shares with the one before is weighted 0."""
@@ -361,9 +370,11 @@ def _half_step_dense(factor_other, val_plane, rpw: int, cfg: ALSConfig,
     kp = round_up(k, 8)
     e = factor_other.shape[0]
     rb, n_rb, ce, n_ce = blocks or _dense_blocks(rpw, e, k)
+    packed = pallas_kernels.spd_pack_size(kp)            # P
     f32, bf16 = jnp.float32, jnp.bfloat16
-    # runs when jax traces, only
+    # run when jax traces, only
     metrics.DEFAULT.count("als.row_blocks", n_rb)
+    metrics.DEFAULT.count("als.gram.rows", packed)
     with jax.named_scope("als.outer"):
         f_t = jnp.pad(factor_other.T, ((0, kp - k), (0, 0))).astype(bf16)
     with jax.named_scope("als.gram"):
@@ -376,14 +387,15 @@ def _half_step_dense(factor_other, val_plane, rpw: int, cfg: ALSConfig,
                 precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=f32)
             shift = shift + jnp.pad(gram, ((0, kp - k), (0, kp - k)))
+        shift = pallas_kernels.spd_pack(shift)
 
     def normal_equations(r0, c0, lo):
-        """(K², rb) and (Kp, rb) of the plane's block at (r0, c0); columns
+        """(P, rb) and (Kp, rb) of the plane's block at (r0, c0); columns
         before ``lo`` belong to the chunk before."""
         blk = jax.lax.dynamic_slice(val_plane, (r0, c0), (rb, ce))
         f_c = jax.lax.dynamic_slice_in_dim(f_t, c0, ce, 1)
         with jax.named_scope("als.outer"):
-            vv = (f_c[:, None, :] * f_c[None, :, :]).reshape(kp * kp, ce)
+            vv = pallas_kernels.spd_pack_outer(f_c)
         obs = jnp.isfinite(blk) & (c0 + jnp.arange(ce) >= lo)[None, :]
         vz = jnp.where(obs, blk, 0).astype(bf16)
         if cfg.implicit:
@@ -413,13 +425,15 @@ def _half_step_dense(factor_other, val_plane, rpw: int, cfg: ALSConfig,
             else:
                 a, b = jax.lax.fori_loop(
                     0, n_ce, chunk,
-                    (jnp.zeros((kp * kp, rb), f32), jnp.zeros((kp, rb), f32)))
+                    (jnp.zeros((packed, rb), f32), jnp.zeros((kp, rb), f32)))
         with jax.named_scope("als.solve"):
-            x = _spd_solve_lanes(a.reshape(kp, kp, rb) + shift[:, :, None],
-                                 b, cfg)
+            x = _spd_solve_lanes(a + shift[:, None], b, cfg)
             return jax.lax.dynamic_update_slice_in_dim(out, x, r0, 0)
 
-    return jax.lax.fori_loop(0, n_rb, block, jnp.zeros((rpw, k), f32))
+    # what the compiler makes of the loop itself (it keeps the carried bf16
+    # factors in fast memory, slice by slice) has no name of its own
+    with jax.named_scope("als.gram"):
+        return jax.lax.fori_loop(0, n_rb, block, jnp.zeros((rpw, k), f32))
 
 
 def _monitor_dense(u_block, v, u_plane, cfg: ALSConfig):

@@ -684,6 +684,13 @@ def use_flash_head_pack(h: int, dh: int, dv: int) -> bool:
 # right unit for K x K systems of this size; the VPU at full lane occupancy
 # is.
 #
+# The operand is PACKED (:func:`spd_pack_rows`): the factorisation reads row
+# j of its working copy only from the start of j's sublane group on, so a
+# caller hands over exactly those entries, the block-upper triangle, as the
+# rows of a (P, B) array, and the normal equations never exist in full: 5,824
+# rows of the 10,816 at K = 104. The kernel's first act lays them out as the
+# (K, K, B) working copy, a static aligned copy per sublane group.
+#
 # Only the sublane groups of 8 columns are unrolled; the columns of a group
 # and the trailing update of a column (the rows below it: a dynamic index on
 # the leading dim is free) are loops, and the update touches only the groups
@@ -703,13 +710,77 @@ SPD_SOLVE_NAME = "als_spd_solve"
 SPD_SOLVE_TILES = (4 * lane_pack.LANES, 2 * lane_pack.LANES, lane_pack.LANES)
 
 
+# imported here: a line added above the kernels of this file changes the
+# source lines their payloads carry, and with them the compile cache's key of
+# every step that holds one (PERF.md, Findings, PR 29)
+import numpy as np  # noqa: E402
+
+
+def spd_pack_rows(kp: int):
+    """``(rows_j, rows_c)``: the entries of a symmetric ``(kp, kp)`` matrix
+    the solve kernel reads, in the order its packed operand holds them. Row
+    ``p`` is the pair ``(j, c)`` with ``c >= 8 * (j // 8)``, ordered by ``j``
+    then ``c``: row ``j``'s segment is contiguous, starts at a multiple of 8
+    and is ``kp - 8 * (j // 8)`` long. Every ``c >= j`` is there once (and
+    the few ``c < j`` of j's own group); ``kp`` = 8 packs to the whole
+    matrix. Static NumPy arrays, from ``kp`` alone."""
+    if kp <= 0 or kp % 8:
+        raise ValueError(f"spd_pack_rows: kp = {kp} is no multiple of 8")
+    j, c = np.divmod(np.arange(kp * kp, dtype=np.int32), kp)
+    keep = c >= 8 * (j // 8)
+    return j[keep], c[keep]
+
+
+def _spd_group_offset(kp: int, g: int) -> int:
+    """The packed row at which sublane group ``g`` starts: 8 rows of
+    ``kp - 8 h`` entries for every group ``h`` before it."""
+    return 8 * g * kp - 32 * g * (g - 1)
+
+
+def spd_pack_size(kp: int) -> int:
+    """P, the rows of the packed operand: 5,824 at ``kp`` = 104."""
+    return _spd_group_offset(kp, kp // 8)
+
+
+def spd_pack(full: jax.Array) -> jax.Array:
+    """``full[rows_j, rows_c]`` of :func:`spd_pack_rows`, ``(kp, kp, ...)``
+    → ``(P, ...)``, as one static slice a sublane group (an element-wise
+    gather is the slow way to say it on the TPU)."""
+    kp = full.shape[0]
+    return jnp.concatenate([
+        full[g:g + 8, g:].reshape(8 * (kp - g), *full.shape[2:])
+        for g in range(0, kp, 8)])
+
+
+def spd_pack_outer(f: jax.Array) -> jax.Array:
+    """The packed rows of the outer products of ``f`` (kp, n) with itself,
+    ``f[rows_j] * f[rows_c]`` → ``(P, n)``, a sublane group's slab at a
+    time: :func:`spd_pack` of the whole ``(kp, kp, n)`` product would have
+    XLA form it first (two gathers of P rows each is the third way)."""
+    kp = f.shape[0]
+    return jnp.concatenate([
+        (f[g:g + 8, None] * f[None, g:]).reshape(8 * (kp - g), *f.shape[1:])
+        for g in range(0, kp, 8)])
+
+
+def spd_unpack(at: jax.Array, kp: int) -> jax.Array:
+    """The full systems ``(kp, kp, N)``, both triangles, from the packed
+    ``(P, N)``: what a solver other than the kernel reads. An entry below
+    its row's sublane group is read from its mirror image."""
+    rows_j, rows_c = spd_pack_rows(kp)
+    where = np.empty((kp, kp), np.int32)
+    where[rows_c, rows_j] = np.arange(len(rows_j))
+    where[rows_j, rows_c] = np.arange(len(rows_j))    # a kept entry: itself
+    return at[where.reshape(-1)].reshape(kp, kp, *at.shape[1:])
+
+
 def spd_solve_vmem_bytes(k: int, tile_b: int) -> int:
-    """VMEM one grid step of the solve holds, from above: the (K, K, B)
-    operand block twice (the pipeline's two buffers) and once more as the
-    working copy the factorisation overwrites, a dozen (K, B) rows (b, x,
-    the reciprocal diagonal, each double-buffered or live), 2 MiB."""
+    """VMEM one grid step of the solve holds, from above: the packed (P, B)
+    operand block twice (the pipeline's two buffers), the (K, K, B) working
+    copy the factorisation overwrites, a dozen (K, B) rows (b, x, the
+    reciprocal diagonal, each double-buffered or live), 2 MiB."""
     kp = lane_pack.round_up(k, 8)
-    return 4 * tile_b * (3 * kp * kp + 12 * kp) + (2 << 20)
+    return 4 * tile_b * (2 * spd_pack_size(kp) + kp * kp + 12 * kp) + (2 << 20)
 
 
 def spd_solve_tile(k: int) -> int:
@@ -724,10 +795,13 @@ def spd_solve_tile(k: int) -> int:
 
 def _chol_solve_kernel(a_ref, b_ref, x_ref, l_ref, dinv_ref, col_ref, *,
                        k: int):
-    """One batch tile: A (k, k, B) SPD with both triangles, b (k, B) →
-    x (k, B); k a multiple of 8.
+    """One batch tile: A packed (P, B) (:func:`spd_pack_rows`), SPD, b (k, B)
+    → x (k, B); k a multiple of 8.
 
-    Right-looking Cholesky on a working copy ``l_ref``. At column j, row j of
+    Right-looking Cholesky on a working copy ``l_ref`` (k, k, B), of which
+    only ``l_ref[j, 8 * (j // 8):]`` is ever read: the packed rows land
+    there, and what lies below (never initialised, updated all the same by
+    the trailing loop) reaches no result. At column j, row j of
     the running Schur complement (= its column j: the trailing block is kept
     symmetric) scaled by 1/sqrt(d_j) is column j of L; it overwrites row j,
     which nothing reads again, so ``l_ref[j, c]`` ends as ``L[c, j]``. Row
@@ -745,7 +819,10 @@ def _chol_solve_kernel(a_ref, b_ref, x_ref, l_ref, dinv_ref, col_ref, *,
     index in ``col_ref`` (k/8, 8, B), where row i's is a static sublane."""
     tb = b_ref.shape[-1]
     groups = k // 8
-    l_ref[...] = a_ref[...].astype(jnp.float32)
+    for g in range(groups):                   # row j's segment → l_ref[j, c0:]
+        c0, off = 8 * g, _spd_group_offset(k, g)
+        l_ref[c0:c0 + 8, c0:, :] = a_ref[off:off + 8 * (k - c0), :].reshape(
+            8, k - c0, tb)
     x_ref[...] = b_ref[...].astype(jnp.float32)
     lane8 = jax.lax.broadcasted_iota(jnp.int32, (8, tb), 0)
 
@@ -803,27 +880,29 @@ def _chol_solve_kernel(a_ref, b_ref, x_ref, l_ref, dinv_ref, col_ref, *,
 
 def spd_solve_lanes(at: jax.Array, bt: jax.Array, tile_b: Optional[int] = None,
                     interpret: bool = False) -> jax.Array:
-    """Solve batch-last SPD systems: at (K, K, N) float32 with both
-    triangles, bt (K, N) → (K, N); K a multiple of 8. The layout the kernel
-    reads: a caller that builds its systems batch-last (ALS's dense
-    half-step) pays no relayout. N is padded up to the lane tile with
-    identity systems."""
+    """Solve batch-last SPD systems: at (P, N) float32, the packed entries of
+    the (K, K) matrices (:func:`spd_pack_rows`), bt (K, N) → (K, N); K a
+    multiple of 8. The layout the kernel reads: a caller that builds its
+    systems packed and batch-last (ALS's dense half-step) pays no relayout.
+    N is padded up to the lane tile with identity systems."""
     k, n = bt.shape
-    if at.shape != (k, k, n) or k % 8:
+    if k % 8 or at.shape != (spd_pack_size(k), n):
         raise ValueError(f"spd_solve_lanes: at {at.shape} vs bt {bt.shape}")
+    packed = at.shape[0]
     tile_b = tile_b or spd_solve_tile(k)
     if not tile_b:
         raise ValueError(f"spd_solve_lanes: k = {k} does not fit VMEM")
     npad = lane_pack.round_up(n, tile_b)
     if npad != n:
         at = jnp.concatenate([at, jnp.broadcast_to(
-            jnp.eye(k, dtype=at.dtype)[:, :, None], (k, k, npad - n))], axis=2)
+            spd_pack(jnp.eye(k, dtype=at.dtype))[:, None],
+            (packed, npad - n))], axis=1)
         bt = jnp.pad(bt, ((0, 0), (0, npad - n)))
     xt = pl.pallas_call(
         functools.partial(_chol_solve_kernel, k=k),
         grid=(npad // tile_b,),
         in_specs=[
-            pl.BlockSpec((k, k, tile_b), lambda i: (0, 0, i)),
+            pl.BlockSpec((packed, tile_b), lambda i: (0, i)),
             pl.BlockSpec((k, tile_b), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((k, tile_b), lambda i: (0, i)),
@@ -847,8 +926,9 @@ def spd_solve_pallas(a: jax.Array, b: jax.Array, tile_b: Optional[int] = None,
 
     Pads K up to a sublane multiple (identity diagonal, zero rhs — padded
     components solve to 0 and never couple) and moves the batch onto the
-    lanes: the (N, K, K) → (K, K, N) transpose is one HBM-bound XLA pass
-    (:func:`spd_solve_lanes` takes operands that are built batch-last)."""
+    lanes, packing on the way (:func:`spd_pack`): the (N, K, K) → (P, N)
+    move is one HBM-bound XLA pass that writes only the entries the kernel reads
+    (:func:`spd_solve_lanes` takes operands that are built that way)."""
     n, k = b.shape
     if a.shape != (n, k, k):
         raise ValueError(f"spd_solve_pallas: a {a.shape} vs b {b.shape}")
@@ -857,14 +937,14 @@ def spd_solve_pallas(a: jax.Array, b: jax.Array, tile_b: Optional[int] = None,
         a = jnp.pad(a, ((0, 0), (0, kp - k), (0, kp - k)))
         a = a + jnp.diag((jnp.arange(kp) >= k).astype(a.dtype))[None]
         b = jnp.pad(b, ((0, 0), (0, kp - k)))
-    xt = spd_solve_lanes(jnp.transpose(a, (1, 2, 0)), jnp.transpose(b, (1, 0)),
-                         tile_b, interpret)
+    xt = spd_solve_lanes(jnp.transpose(jax.vmap(spd_pack)(a), (1, 0)),
+                         jnp.transpose(b, (1, 0)), tile_b, interpret)
     return jnp.transpose(xt, (1, 0))[:, :k]
 
 
 def use_spd_solve_pallas(k: int) -> bool:
     """Dispatch predicate: default ON for TPU wherever a lane tile of the
-    (K, K, B) working set fits VMEM (:func:`spd_solve_tile`: K up to ~250)."""
+    working set fits VMEM (:func:`spd_solve_tile`: K up to ~300)."""
     if jax.default_backend() != "tpu":
         return False
     return spd_solve_tile(k) > 0

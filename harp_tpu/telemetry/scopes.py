@@ -59,8 +59,8 @@ SCOPES = (
     "sgdmf.select",     # picking the resident bucket of the slab
     "sgdmf.stripes",    # the masked stripe update (XLA, Pallas or sparse)
     "sgdmf.rmse",       # per-epoch quality: two psums and a square root
-    "als.outer",        # the factor's row-wise outer products, K² a row
-    "als.gram",         # the weights, the plane GEMM (K², rows) and V'V
+    "als.outer",        # the factor's row-wise outer products, packed: P a row
+    "als.gram",         # the weights, the plane GEMM (P, rows) and V'V
     "als.rhs",          # the right-hand sides: weights x factors
     "als.solve",        # the batched K x K SPD solve and the block's way out
     "als.monitor",      # per-iteration quality over the observed cells

@@ -1,5 +1,6 @@
 """The dense ALS half-step in row blocks, the batched SPD solve past k = 64
-and the span layer of ``ALS`` (ISSUE 27), at sizes a CPU test can hold."""
+and the span layer of ``ALS`` (ISSUE 27), and the packed block-triangle
+operand both stand on (ISSUE 30), at sizes a CPU test can hold."""
 
 import importlib.util
 import os
@@ -73,18 +74,95 @@ def test_contraction_chunks_agree_to_float32_rounding(implicit):
 
 
 def test_blocks_of_the_cells_shape_fit_the_scratch_budget():
-    k, kp = 100, 104
+    k, packed = 100, 5824
     for rows, others in ((71_567, 10_681), (10_681, 71_567)):
         rb, n_rb, ce, n_ce = als._dense_blocks(rows, others, k)
         assert rb * n_rb >= rows and ce * n_ce >= others
         assert n_rb == 1 or (rb % 512 == 0 and rb <= rows)
         assert n_ce == 1 or (ce % 128 == 0 and ce <= min(others, 16384))
-        scratch = rb * (8 * kp * kp + 4 * ce) + 2 * kp * kp * ce
+        scratch = rb * (8 * packed + 4 * ce) + 2 * packed * ce
         assert scratch <= als.DENSE_SCRATCH_BYTES
-    # the users' side is what has to be blocked: 2.9 GB of systems at once
-    assert als._dense_blocks(71_567, 10_681, k)[1] > 1
-    assert als._dense_blocks(10_681, 71_567, k)[3] > 1
+    # the users' side is what has to be blocked: 1.7 GB of packed systems at
+    # once; in four blocks where the full matrices took five
+    assert als._dense_blocks(71_567, 10_681, k) == (17_920, 4, 10_681, 1)
+    assert als._dense_blocks(10_681, 71_567, k) == (10_681, 1, 14_336, 5)
     assert als._row_block(300, 8 * 200) == (300, 1)
+
+
+def _full_half_step(f, plane, cfg, blocks):
+    """The half-step over the FULL normal equations, all Kp² outer products
+    against the weights and ``(Kp, Kp, rb)`` into the exact solver: what the
+    packed operand replaced, on the same blocks in the same order."""
+    k, (rows, e) = cfg.rank, plane.shape
+    kp = als.round_up(k, 8)
+    rb, n_rb, ce, n_ce = blocks
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    f_t = jnp.pad(f.T, ((0, kp - k), (0, 0))).astype(bf16)
+    shift = jnp.diag(jnp.where(jnp.arange(kp) < k, cfg.lam, 1.0).astype(f32))
+    if cfg.implicit:
+        gram = jax.lax.dot_general(f, f, (((0,), (0,)), ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=f32)
+        shift = shift + jnp.pad(gram, ((0, kp - k), (0, kp - k)))
+    out = np.zeros((rows, k), np.float32)
+    for i in range(n_rb):
+        r0 = min(i * rb, rows - rb)
+        a, b = jnp.zeros((kp * kp, rb), f32), jnp.zeros((kp, rb), f32)
+        for c in range(n_ce):
+            c0 = min(c * ce, e - ce)
+            blk, f_c = plane[r0:r0 + rb, c0:c0 + ce], f_t[:, c0:c0 + ce]
+            vv = (f_c[:, None, :] * f_c[None, :, :]).reshape(kp * kp, ce)
+            obs = jnp.isfinite(blk) & (c0 + jnp.arange(ce) >= c * ce)[None, :]
+            vz = jnp.where(obs, blk, 0).astype(bf16)
+            if cfg.implicit:
+                w_a = (cfg.alpha * vz).astype(bf16)
+                w_b = jnp.where(obs, 1.0 + cfg.alpha * vz.astype(f32),
+                                0.0).astype(bf16)
+            else:
+                w_a, w_b = obs.astype(bf16), vz
+            da = jax.lax.dot_general(vv, w_a, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=f32)
+            db = jax.lax.dot_general(f_c, w_b, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=f32)
+            a, b = (da, db) if n_ce == 1 else (a + da, b + db)
+        full = a.reshape(kp, kp, rb) + shift[:, :, None]
+        out[r0:r0 + rb] = als._spd_solve(
+            jnp.transpose(full, (2, 0, 1))[:, :k, :k], b[:k].T, cfg)
+    return out
+
+
+@pytest.mark.parametrize("blocks", [(300, 1, 333, 1), (128, 3, 333, 1),
+                                    (128, 3, 128, 3)],
+                         ids=["whole", "blocked", "chunked"])
+@pytest.mark.parametrize("implicit", (True, False))
+def test_the_packed_half_step_is_the_full_one_bitwise(implicit, blocks):
+    """The kept entries are the same bf16 products summed in the same
+    order, and the exact solver is handed them back in both triangles."""
+    rng = np.random.default_rng(9)
+    rows, others, k = 300, 333, 12
+    plane, _ = _plane(rng, rows, others)
+    f = _factors(rng, others, k)
+    cfg = als.ALSConfig(rank=k, lam=0.05, alpha=40.0, implicit=implicit)
+    packed = np.asarray(als._half_step_dense(f, plane, rows, cfg,
+                                             blocks=blocks))
+    assert np.array_equal(packed, _full_half_step(f, plane, cfg, blocks))
+
+
+@pytest.mark.parametrize("rows, others", [(71_567, 10_681), (10_681, 71_567)])
+def test_no_product_of_the_cells_half_steps_has_k_squared_rows(rows, others):
+    """Lowered from shapes alone at the cell's two sides: the plane GEMM's M
+    is the 5,824 packed rows, and no product is 10,816 long (off the TPU the
+    exact solver's operand is, unpacked behind the GEMM)."""
+    cfg = als.ALSConfig(rank=100, lam=0.05, alpha=40.0, layout="dense")
+    text = jax.jit(lambda f, plane: als._half_step_dense(
+        f, plane, rows, cfg)).lower(
+        jax.ShapeDtypeStruct((others, 100), jnp.float32),
+        jax.ShapeDtypeStruct((rows, others), jnp.bfloat16)).as_text()
+    dots = [line for line in text.splitlines() if "dot_general" in line]
+    rb, _, ce, _ = als._dense_blocks(rows, others, 100)
+    assert any(f"tensor<5824x{ce}xbf16>" in line
+               and f"-> tensor<5824x{rb}xf32>" in line for line in dots), dots
+    assert not [line for line in dots if "10816" in line]
 
 
 def test_the_monitor_in_blocks_counts_every_observed_cell_once(monkeypatch):
@@ -111,10 +189,48 @@ def _spd_batch(rng, n, k):
     return a, rng.standard_normal((n, k)).astype(np.float32)
 
 
-@pytest.mark.parametrize("k, n", [(32, 200), (64, 128), (100, 130)])
+@pytest.mark.parametrize("kp, packed", [(8, 64), (16, 192), (104, 5824)])
+def test_the_packing_is_the_block_upper_triangle_row_by_row(kp, packed):
+    rows_j, rows_c = pallas_kernels.spd_pack_rows(kp)
+    want = [(j, c) for j in range(kp) for c in range(8 * (j // 8), kp)]
+    assert list(zip(rows_j.tolist(), rows_c.tolist())) == want
+    assert len(want) == packed == pallas_kernels.spd_pack_size(kp)
+    pairs = set(want)
+    assert len(pairs) == packed                       # each once
+    assert all((j, c) in pairs for j in range(kp) for c in range(j, kp))
+    for j in range(kp):                     # a row's segment, 8-aligned
+        (at,) = np.nonzero(rows_j == j)
+        assert at[0] % 8 == 0 and len(at) == kp - 8 * (j // 8)
+        assert np.array_equal(at, np.arange(at[0], at[0] + len(at)))
+        assert np.array_equal(rows_c[at], np.arange(8 * (j // 8), kp))
+        if j % 8 == 0:
+            assert at[0] == pallas_kernels._spd_group_offset(kp, j // 8)
+    with pytest.raises(ValueError):
+        pallas_kernels.spd_pack_rows(12)
+
+
+@pytest.mark.parametrize("kp", [8, 16, 104])
+def test_pack_reads_the_listed_entries_and_unpack_gives_them_back(kp):
+    a, _ = _spd_batch(np.random.default_rng(kp), 5, kp)
+    at = jnp.transpose(jnp.asarray(a), (1, 2, 0))
+    rows_j, rows_c = pallas_kernels.spd_pack_rows(kp)
+    assert np.array_equal(a, np.transpose(a, (0, 2, 1)))
+    packed = pallas_kernels.spd_pack(at)
+    assert np.array_equal(packed, at[rows_j, rows_c])
+    back = pallas_kernels.spd_unpack(packed, kp)
+    assert back.shape == (kp, kp, 5) and np.array_equal(back, at)
+    # the outer products' rows, formed slab by slab, are the same entries
+    f = jnp.asarray(a[0], jnp.bfloat16)
+    assert np.array_equal(pallas_kernels.spd_pack_outer(f),
+                          f[rows_j] * f[rows_c])
+
+
+@pytest.mark.parametrize("k, n", [(8, 70), (12, 150), (32, 200), (64, 128),
+                                  (100, 130)])
 def test_spd_solve_pallas_interpret_matches_linalg_solve(k, n):
     """Batches that are and are not a multiple of the 128 lanes; k = 100 is
-    padded to 104 by an identity block that leaves the solution alone."""
+    padded to 104 by an identity block that leaves the solution alone, and
+    k = 8 packs to its whole matrix."""
     a, b = _spd_batch(np.random.default_rng(k), n, k)
     want = jnp.linalg.solve(jnp.asarray(a), jnp.asarray(b)[..., None])[..., 0]
     got = pallas_kernels.spd_solve_pallas(jnp.asarray(a), jnp.asarray(b),
@@ -124,18 +240,42 @@ def test_spd_solve_pallas_interpret_matches_linalg_solve(k, n):
                                rtol=0, atol=2e-5 * np.abs(want).max())
 
 
-def test_spd_solve_lanes_reads_the_systems_batch_last():
+def test_spd_solve_lanes_reads_the_systems_packed_and_batch_last():
     k, n = 16, 70
     a, b = _spd_batch(np.random.default_rng(3), n, k)
     want = np.linalg.solve(a.astype(np.float64), b.astype(np.float64)[..., None])
+    rows_j, rows_c = pallas_kernels.spd_pack_rows(k)
     got = pallas_kernels.spd_solve_lanes(
-        jnp.transpose(jnp.asarray(a), (1, 2, 0)), jnp.asarray(b).T,
+        jnp.asarray(a[:, rows_j, rows_c].T), jnp.asarray(b).T,
         tile_b=128, interpret=True)
     assert got.shape == (k, n)
     np.testing.assert_allclose(np.asarray(got).T, want[..., 0], atol=1e-4)
-    with pytest.raises(ValueError):
-        pallas_kernels.spd_solve_lanes(jnp.zeros((10, 10, 8)),
-                                       jnp.zeros((10, 8)), interpret=True)
+    for at, bt in [(jnp.zeros((16, 16, 8)), jnp.zeros((16, 8))),  # unpacked
+                   (jnp.zeros((100, 8)), jnp.zeros((10, 8)))]:
+        with pytest.raises(ValueError):
+            pallas_kernels.spd_solve_lanes(at, bt, interpret=True)
+
+
+@pytest.mark.parametrize("k", [16, 104])
+def test_the_kernel_never_needed_the_entries_the_packing_drops(k):
+    """NaN in every entry below its row's sublane group, before packing:
+    the same bits as from the symmetric matrices."""
+    n = 40
+    a, b = _spd_batch(np.random.default_rng(k), n, k)
+    rows_j, rows_c = pallas_kernels.spd_pack_rows(k)
+    j, c = np.divmod(np.arange(k * k), k)
+    poisoned = a.copy().reshape(n, k * k)
+    poisoned[:, c < 8 * (j // 8)] = np.nan
+    poisoned = poisoned.reshape(n, k, k)
+    assert np.isnan(poisoned).sum() == n * (k * k - len(rows_j))
+
+    def solve(m):
+        return np.asarray(pallas_kernels.spd_solve_lanes(
+            jnp.asarray(m[:, rows_j, rows_c].T), jnp.asarray(b).T,
+            tile_b=128, interpret=True))
+
+    got = solve(poisoned)
+    assert np.isfinite(got).all() and np.array_equal(got, solve(a))
 
 
 def test_the_dispatch_decides_from_backend_rank_and_vmem(monkeypatch):
@@ -146,8 +286,9 @@ def test_the_dispatch_decides_from_backend_rank_and_vmem(monkeypatch):
     assert pallas_kernels.use_spd_solve_pallas(128)
     assert not pallas_kernels.use_spd_solve_pallas(512)      # 400 MB a tile
     assert pallas_kernels.spd_solve_tile(32) == 512
-    assert pallas_kernels.spd_solve_tile(100) == 512         # 71 MB
-    assert pallas_kernels.spd_solve_tile(128) == 256
+    assert pallas_kernels.spd_solve_tile(100) == 512         # 51 MB
+    assert pallas_kernels.spd_solve_tile(128) == 512         # 75 MB
+    assert pallas_kernels.spd_solve_tile(200) == 256
     assert (pallas_kernels.spd_solve_vmem_bytes(
         100, pallas_kernels.spd_solve_tile(100))
         <= pallas_kernels.SPD_SOLVE_VMEM_LIMIT)
@@ -267,6 +408,7 @@ def test_als_leaves_its_phases_marks_and_counters():
 
     assert grew("program.traces.als.fit") == 1
     assert grew("als.row_blocks") == 2           # one block a half-step
+    assert grew("als.gram.rows") == 2 * 64       # rank 8 packs to the whole
     assert grew("als.solve.xla") == 2 and grew("als.solve.pallas") == 0
 
 

@@ -138,9 +138,11 @@ def test_bench_collectives_smoke(session):
 
 def test_pallas_spd_solve_interpret_matches_scipy():
     """The lane-vectorized batched Cholesky solve (interpret mode) matches
-    jax.scipy's exact SPD solve, including K/N shapes that need padding."""
+    jax.scipy's exact SPD solve, including K/N shapes that need padding and
+    the K = 8 whose packed operand is the whole matrix."""
     rng = np.random.default_rng(7)
-    for n, k in [(256, 16), (300, 10)]:       # (aligned, needs K+N padding)
+    # (aligned, needs K+N padding, one sublane group)
+    for n, k in [(256, 16), (300, 10), (200, 8)]:
         g = rng.standard_normal((n, k, k)).astype(np.float32)
         a = g @ np.transpose(g, (0, 2, 1)) + 0.1 * np.eye(k, dtype=np.float32)
         b = rng.standard_normal((n, k)).astype(np.float32)

@@ -291,8 +291,9 @@ def test_the_als_iteration_fits_the_chip_at_the_cells_full_shape(
     """The row-blocked iteration lowers for a v5e at 71,567 x 10,681, rank
     100: the step's scratch stays inside the budget the blocks are derived
     from, planes and scratch inside the chip, and the systems reach the
-    solve kernel batch-last from the product itself (no relayout of the
-    normal equations stands between them)."""
+    solve kernel packed and batch-last from the product itself (no relayout
+    of the normal equations stands between them, and no product has the
+    10,816 rows of the full matrices)."""
     from harp_tpu.ops import pallas_kernels as pk
 
     step = _als_step(topo)
@@ -310,13 +311,17 @@ def test_the_als_iteration_fits_the_chip_at_the_cells_full_shape(
         assert mapped[name] == "als.solve"
         operand = re.search(r"custom-call\(%([\w.\-]+)", line).group(1)
         assert "convolution" in operand or "pad" in operand, line
+        assert "f32[5824," in line.split("custom-call(")[1], line
+    products = [line for line in text.splitlines() if " convolution(" in line]
+    assert any("f32[5824," in line for line in products)
+    assert not [line for line in products if "10816" in line]
 
 
-@pytest.mark.parametrize("rows", [14_336, 10_752])
+@pytest.mark.parametrize("rows", [17_920, 10_752])
 def test_the_solve_kernel_compiles_at_rank_100(topo, no_compile_cache, rows):
     """Mosaic accepts the batched Cholesky at k = 100 (stored 104) on a
-    row block of the cell, at the lane tile the dispatch picks and under the
-    VMEM limit its estimate gives."""
+    row block of the cell, its operand the 5,824 packed rows, at the lane
+    tile the dispatch picks and under the VMEM limit its estimate gives."""
     from jax.sharding import SingleDeviceSharding
 
     from harp_tpu.ops import pallas_kernels as pk
@@ -326,7 +331,7 @@ def test_the_solve_kernel_compiles_at_rank_100(topo, no_compile_cache, rows):
     assert pk.spd_solve_vmem_bytes(100, tile) <= pk.SPD_SOLVE_VMEM_LIMIT
     one = SingleDeviceSharding(topo.devices[0])
     text = jax.jit(pk.spd_solve_lanes).lower(
-        jax.ShapeDtypeStruct((104, 104, rows), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((5824, rows), jnp.float32, sharding=one),
         jax.ShapeDtypeStruct((104, rows), jnp.float32, sharding=one)
     ).compile().as_text()
     assert "tpu_custom_call" in text and pk.SPD_SOLVE_NAME in text
