@@ -12,7 +12,9 @@ Families (reference dirs → modules):
   daal_svm + contrib/svm            → models.svm
   daal_knn                          → models.knn
   daal_als (+ _batch)               → models.als
-  ccd/ (CCD++ MF)                   → models.ccd
+  ccd/ (CCD++ MF)                   → models.ccd (dense bf16 planes, one
+                                      layout; prepare / train_prepared;
+                                      a fused sweep kernel: ops.ccd_sweep)
   lda/ (CGS) + contrib/lda (CVB0)   → models.lda
   daal_nn                           → models.nn
   daal_optimization_solvers         → models.solvers

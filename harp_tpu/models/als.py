@@ -44,6 +44,7 @@ import numpy as np
 
 from harp_tpu import telemetry
 from harp_tpu.collectives import lax_ops
+from harp_tpu.models.dense_planes import dense_plane, place_dense_planes
 from harp_tpu.ops import pallas_kernels
 from harp_tpu.ops.lane_pack import LANES, round_up
 from harp_tpu.parallel.mesh import WORKERS
@@ -608,19 +609,14 @@ class ALS:
         stay in natural entity order (no slot permutation); padding rows sit
         past num_users/num_items and are zeroed so the implicit gram V'V is
         unbiased."""
-        import ml_dtypes
-
         sess, cfg = self.session, self.config
         w = sess.num_workers
         u_rpw = -(-num_users // w)
         i_rpw = -(-num_items // w)
         u_pad, i_pad = w * u_rpw, w * i_rpw
-        # build the user plane straight in bf16 on the host; the item plane
-        # is its transpose by construction and is made ON THE DEVICE (entries
-        # are already deduped): a strided host transpose of 1.5 GB and a
-        # second transfer were half of `prepare` at the MovieLens-10M shape
-        u_plane = np.full((u_pad, i_pad), np.nan, ml_dtypes.bfloat16)
-        u_plane[rows, cols] = vals.astype(ml_dtypes.bfloat16)
+        # the two planes, as models/ccd.py also keeps them: the users' built
+        # on the host, the items' transposed on the device
+        u_plane = dense_plane(rows, cols, vals, u_pad, i_pad)
         self.last_layout_stats = {
             "layout": "dense",
             "plane_bytes": 2 * u_pad * i_pad * 2,
@@ -634,13 +630,8 @@ class ALS:
         u0[num_users:] = 0.0
         v0[num_items:] = 0.0
         key = self._dense_program(u_rpw, i_rpw)
-        u_dev = sess.scatter(jnp.asarray(u_plane, jnp.bfloat16))
-        if "transpose" not in self._fns:
-            self._fns["transpose"] = jax.jit(
-                jnp.transpose, out_shardings=sess.sharding(sess.shard()))
-        with telemetry.phase("session.run"):
-            i_dev = self._fns["transpose"](u_dev)
-        placed = (u_dev, i_dev, sess.replicate_put(u0), sess.replicate_put(v0))
+        placed = (*place_dense_planes(sess, self._fns, u_plane),
+                  sess.replicate_put(u0), sess.replicate_put(v0))
         return (key, placed, np.arange(num_users), np.arange(num_items))
 
     def _dense_program(self, u_rpw: int, i_rpw: int):

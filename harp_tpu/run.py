@@ -737,13 +737,17 @@ def run_ccd(argv) -> int:
     rows, cols, vals = datagen.sparse_ratings(
         args.num_users, args.num_items, rank=min(cfg.rank, 8),
         density=args.density, seed=args.seed)
+    model = ccd.CCD(sess, cfg)
+    state = model.prepare(rows, cols, vals, args.num_users, args.num_items,
+                          seed=args.seed)
+    model.train_prepared(state)                   # compile + warmup
     t0 = time.perf_counter()
-    _, _, rmse = ccd.CCD(sess, cfg).fit(rows, cols, vals, args.num_users,
-                                        args.num_items, seed=args.seed)
+    _, _, rmse = model.fit_prepared(state)
     dt = time.perf_counter() - t0
     print(f"ccd workers={sess.num_workers} nnz={len(vals)} rank={cfg.rank}: "
-          f"{cfg.outer_iterations / dt:.2f} sweeps/s (incl compile), "
-          f"rmse {rmse[0]:.4f} -> {rmse[-1]:.4f}")
+          f"{cfg.outer_iterations / dt:.2f} sweeps/s, "
+          f"rmse {rmse[0]:.4f} -> {rmse[-1]:.4f}, "
+          f"layout {model.last_layout_stats}")
     return 0
 
 

@@ -40,6 +40,28 @@ from typing import Callable, Dict, Optional
 
 import jax
 
+def scoped(name: str) -> Callable:
+    """Decorator: run the function under the listed scope ``name``.
+
+    Stands ABOVE the list: a Pallas kernel traced under a scoped function
+    carries this wrapper's source line in its payload, and the compile
+    cache's key with it, so a line added above ``inner`` compiles every
+    such step anew. A name added to :data:`SCOPES` below moves nothing
+    (``tests/test_scopes.py`` holds the order)."""
+    if name not in _LISTED:
+        raise ValueError(f"{name!r} is not in telemetry.scopes.SCOPES")
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return wrap
+
+
 _TABLE_OPS = ("allreduce", "reduce", "broadcast", "regroup", "allgather",
               "aggregate", "rotate", "rotate_with_map", "push", "pull",
               "gather", "join", "group_by_key", "bucket_route", "route_back",
@@ -64,24 +86,11 @@ SCOPES = (
     "als.rhs",          # the right-hand sides: weights x factors
     "als.solve",        # the batched K x K SPD solve and the block's way out
     "als.monitor",      # per-iteration quality over the observed cells
+    "ccd.sweep",        # one fused pass over a side's plane: both row sums
+    "ccd.column",       # column t picked, the closed form, row t written back
+    "ccd.monitor",      # per-epoch RMSE over the observed cells
 )
 _LISTED = frozenset(SCOPES)
-
-
-def scoped(name: str) -> Callable:
-    """Decorator: run the function under the listed scope ``name``."""
-    if name not in _LISTED:
-        raise ValueError(f"{name!r} is not in telemetry.scopes.SCOPES")
-
-    def wrap(fn):
-        @functools.wraps(fn)
-        def inner(*args, **kwargs):
-            with jax.named_scope(name):
-                return fn(*args, **kwargs)
-
-        return inner
-
-    return wrap
 
 
 # -- readers ----------------------------------------------------------------- #

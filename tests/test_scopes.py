@@ -8,6 +8,7 @@ on-chip-measurement guide, section 2): only the worker that is handed this
 file loads the TPU's library. Nothing runs: a compile says nothing about time.
 """
 
+import dataclasses
 import os
 import re
 
@@ -17,7 +18,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding
 
-from harp_tpu.models import als, kmeans, sgd_mf
+from harp_tpu.models import als, ccd, kmeans, sgd_mf
 from harp_tpu.session import HarpSession
 from harp_tpu.telemetry import scopes
 
@@ -122,6 +123,32 @@ def _als_step(topo):
         return model._fns[key].lower(*args).compile()
 
 
+def _ccd_step(topo, workers: int = 1):
+    """The compiled CCD++ outer iteration at the cell ccd-k100.ml10m's full
+    shape, with the sweep kernel the dispatch picks on the chip (the
+    predicate and the sides' ``interpret`` ask ``jax`` for its backend, which
+    is the CPU here)."""
+    from harp_tpu.ops import ccd_sweep
+
+    m, n, k = ALS_SHAPE
+    sess = HarpSession(num_workers=workers, devices=topo.devices[:workers])
+    model = ccd.CCD(sess, ccd.CCDConfig(rank=k, lam=0.05, outer_iterations=1))
+    u_rpw, i_rpw = -(-m // workers), -(-n // workers)
+    u_pad, i_pad = workers * u_rpw, workers * i_rpw
+    args = (_shaped(sess, (u_pad, i_pad), jnp.bfloat16, sess.shard()),
+            _shaped(sess, (i_pad, u_pad), jnp.bfloat16, sess.shard()),
+            _shaped(sess, (u_pad, k), jnp.float32, sess.replicate()),
+            _shaped(sess, (i_pad, k), jnp.float32, sess.replicate()))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(ccd_sweep, "use_ccd_sweep_pallas",
+                      lambda rows, cols, kp: True)
+        real = ccd._geometry
+        patch.setattr(ccd, "_geometry", lambda *a: tuple(
+            dataclasses.replace(s, interpret=False) for s in real(*a)))
+        key = model._program(u_rpw, i_rpw)
+        return key, model._fns[key].lower(*args).compile()
+
+
 def _loop_lines(text: str):
     """The instruction lines of every ``while`` body of the compiled text,
     nested loops included."""
@@ -193,6 +220,14 @@ PROGRAMS = {
     "als-1-fused": (lambda t: _als_step(t).as_text(),
                     {"als.outer", "als.gram", "als.rhs", "als.solve",
                      "als.monitor"}),
+    # the CCD++ outer iteration at the cell's full shape: two sweep kernels
+    # a feature and round, on one chip and on four. There the compiler makes
+    # the allgather of a column an all-reduce of its own, with no op_name:
+    # it takes the scope of the pad that reads it, ccd.column
+    "ccd-1-fused": (lambda t: _ccd_step(t)[1].as_text(),
+                    {"ccd.sweep", "ccd.column", "ccd.monitor"}),
+    "ccd-4-fused": (lambda t: _ccd_step(t, 4)[1].as_text(),
+                    {"ccd.sweep", "ccd.column", "ccd.monitor"}),
 }
 
 
@@ -317,6 +352,41 @@ def test_the_als_iteration_fits_the_chip_at_the_cells_full_shape(
     assert not [line for line in products if "10816" in line]
 
 
+def test_the_ccd_iteration_fits_the_chip_at_the_cells_full_shape(
+        topo, no_compile_cache):
+    """The scan of 200 feature rounds lowers for a v5e at 71,567 x 10,681,
+    rank 100: one sweep kernel a side, both under ``ccd.sweep``, and no
+    float32 prediction plane anywhere in the step. The item plane is read
+    as it lies. The user plane is not: the device keeps a (71567, 10681)
+    array column-major (fewer padded cells), the kernel reads rows, and the
+    compiler copies the plane row-major once a call, outside every loop
+    (``PERF.md`` section 7); that copy is the step's scratch."""
+    from harp_tpu.ops import ccd_sweep
+
+    key, step = _ccd_step(topo)
+    sides = key[1]
+    assert [s.row_tile for s in sides] == [512, 512]
+    assert [s.col_tile for s in sides] == [10_752, 14_336]
+    m, n, _ = ALS_SHAPE
+    stats = step.memory_analysis()
+    assert stats.temp_size_in_bytes < 2 * m * n + 64 * 1024 ** 2
+    assert (stats.temp_size_in_bytes + stats.argument_size_in_bytes
+            + stats.output_size_in_bytes) < 5e9
+    text = step.as_text()
+    sweeps = [line for line in text.splitlines()
+              if "custom-call(" in line and ccd_sweep.NAME in line]
+    assert len(sweeps) == 2, sweeps                 # one a side
+    mapped = scopes.scope_map(text)
+    for line, shape in zip(sweeps, ((m, n), (n, m))):
+        name, _ = scopes._instruction(line.strip())
+        assert mapped[name] == "ccd.sweep"
+        assert _bf16_shape(shape) in line.split("custom-call(")[1], line
+    assert not _block_sized_bf16(_loop_lines(text), m * n)
+    copies = _block_sized_bf16(text.splitlines(), m * n)
+    assert [(op, dims) for _, op, dims in copies] == [("copy", f"{m},{n}")]
+    assert not re.search(r"f32\[(71567|10681),(10681|71567)\]", text)
+
+
 @pytest.mark.parametrize("rows", [17_920, 10_752])
 def test_the_solve_kernel_compiles_at_rank_100(topo, no_compile_cache, rows):
     """Mosaic accepts the batched Cholesky at k = 100 (stored 104) on a
@@ -411,6 +481,20 @@ def test_scoped_refuses_a_name_that_is_not_listed():
     with pytest.raises(ValueError, match="SCOPES"):
         scopes.scoped("kmeans.typo")
     assert len(set(scopes.SCOPES)) == len(scopes.SCOPES)
+
+
+def test_the_list_stands_below_the_wrapper_every_scoped_kernel_carries():
+    """A name added to ``SCOPES`` must move no line of ``scoped``: a Pallas
+    kernel traced under a scoped function carries the wrapper's source line,
+    and the compile cache's key with it (PERF.md, Findings, PR 31, 3)."""
+    import inspect
+
+    lines, first = inspect.getsourcelines(scopes.scoped)
+    source = inspect.getsource(scopes).splitlines()
+    listed = [i + 1 for i, line in enumerate(source)
+              if line.startswith(("SCOPES = (", "_TABLE_OPS = (",
+                                  "_LAX_OPS = ("))]
+    assert len(listed) == 3 and min(listed) > first + len(lines)
 
 
 def test_device_time_by_scope_sums_to_the_ops_self_time():
