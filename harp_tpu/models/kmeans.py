@@ -130,7 +130,7 @@ class KMeans:
             # centroids carry k_pad rows, valid_k masks the phantoms
             sums, counts, sq = distance.partial_sums_counts(
                 points, centroids, cdtype, x_sq_sum,
-                valid_k=cfg.num_centroids)
+                valid_k=cfg.num_centroids, valid_d=cfg.dim)
             with jax.named_scope("kmeans.stats"):
                 stats = jnp.concatenate([sums, counts[:, None]], axis=1)  # (K, D+1)
             return stats, sq
@@ -275,14 +275,11 @@ class KMeans:
         init = (jnp.full((points.shape[0],), jnp.inf), jnp.zeros(points.shape[0], jnp.int32))
         (best_d, best_id), my = rotation.rotate_scan(
             scoped("kmeans.scores")(body), init, my, w)
+        # the same stats product as every other variant's E-step (the
+        # cross-variant bit identity depends on it), over the points as stored
+        sums, counts = distance.onehot_stats(points, best_id, k_pad,
+                                             valid_d=cfg.dim)
         with jax.named_scope("kmeans.stats"):
-            onehot = jax.nn.one_hot(best_id, k_pad, dtype=points.dtype)
-            sums = jax.lax.dot_general(
-                onehot, points, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            # counts must accumulate in f32: a bf16 one-hot (bf16 point
-            # storage) cannot represent integer sums past 256
-            counts = jnp.sum(onehot.astype(jnp.float32), axis=0)
             stats = jnp.concatenate([sums, counts[:, None]], axis=1)
         if comm is None:
             full = table_ops.allreduce(Table.local(stats, num_workers=w))

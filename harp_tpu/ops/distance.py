@@ -10,11 +10,13 @@ as matmuls so the MXU does all the FLOPs:
 
   * ``-2 * X @ C^T`` (N×D @ D×K) dominates the distance computation;
   * partial sums = ``onehot(assign)^T @ X`` (K×N @ N×D) — the scatter-add that Harp
-    did with per-thread arrays becomes a second matmul.
+    did with per-thread arrays becomes a second matmul, which also counts the
+    points of each centroid where the stored feature axis has a lane to spare
+    (``onehot_stats``).
 
-A fused pallas kernel (ops/pallas_kernels.py) avoids materializing the N×K distance
-matrix in HBM for large N·K; this module is the XLA path and the reference
-implementation.
+Both are plain XLA: each product is one fusion that reads the points once (the
+score product with its mask, argmin and min; the stats product with the one-hot
+built in its operand), and the N×K matrices never reach HBM.
 """
 
 from __future__ import annotations
@@ -26,6 +28,7 @@ import jax.numpy as jnp
 
 from harp_tpu.ops import lane_pack
 from harp_tpu.telemetry.scopes import scoped
+from harp_tpu.utils import metrics
 
 
 def pairwise_sq_dist(x: jax.Array, c: jax.Array,
@@ -74,9 +77,56 @@ def assign_clusters(x: jax.Array, c: jax.Array) -> jax.Array:
     return jnp.argmin(pairwise_sq_dist(x, c), axis=1).astype(jnp.int32)
 
 
+# float32 holds every whole number up to 2**24: a count summed as a column of
+# the stats product is exact for a block of at most that many rows
+FOLD_MAX_ROWS = 1 << 24
+
+
+def counts_fold(rows: int, stored_d: int, valid_d: Optional[int]) -> bool:
+    """Whether the stats product can count its own rows: the stored feature
+    axis has a lane past the ``valid_d`` logical ones to carry the 1, and
+    the block's (static) row count keeps a float32 sum of ones exact."""
+    return (valid_d is not None and valid_d < stored_d
+            and rows <= FOLD_MAX_ROWS)
+
+
+@scoped("kmeans.stats")
+def onehot_stats(
+    x: jax.Array, assign: jax.Array, k: int, compute_dtype=None,
+    valid_d: Optional[int] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """Per-centroid sums (k, D) and counts (k,), both float32, of the rows of
+    x (N, D) under ``assign`` (N,): ``onehot(assign)^T @ x``, one MXU product
+    with the one-hot built in its operand.
+
+    ``valid_d``: the logical feature count where x is stored lane-padded
+    (ops/lane_pack). Where ``counts_fold`` holds, the first spare column
+    carries a 1 into the product, whose own output column is then the
+    count (float32 sums of 1.0, exact), and that column of the sums is set
+    back to exact zero. Otherwise the one-hot is reduced a second time.
+    """
+    xm = x if compute_dtype is None else x.astype(compute_dtype)
+    onehot = jax.nn.one_hot(assign, k, dtype=xm.dtype)            # (N, k)
+    fold = counts_fold(x.shape[0], x.shape[1], valid_d)
+    # runs when jax traces, only: where this program's E-step counts
+    metrics.DEFAULT.count("kmeans.stats.counts_folded" if fold
+                          else "kmeans.stats.counts_reduced")
+    if fold:
+        spare = jax.lax.broadcasted_iota(
+            jnp.int32, (1, x.shape[1]), 1) == valid_d
+        xm = jnp.where(spare, 1, xm)
+    sums = jax.lax.dot_general(                                   # (k, D)
+        onehot, xm, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if fold:
+        return jnp.where(spare, 0.0, sums), sums[:, valid_d]
+    # a bf16 one-hot cannot hold a whole-number sum past 256: reduce in f32
+    return sums, jnp.sum(onehot.astype(jnp.float32), axis=0)
+
+
 def partial_sums_counts(
     x: jax.Array, c: jax.Array, compute_dtype=None, x_sq_sum=None,
-    valid_k: Optional[int] = None,
+    valid_k: Optional[int] = None, valid_d: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One K-means E-step on this worker's block.
 
@@ -95,28 +145,22 @@ def partial_sums_counts(
     (ops/lane_pack: K padded to an MXU-lane multiple), rows >= valid_k are
     masked out of the argmin (+inf score columns) so no point can assign to
     padding; their sums/counts come out exactly zero.
+
+    ``valid_d``: the logical feature count where x is stored lane-padded; it
+    lets the stats product count (``onehot_stats``).
     """
     # argmin over ‖x−c‖² == argmin over (‖c‖² − 2x·c): the per-row ‖x‖² term is
-    # constant and never needs materializing — the E-step reads x exactly
-    # twice (two MXU matmuls) and touches no (N, D)-sized temporaries.
+    # constant and never needs materializing. Two products, each one read of
+    # x: the score GEMM with its mask, argmin and min, and the one-hot stats
+    # product, which also counts where x has a spare lane (else the one-hot
+    # is reduced once more); no (N, D)-sized temporary
     scores = pairwise_scores(x, c, compute_dtype)         # (N, K)
-    # two kernels, interleaved as the equations always were: the score GEMM
-    # with its mask, argmin and min, and the one-hot stats product
-    if valid_k is not None:
-        with jax.named_scope("kmeans.scores"):
-            scores = lane_pack.mask_phantom_cols(scores, valid_k)
-    with jax.named_scope("kmeans.stats"):
-        xm = x if compute_dtype is None else x.astype(compute_dtype)
     with jax.named_scope("kmeans.scores"):
+        if valid_k is not None:
+            scores = lane_pack.mask_phantom_cols(scores, valid_k)
         assign = jnp.argmin(scores, axis=1)
         min_s = jnp.min(scores, axis=1)
-    with jax.named_scope("kmeans.stats"):
-        oh_dtype = x.dtype if compute_dtype is None else compute_dtype
-        onehot = jax.nn.one_hot(assign, c.shape[0], dtype=oh_dtype)  # (N, K)
-        sums = jax.lax.dot_general(                              # (K, D) on MXU
-            onehot, xm, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        counts = jnp.sum(onehot.astype(jnp.float32), axis=0)
+    sums, counts = onehot_stats(x, assign, c.shape[0], compute_dtype, valid_d)
     if x_sq_sum is None:
         with jax.named_scope("kmeans.norms"):
             xf = x.astype(jnp.float32)

@@ -60,15 +60,19 @@ def _shaped(sess, shape, dtype, spec):
                                 sharding=NamedSharding(sess.mesh, spec))
 
 
-def _kmeans_text(topo, workers: int) -> str:
+KMEANS_ROWS = 65536     # a worker's points in the K-means step compiled here
+
+
+def _kmeans_text(topo, workers: int, ambient="highest") -> str:
     """The K-means step at the cell's widths (k 100, d 100 lane-padded to
-    128, ``highest`` products), the point count cut."""
+    128, ``highest`` products as the cell sets them), the point count cut."""
     sess = HarpSession(num_workers=workers, devices=topo.devices[:workers])
     model = kmeans.KMeans(sess, kmeans.KMeansConfig(
         num_centroids=100, dim=100, iterations=5))
-    points = _shaped(sess, (65536 * workers, 128), jnp.float32, sess.shard())
+    points = _shaped(sess, (KMEANS_ROWS * workers, 128), jnp.float32,
+                     sess.shard())
     centroids = _shaped(sess, (100, 100), jnp.float32, sess.replicate())
-    with jax.default_matmul_precision("highest"):
+    with jax.default_matmul_precision(ambient):
         return model._fit.lower(points, centroids).compile().as_text()
 
 
@@ -276,6 +280,44 @@ def test_each_scope_the_program_reaches_is_present(compiled, program):
     # a ring of one picks its single block by a static index: no select
     if program.startswith("sgdmf-1"):
         assert "sgdmf.select" not in loops
+
+
+def test_the_kmeans_stats_are_one_product_that_counts(compiled):
+    """The stats product counts through the spare lane, so nothing else under
+    ``kmeans.stats`` reads a row-sized operand and no second reduction of
+    the one-hot is left. Both products run at the cell's ambient ``highest``:
+    the program pins no precision (the TPU backend runs a
+    ``{default,highest}`` pair no faster: PERF.md, Findings, PR 32)."""
+    text = compiled("kmeans-1")
+    mapped = scopes.scope_map(text)
+    products = {re.search(r'op_name="[^"]*?(kmeans\.\w+)/dot_general"',
+                          line).group(1):
+                re.search(r"operand_precision=\{(\w+),(\w+)\}", line).groups()
+                for line in text.splitlines() if " convolution(" in line}
+    assert products == {"kmeans.scores": ("highest", "highest"),
+                        "kmeans.stats": ("highest", "highest")}
+    assert "kmeans.stats/reduce_sum" not in text
+    # operands are names in the compiled text; what a name yields stands on
+    # its own line, before its opcode
+    rows = re.compile(r"\[%d[,\]]" % KMEANS_ROWS)
+    row_sized = {scopes._instruction(line.strip())[0]
+                 for line in text.splitlines()
+                 if scopes._INSTRUCTION.match(line)
+                 and rows.search(line.split(" = ", 1)[1].split("(", 1)[0])}
+    reads_rows = [name for line in _loop_lines(text)
+                  for name, opcode in [scopes._instruction(line.strip())]
+                  if opcode in KERNELS and mapped[name] == "kmeans.stats"
+                  and row_sized & set(re.findall(
+                      r"%([\w.\-]+)", line.split(opcode + "(", 1)[1]))]
+    assert len(reads_rows) == 1, reads_rows
+
+
+def test_at_the_ambient_default_the_kmeans_step_asks_for_no_precision(
+        topo, no_compile_cache):
+    """A user who sets nothing gets the products they got: one term each."""
+    text = _kmeans_text(topo, 1, ambient=None)
+    assert " convolution(" in text
+    assert "highest" not in text and "operand_precision" not in text
 
 
 @pytest.mark.parametrize("workers, num_rows, num_cols", [
