@@ -754,7 +754,16 @@ def run_ccd(argv) -> int:
 def run_mds(argv) -> int:
     from harp_tpu.models.mds import MDSConfig
 
-    p = argparse.ArgumentParser(prog="harp_tpu.run mds")
+    p = argparse.ArgumentParser(
+        prog="harp_tpu.run mds",
+        description="WDA-SMACOF: weighted MDS by SMACOF majorization under "
+        "deterministic annealing. The temperature starts at alpha x the "
+        "largest weighted distance and cools by alpha every "
+        "--level-iterations iterations until it falls under --t-floor of "
+        "that distance, then runs at 0; a job is that whole schedule, in "
+        "calls of --iterations iterations, each solved by --cg-iters steps "
+        "of CG. One line a call: the iteration reached, its temperature and "
+        "the normalised stress sum w (delta - d)^2 / sum w delta^2.")
     _common_flags(p)
     p.add_argument("--num-points", type=int, default=256)
     p.add_argument("--source-dim", type=int, default=8,
@@ -762,21 +771,23 @@ def run_mds(argv) -> int:
     _add_config_flags(p, MDSConfig)
     args = p.parse_args(argv)
     sess = _session(args)
-    import numpy as np
-
     from harp_tpu.io import datagen
     from harp_tpu.models import mds
 
     cfg = _config_from_args(mds.MDSConfig, args)
     n = args.num_points - args.num_points % sess.num_workers
     pts = datagen.dense_points(n, args.source_dim, seed=args.seed)
-    d = np.sqrt(((pts[:, None] - pts[None]) ** 2).sum(-1)).astype(np.float32)
+    model = mds.WDAMDS(sess, cfg)
     t0 = time.perf_counter()
-    x, stress = mds.WDAMDS(sess, cfg).fit(d, seed=args.seed)
+    state = model.prepare(mds.distance_matrix(pts), seed=args.seed)
+    _, stress = model.fit_prepared(state, on_call=lambda done, sigma: print(
+        f"mds iteration {done} T {model.temperature(done - 1):.4f} "
+        f"stress {sigma[-1]:.6f}"))
     dt = time.perf_counter() - t0
     print(f"mds workers={sess.num_workers} n={n} dim={cfg.dim}: "
-          f"{cfg.iterations / dt:.2f} iters/s (incl compile), "
-          f"stress {stress[0]:.4f} -> {stress[-1]:.4f}")
+          f"{len(stress) / dt:.2f} iters/s (incl compile), "
+          f"stress {stress[0]:.4f} -> {stress[-1]:.6f}, "
+          f"layout {model.last_layout_stats}")
     return 0
 
 

@@ -89,6 +89,9 @@ SCOPES = (
     "ccd.sweep",        # one fused pass over a side's plane: both row sums
     "ccd.column",       # column t picked, the closed form, row t written back
     "ccd.monitor",      # per-epoch RMSE over the observed cells
+    "mds.anneal",       # the schedule's loop: the temperature, the carry, the curve
+    "mds.bc",           # one fused pass over delta and w: B(X)X and the stress
+    "mds.cg",           # the Guttman solve: w's matvecs and the CG arithmetic
 )
 _LISTED = frozenset(SCOPES)
 

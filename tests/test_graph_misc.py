@@ -50,6 +50,8 @@ def test_mds_recovers_geometry(session):
     d = np.sqrt(((pts[:, None] - pts[None]) ** 2).sum(-1)).astype(np.float32)
     model = mds.WDAMDS(session, mds.MDSConfig(dim=2, iterations=80))
     x, stress = model.fit(d, seed=1)
+    # a job is the whole annealing schedule, in calls of 80 iterations
+    assert len(stress) == 320 >= mds.schedule_iterations(model.config)
     assert stress[-1] < 0.05 * stress[0]
     # embedded distances match target distances (up to rigid motion)
     d_emb = np.sqrt(((x[:, None] - x[None]) ** 2).sum(-1))
@@ -60,7 +62,7 @@ def test_wda_mds_weighted_cg_matches_numpy_oracle(session):
     """The distributed weighted V CG solve (WDAMDSMapper.java:585 parity)
     matches a single-host SMACOF-with-CG oracle on NON-uniform weights —
     the case where the old uniform V+=I/n simplification was a genuinely
-    different algorithm."""
+    different algorithm — over the whole annealing schedule."""
     rng = np.random.default_rng(11)
     n = 48
     pts = rng.standard_normal((n, 2)).astype(np.float32)
@@ -69,40 +71,53 @@ def test_wda_mds_weighted_cg_matches_numpy_oracle(session):
     w = (w + w.T) / 2.0                     # symmetric, strongly non-uniform
     cfg = mds.MDSConfig(dim=2, iterations=25, cg_iters=20)
     x, stress = mds.WDAMDS(session, cfg).fit(d, weights=w, seed=1)
-    # oracle with the identical init and the identical truncated CG
+    # oracle with the identical init, schedule and truncated CG
     x0 = np.random.default_rng(1).standard_normal((n, 2)).astype(np.float32)
     x0 -= x0.mean(axis=0)
-    x_ref, s_ref = mds.numpy_wda_smacof(d, w, x0, cfg.iterations,
-                                        cfg.cg_iters)
-    np.testing.assert_allclose(stress, s_ref, rtol=1e-3)
-    np.testing.assert_allclose(x, x_ref, rtol=1e-2, atol=1e-2)
+    x_ref, s_ref = mds.numpy_wda_smacof(d, w, x0, cfg, len(stress))
+    np.testing.assert_allclose(stress, s_ref, rtol=1e-3, atol=1e-6)
+    np.testing.assert_allclose(x, x_ref - x_ref.mean(axis=0),
+                               rtol=1e-2, atol=1e-2)
+    # the curve first rises (the hottest targets are nearly all 0)
+    assert stress[1] > stress[0] and stress[-1] < 1e-3
     # and the weighted fit still embeds the geometry
     d_emb = np.sqrt(((x[:, None] - x[None]) ** 2).sum(-1))
     assert np.abs(d_emb - d).mean() < 0.15 * d.mean()
 
 
-def test_mds_matmuls_request_highest_precision(session):
+@pytest.mark.parametrize("weights_dtype", ["float32", "bfloat16"])
+def test_mds_matmuls_request_highest_precision(session, weights_dtype):
     """Regression guard for a REAL-CHIP-only failure the CPU suite cannot
     reproduce: TPU's default f32 matmul truncates operands to bf16, which
     sign-flips the CG's pᵀVp at convergence scale and sent the embedding to
-    overflow (stress NaN at iteration 1 on hardware, round 5). The three
-    SMACOF matmuls (V matvec, B(X)·X, pairwise distances) must pin
-    Precision.HIGHEST — assert it survives in the traced jaxpr."""
-    from harp_tpu.models.mds import MDSConfig, _smacof
+    overflow (stress NaN at iteration 1 on hardware, round 5). A SMACOF
+    product of float32 operands must pin Precision.HIGHEST; the one with
+    bfloat16 weights states its precision by type (three exact bfloat16
+    terms of the direction, ops/mds_kernels.py). B(X)X and the distances
+    are elementwise float32: no product at all. Assert it in the lowered
+    program."""
+    from harp_tpu.models.mds import MDSConfig, _geometry, _train
 
     n = 16
     cfg = MDSConfig(dim=2, iterations=1)
+    geom = _geometry(n // session.num_workers, n, cfg.dim, weights_dtype)
     prog = session.spmd(
-        lambda d, wt, x0: _smacof(d, wt, x0, n, cfg),
-        in_specs=(session.shard(), session.shard(), session.replicate()),
-        out_specs=(session.replicate(), session.replicate()))
+        lambda d, w, v, s, xt, c: _train(d, w, v, s, xt, c, geom, cfg),
+        in_specs=(session.shard(),) * 3 + (session.replicate(),) * 3,
+        out_specs=(session.replicate(),) * 3)
     text = prog.lower(np.zeros((n, n), np.float32),
-                      np.zeros((n, n), np.float32),
-                      np.zeros((n, 2), np.float32)).as_text()
+                      np.zeros((n, n), weights_dtype),
+                      np.zeros((n,), np.float32), np.ones((2,), np.float32),
+                      np.zeros((8, n), np.float32), np.int32(0)).as_text()
     dots = [ln for ln in text.splitlines() if "dot_general" in ln]
     assert dots, "no dot_general in the SMACOF program?"
-    low = [ln for ln in dots if "HIGHEST" not in ln]
-    assert not low, f"SMACOF matmuls without HIGHEST precision: {low}"
+    if weights_dtype == "float32":
+        low = [ln for ln in dots if "HIGHEST" not in ln]
+        assert not low, f"SMACOF matmuls without HIGHEST precision: {low}"
+    else:
+        wide = [ln for ln in dots if "xbf16>, tensor<" not in ln
+                or "xbf16>) ->" not in ln]
+        assert not wide, f"a product with an operand wider than bf16: {wide}"
 
 
 def test_em_gmm_recovers_components(session):

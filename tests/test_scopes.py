@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding
 
-from harp_tpu.models import als, ccd, kmeans, sgd_mf
+from harp_tpu.models import als, ccd, kmeans, mds, sgd_mf
 from harp_tpu.session import HarpSession
 from harp_tpu.telemetry import scopes
 
@@ -153,6 +153,36 @@ def _ccd_step(topo, workers: int = 1):
         return key, model._fns[key].lower(*args).compile()
 
 
+MDS_POINTS = 32_768     # the cell wdamds-d3.clusters-32k
+
+
+def _mds_step(topo, workers: int = 1):
+    """The compiled call of 10 WDA-SMACOF iterations at the cell
+    wdamds-d3.clusters-32k's full shape (float32 distances, bfloat16
+    weights, target dimension 3), with the two kernels the dispatch picks on
+    the chip (the predicate and the geometry's ``interpret`` ask ``jax`` for
+    its backend, which is the CPU here)."""
+    from harp_tpu.ops import mds_kernels
+
+    n = MDS_POINTS
+    sess = HarpSession(num_workers=workers, devices=topo.devices[:workers])
+    model = mds.WDAMDS(sess, mds.MDSConfig(dim=3, iterations=10))
+    args = (_shaped(sess, (n, n), jnp.float32, sess.shard()),
+            _shaped(sess, (n, n), jnp.bfloat16, sess.shard()),
+            _shaped(sess, (n,), jnp.float32, sess.shard()),
+            _shaped(sess, (2,), jnp.float32, sess.replicate()),
+            _shaped(sess, (mds_kernels.DIM_PAD, n), jnp.float32,
+                    sess.replicate()),
+            _shaped(sess, (), jnp.int32, sess.replicate()))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(mds_kernels, "use_mds_pallas", lambda *a: True)
+        real = mds._geometry
+        patch.setattr(mds, "_geometry", lambda *a: dataclasses.replace(
+            real(*a), interpret=False))
+        key = model._program(n, jnp.bfloat16)
+        return key, model._fns[key].lower(*args).compile()
+
+
 def _loop_lines(text: str):
     """The instruction lines of every ``while`` body of the compiled text,
     nested loops included."""
@@ -232,6 +262,13 @@ PROGRAMS = {
                     {"ccd.sweep", "ccd.column", "ccd.monitor"}),
     "ccd-4-fused": (lambda t: _ccd_step(t, 4)[1].as_text(),
                     {"ccd.sweep", "ccd.column", "ccd.monitor"}),
+    # the WDA-SMACOF call at the cell's full shape: the B(X)X kernel once an
+    # iteration, the matvec kernel for the warm start's residual and in the
+    # CG's loop, on one chip and on four
+    "mds-1-fused": (lambda t: _mds_step(t)[1].as_text(),
+                    {"mds.anneal", "mds.bc", "mds.cg"}),
+    "mds-4-fused": (lambda t: _mds_step(t, 4)[1].as_text(),
+                    {"mds.anneal", "mds.bc", "mds.cg", "lax.allgather"}),
 }
 
 
@@ -427,6 +464,50 @@ def test_the_ccd_iteration_fits_the_chip_at_the_cells_full_shape(
     copies = _block_sized_bf16(text.splitlines(), m * n)
     assert [(op, dims) for _, op, dims in copies] == [("copy", f"{m},{n}")]
     assert not re.search(r"f32\[(71567|10681),(10681|71567)\]", text)
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+def test_the_mds_call_fits_the_chip_at_the_cells_full_shape(
+        compiled, topo, no_compile_cache, workers):
+    """Ten iterations, each with its 10-step CG, lower for a v5e at 32,768
+    points: ``mds_bc_stress`` under ``mds.bc``, ``mds_laplacian_matvec``
+    (the warm start's and the CG loop's) under ``mds.cg``, both reading the
+    matrices as they lie; the step's scratch is bounded by the tiles and
+    nothing N x N is made, in any type."""
+    from harp_tpu.ops import mds_kernels
+
+    key, step = _mds_step(topo, workers)
+    geom = key[1]
+    n, rows = MDS_POINTS, MDS_POINTS // workers
+    assert (geom.row_tile, geom.bc_col_tile, geom.mv_col_tile) == (
+        512, 8192, 16384)
+    stats = step.memory_analysis()
+    assert stats.temp_size_in_bytes < 64 * 1024 ** 2
+    assert stats.argument_size_in_bytes < 6 * rows * n + 4 * 1024 ** 2
+    text = compiled(f"mds-{workers}-fused")
+    mapped = scopes.scope_map(text)
+    calls = {name: [line for line in text.splitlines()
+                    if "custom-call(" in line and name in line]
+             for name in (mds_kernels.BC_NAME, mds_kernels.MATVEC_NAME)}
+    assert len(calls[mds_kernels.BC_NAME]) == 1
+    assert len(calls[mds_kernels.MATVEC_NAME]) == 2
+    for name, scope, operands in (
+            (mds_kernels.BC_NAME, "mds.bc",
+             (f"f32[{rows},{n}]", f"bf16[{rows},{n}]")),
+            (mds_kernels.MATVEC_NAME, "mds.cg", (f"bf16[{rows},{n}]",))):
+        for line in calls[name]:
+            assert mapped[scopes._instruction(line.strip())[0]] == scope
+            for operand in operands:
+                assert operand in line.split("custom-call(")[1], line
+    made = [line for line in text.splitlines()
+            if re.search(r"= \w+\[%d,%d\]" % (rows, n), line)
+            and scopes._instruction(line.strip())[1] not in (
+                "parameter", "get-tuple-element", "bitcast")]
+    assert not made, made
+    collectives = {op for _, op in _loop_kernels(text)} & {
+        "all-reduce", "all-gather"}
+    assert collectives == (set() if workers == 1 else {"all-reduce",
+                                                       "all-gather"})
 
 
 @pytest.mark.parametrize("rows", [17_920, 10_752])

@@ -141,6 +141,20 @@ def _als():
     return model._fns[key], placed
 
 
+def _wdamds():
+    from harp_tpu.models import mds
+
+    sess = _session()
+    # two iterations of a 3-step CG: the counts per CG step and per
+    # iteration stand apart in the row
+    model = mds.WDAMDS(sess, mds.MDSConfig(dim=3, iterations=2, cg_iters=3))
+    rng = _rng()
+    pts = rng.normal(size=(64, 5)).astype("float32")
+    dist = mds.distance_matrix(pts)
+    key, placed = model.prepare(dist, (dist < 3.0).astype("float32"))
+    return model._fns[key], placed
+
+
 def _pagerank():
     from harp_tpu.models import pagerank as pr
 
@@ -464,6 +478,12 @@ TARGETS: Dict[str, Callable[[], Tuple[Callable, tuple]]] = {
     # regroup silently reverting to a whole-table host/device gather
     # changes kinds or grows bytes and fails JL201/JL203.
     "ingest_coo_regroup": _ingest_coo_regroup,
+    # ISSUE 34: WDA-SMACOF. A CG step is one all_gather of the direction
+    # and two psums (p'Vp; the residual's squared norm and its sum, folded);
+    # an iteration adds the stress psum, the warm start's two psums and the
+    # all_gather of the new embedding. A third psum in the step, or the
+    # matrices' rows gathered, changes kinds or bytes and fails JL201/JL203.
+    "wdamds": _wdamds,
 }
 
 
