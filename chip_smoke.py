@@ -422,6 +422,36 @@ def leg_kernels() -> dict:
         out[f"spd_solve_rank{rank}"] = {
             "max_abs_err": float(np.max(np.abs(got - want))), "tol": tol,
             "mosaic": True}
+    # the fused K-means E-step against its XLA twin at the benchmark cell's
+    # widths and precision (k 100 of 128, d 100 of 128, `highest`): a block
+    # its tiles divide and one whose last tile is masked. Two float32
+    # implementations may part on a near tie: a few points of 10^5 may sit
+    # with another centroid; the cost may not move.
+    from harp_tpu.ops import distance, kmeans_kernels, lane_pack
+
+    for n in (128_000, 100_003):
+        rng = np.random.default_rng(n)
+        cen = 0.2 * rng.standard_normal((100, 100))
+        pts = cen[rng.integers(0, 100, n)] + rng.standard_normal((n, 100))
+        x = lane_pack.pad_cols(jnp.asarray(pts, jnp.float32), 128)
+        c = lane_pack.pad_rows(lane_pack.pad_cols(jnp.asarray(
+            cen + 0.5 * rng.standard_normal((100, 100)), jnp.float32),
+            128), 128)
+        with jax.default_matmul_precision("highest"):
+            fused = jax.jit(lambda x_, c_: kmeans_kernels.estep_pallas(
+                x_, c_, valid_k=100, valid_d=100)).lower(x, c).compile()
+            _assert_mosaic(fused.as_text(), f"kmeans estep n={n}")
+            got = fused(x, c)
+            want = jax.jit(lambda x_, c_: distance.partial_sums_counts(
+                x_, c_, valid_k=100, valid_d=100))(x, c)
+        moved = float(jnp.sum(jnp.abs(got[1] - want[1]))) / 2
+        cost = abs(float(got[2]) / float(want[2]) - 1.0)
+        _finite(moved, cost)
+        if float(jnp.sum(got[1])) != n or moved > 1e-4 * n or cost > 1e-6:
+            raise SystemExit(f"kmeans estep n={n}: {moved} points moved, "
+                             f"cost off by {cost:.3e}")
+        out[f"kmeans_estep_n{n}"] = {"points_moved": moved,
+                                     "cost_rel_err": cost, "mosaic": True}
     out["cache"] = stats.row()
     return out
 

@@ -9,7 +9,9 @@ hot loop :147-197: CenCalcTask distances → regroup → local average → allga
 TPU-native: the entire iteration loop is ONE compiled XLA program — a ``lax.scan``
 over iterations inside ``shard_map`` — rather than one JVM network op per phase.
 Per iteration each worker computes partial sums/counts for its point block (two
-MXU matmuls, ops/distance.py), then the chosen collective combines them:
+MXU matmuls: one fused Pallas pass over the points on TPU at lane-padded
+shapes, ops/kmeans_kernels.py; XLA's two products elsewhere, ops/distance.py),
+then the chosen collective combines them:
 
   * ``regroupallgather`` — reduce_scatter the (K, D+1) stat table, each worker
     averages its centroid block, all_gather the new centroids. Bandwidth-optimal;
@@ -25,7 +27,11 @@ All variants produce bit-identical centroid trajectories (they compute the same
 sums in the same tree order per partition), which the tests assert — the reference
 could only claim statistical equivalence across its variants. The bit-identity
 guarantee holds for the default f32 path; ``compute_dtype="bfloat16"`` keeps all
-accumulations f32 but near-tie assignments may differ across variants.
+accumulations f32 but near-tie assignments may differ across variants. Where
+the fused E-step kernel runs (TPU), ``rotation`` keeps XLA's products (its
+work is block-local over circulating centroids): it then agrees with the other
+four to rounding, with the same near-tie caveat, and they with each other
+bit for bit.
 """
 
 from __future__ import annotations
@@ -42,10 +48,11 @@ import numpy as np
 from harp_tpu import combiner as cb
 from harp_tpu import telemetry
 from harp_tpu.collectives import lax_ops, quantize, rotation, table_ops
-from harp_tpu.ops import distance, lane_pack
+from harp_tpu.ops import distance, kmeans_kernels, lane_pack
 from harp_tpu.session import HarpSession
 from harp_tpu.table import Table
 from harp_tpu.telemetry.scopes import scoped
+from harp_tpu.utils import metrics
 
 COMM_VARIANTS = ("regroupallgather", "allreduce", "pushpull", "bcastreduce",
                  "rotation")
@@ -77,7 +84,10 @@ class KMeansConfig:
     #   one flip; converged cost equal to 7 digits). Same epsilon class as
     #   compute_dtype="bfloat16"'s documented flips. Cross-VARIANT bit
     #   identity is unaffected (every variant shares the padded formulation).
-    #   Off: the pre-r6 worker-multiple-only padding.
+    #   Off: the pre-r6 worker-multiple-only padding, and always the XLA
+    #   E-step (ops/distance.py): the fused kernel (ops/kmeans_kernels.py,
+    #   chosen by use_kmeans_estep_pallas on TPU) needs the 128-lane store,
+    #   whose spare lane also carries its counts.
     quant: Optional[str] = None   # None | "int8" | "bf16": quantize the
     #   stats-table collectives' WIRE format (collectives/quantize.py) with
     #   error-feedback residual carried in the fit scan. The math stays f32
@@ -87,6 +97,16 @@ class KMeansConfig:
     #   tests pin a per-codec tolerance vs the f32 run instead. Unsupported
     #   for bcastreduce (rooted reduce/broadcast are masked psums whose
     #   mask trick defeats per-block scales).
+
+
+def _fused_estep(points, k_pad: int, cdtype) -> Tuple[bool, bool]:
+    """``(whether the E-step over this worker's stored block runs the fused
+    kernel, whether interpreted)``: one predicate beside the kernel decides,
+    by backend, stored shape and dtype; elsewhere the XLA twin."""
+    fused = (cdtype in (None, jnp.bfloat16)
+             and kmeans_kernels.use_kmeans_estep_pallas(
+                 points.shape[0], points.shape[1], k_pad, points.dtype))
+    return fused, fused and jax.default_backend() != "tpu"
 
 
 class KMeans:
@@ -128,9 +148,20 @@ class KMeans:
 
         def estep(points, centroids, x_sq_sum=None):
             # centroids carry k_pad rows, valid_k masks the phantoms
-            sums, counts, sq = distance.partial_sums_counts(
-                points, centroids, cdtype, x_sq_sum,
-                valid_k=cfg.num_centroids, valid_d=cfg.dim)
+            fused, interpret = _fused_estep(points, k_pad, cdtype)
+            # runs when jax traces, only: which E-step this program's body runs
+            if fused:
+                metrics.DEFAULT.count("kmeans.estep.pallas")
+                with jax.named_scope("kmeans.estep"):
+                    # one pass over the points: Σ‖x‖² comes with it
+                    sums, counts, sq = kmeans_kernels.estep_pallas(
+                        points, centroids, cdtype, valid_k=cfg.num_centroids,
+                        valid_d=cfg.dim, interpret=interpret)
+            else:
+                metrics.DEFAULT.count("kmeans.estep.xla")
+                sums, counts, sq = distance.partial_sums_counts(
+                    points, centroids, cdtype, x_sq_sum,
+                    valid_k=cfg.num_centroids, valid_d=cfg.dim)
             with jax.named_scope("kmeans.stats"):
                 stats = jnp.concatenate([sums, counts[:, None]], axis=1)  # (K, D+1)
             return stats, sq
@@ -203,11 +234,16 @@ class KMeans:
             points = lane_pack.pad_cols(points, d_pad)
             cen = lane_pack.pad_rows(
                 lane_pack.pad_cols(centroids0, d_pad), k_pad)
-            # Σ‖x‖² is iteration-invariant: hoist it so the hot loop reads the
-            # point block exactly twice per iteration (the two MXU matmuls)
-            with jax.named_scope("kmeans.norms"):
-                pf = points.astype(jnp.float32)
-                x_sq_sum = jnp.sum(pf * pf)
+            # Σ‖x‖² is iteration-invariant: where the E-step is the XLA twin
+            # (and in the rotation variant) hoist it, so the hot loop reads
+            # the point block exactly twice per iteration (the two MXU
+            # matmuls); the fused kernel reads a tile once for everything
+            x_sq_sum = None
+            if cfg.comm == "rotation" or not _fused_estep(
+                    points, k_pad, cdtype)[0]:
+                with jax.named_scope("kmeans.norms"):
+                    pf = points.astype(jnp.float32)
+                    x_sq_sum = jnp.sum(pf * pf)
 
             # the loop's own plumbing (the counter, the stacking of the
             # per-iteration cost) reads under the M-step's name; what the

@@ -14,9 +14,16 @@ as matmuls so the MXU does all the FLOPs:
     points of each centroid where the stored feature axis has a lane to spare
     (``onehot_stats``).
 
-Both are plain XLA: each product is one fusion that reads the points once (the
-score product with its mask, argmin and min; the stats product with the one-hot
-built in its operand), and the N×K matrices never reach HBM.
+Here both are plain XLA: each product is one fusion that reads the points once
+(the score product with its mask, argmin and min; the stats product with the
+one-hot built in its operand), and the N×K matrices never reach HBM. This is
+the TWIN: on TPU, where the stored shapes are whole 128-lane tiles, the E-step
+of ``models/kmeans.py`` is one Pallas kernel that reads each tile of the
+points once for both products (``ops/kmeans_kernels.py`` ``kmeans_estep``,
+chosen by ``use_kmeans_estep_pallas`` by backend, stored shape and dtype);
+``partial_sums_counts`` runs everywhere else (the CPU, ``lane_pad=False``,
+odd shapes), in the rotation variant and the minibatch step, and is what the
+kernel's tests compare against.
 """
 
 from __future__ import annotations

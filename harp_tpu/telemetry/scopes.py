@@ -92,6 +92,7 @@ SCOPES = (
     "mds.anneal",       # the schedule's loop: the temperature, the carry, the curve
     "mds.bc",           # one fused pass over delta and w: B(X)X and the stress
     "mds.cg",           # the Guttman solve: w's matvecs and the CG arithmetic
+    "kmeans.estep",     # the fused E-step: scores, argmin, one-hot, stats, norms
 )
 _LISTED = frozenset(SCOPES)
 

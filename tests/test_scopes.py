@@ -63,17 +63,22 @@ def _shaped(sess, shape, dtype, spec):
 KMEANS_ROWS = 65536     # a worker's points in the K-means step compiled here
 
 
-def _kmeans_text(topo, workers: int, ambient="highest") -> str:
+def _kmeans_text(topo, workers: int, ambient="highest", fused: bool = False,
+                 rows: int = KMEANS_ROWS) -> str:
     """The K-means step at the cell's widths (k 100, d 100 lane-padded to
-    128, ``highest`` products as the cell sets them), the point count cut."""
+    128, ``highest`` products as the cell sets them), the point count cut.
+    ``fused``: with the E-step kernel the dispatch picks on the chip (the
+    predicate asks ``jax`` for its backend, which is the CPU here)."""
     sess = HarpSession(num_workers=workers, devices=topo.devices[:workers])
     model = kmeans.KMeans(sess, kmeans.KMeansConfig(
         num_centroids=100, dim=100, iterations=5))
-    points = _shaped(sess, (KMEANS_ROWS * workers, 128), jnp.float32,
-                     sess.shard())
+    points = _shaped(sess, (rows * workers, 128), jnp.float32, sess.shard())
     centroids = _shaped(sess, (100, 100), jnp.float32, sess.replicate())
-    with jax.default_matmul_precision(ambient):
-        return model._fit.lower(points, centroids).compile().as_text()
+    with pytest.MonkeyPatch.context() as patch:
+        if fused:
+            patch.setattr(kmeans, "_fused_estep", lambda *a: (True, False))
+        with jax.default_matmul_precision(ambient):
+            return model._fit.lower(points, centroids).compile().as_text()
 
 
 def _sgdmf_text(topo, workers: int, fused: bool = False) -> str:
@@ -347,6 +352,38 @@ def test_the_kmeans_stats_are_one_product_that_counts(compiled):
                   and row_sized & set(re.findall(
                       r"%([\w.\-]+)", line.split(opcode + "(", 1)[1]))]
     assert len(reads_rows) == 1, reads_rows
+
+
+@pytest.mark.parametrize("workers, rows, ambient, passes", [
+    (1, 8_000_000, "highest", 6),           # the cell kmeans-d100.overlap-8m
+    (4, KMEANS_ROWS, "highest", 6),
+    (1, KMEANS_ROWS, None, 1)])
+def test_the_fused_estep_is_the_loops_one_pass_over_the_points(
+        topo, no_compile_cache, workers, rows, ambient, passes):
+    """With the predicate on, an iteration reads the points in ONE kernel,
+    ``kmeans_estep`` under ``kmeans.estep``: no score or stats convolution
+    and no hoisted norms are left, and the centroids reach the kernel as the
+    bfloat16 passes the ambient precision states, side by side (six at
+    ``highest``, bf16_6x's: the passes are the kernel's own)."""
+    text = _kmeans_text(topo, workers, ambient, fused=True, rows=rows)
+    mapped = scopes.scope_map(text)
+    kernels = _loop_kernels(text)
+    calls = [name for name, opcode in kernels if opcode == "custom-call"]
+    assert len(calls) == 1 and calls[0].startswith("kmeans_estep"), calls
+    assert mapped[calls[0]] == "kmeans.estep"
+    call = next(line for line in _loop_lines(text)
+                if scopes._instruction(line.strip())[0] == calls[0])
+    assert f"f32[{rows},128]" in call and f"bf16[128,{128 * passes}]" in call
+    assert " convolution(" not in text
+    everywhere = set(mapped.values())
+    assert not {"kmeans.norms", "kmeans.scores"} & everywhere, everywhere
+    bare = [(name, op) for name, op in kernels
+            if mapped[name] not in scopes.SCOPES]
+    assert not bare, bare
+    # nothing row-sized but the points themselves: no N-sized temporary
+    assert not re.search(r"= \w+\[%d[,\]]" % rows, "\n".join(
+        line for line in text.splitlines() if " parameter(" not in line
+        and "get-tuple-element(" not in line)), "an N-sized value is made"
 
 
 def test_at_the_ambient_default_the_kmeans_step_asks_for_no_precision(
