@@ -189,32 +189,37 @@ def _device(leg: str) -> dict:
 
 class _CompileStats:
     """Persistent-cache hits/misses and backend compile seconds of this
-    process, read from jax's own monitoring events."""
+    process since this object was made, from the program's own records of
+    the compile path (``harp_tpu.telemetry.host_spans``: the
+    ``program.compile`` records of its ring, the ``program.cache.*``
+    counters), which it takes from jax's monitoring events."""
 
     def __init__(self):
-        import jax.monitoring as mon
+        from harp_tpu import telemetry  # noqa: F401  (registers the listeners)
 
-        self.hits = self.misses = 0
-        self.compile_s = 0.0
-        mon.register_event_listener(self._event)
-        mon.register_event_duration_secs_listener(self._duration)
+        self._since = time.perf_counter()
+        self._before = self._counts()
 
-    def _event(self, name, **_kw):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif name == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
+    @staticmethod
+    def _counts() -> tuple:
+        from harp_tpu.utils.metrics import DEFAULT
 
-    def _duration(self, name, secs, **_kw):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
+        counters = DEFAULT.snapshot()["counters"]
+        return (int(counters.get("program.cache.hits", 0)),
+                int(counters.get("program.cache.misses", 0)))
 
     def row(self) -> dict:
         import jax
 
+        from harp_tpu import telemetry
+
+        hits, misses = (now - before for now, before
+                        in zip(self._counts(), self._before))
+        compile_s = telemetry.union_seconds(
+            telemetry.phases(self._since), "program.compile")
         return {"dir": jax.config.jax_compilation_cache_dir,
-                "hits": self.hits, "misses": self.misses,
-                "compile_s": round(self.compile_s, 2)}
+                "hits": hits, "misses": misses,
+                "compile_s": round(compile_s, 2)}
 
 
 def _cli(argv) -> str:

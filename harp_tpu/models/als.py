@@ -655,12 +655,12 @@ class ALS:
         The last two entries of ``state[1]`` are the factors the call starts
         from: a caller that trains in several calls hands back what the call
         before returned."""
-        import time as _time
-
         key, placed, _, _ = state
-        with telemetry.phase("als.call"):
+        # the lines from here to the dispatch keep the numbers they had: a
+        # kernel's payload, and with it the compile cache's key of the step,
+        # carries the line of every frame above it (PERF.md section 7, row 11)
+        with telemetry.phase("als.call") as call:
             step = self._fns[key]
-            t0 = _time.perf_counter()
             with telemetry.phase("step.dispatch"):
                 u, v, rmse = step(*placed)
             telemetry.record_program("als.fit", step, placed)
@@ -672,7 +672,7 @@ class ALS:
             # implicit jobs get no comm row
             telemetry.record_chunk(
                 "als", start=0, losses=rmse.tolist(),
-                wall_s=_time.perf_counter() - t0,
+                wall_s=call.elapsed(),
                 ledger=(telemetry.ledger_for("als")
                         if not self.config.implicit else None))
         return u, v, rmse

@@ -23,13 +23,13 @@ and is stateless and static-shape. A row with no rating keeps its value.
 One layout, the dense planes: bfloat16 keeps 8 bits of a rating (half stars
 are exact); ``prepare`` raises where a worker's two plane shards pass
 :data:`DENSE_PLANE_BYTES`. Duplicate (row, col) pairs are dropped keep-first
-(the ``sgd_mf.dedupe_coo`` contract; ``last_layout_stats``).
+(the ``sgd_mf.dedupe_coo`` contract; ``last_layout_stats``). Host phases:
+``ccd.prepare``; ``ccd.call`` with ``step.dispatch`` and ``step.fetch``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Tuple
 
 import jax
@@ -250,16 +250,16 @@ class CCD:
         caller that trains in several calls hands back what the call before
         returned."""
         key, placed, _, _ = state
-        with telemetry.phase("ccd.call"):
+        with telemetry.phase("ccd.call") as call:
             step = self._fns[key]
-            t0 = time.perf_counter()
+            # (the dispatch keeps its line number: PERF.md section 7, row 11)
             with telemetry.phase("step.dispatch"):
                 u, v, rmse = step(*placed)
             telemetry.record_program("ccd", step, placed)
             with telemetry.phase("step.fetch"):
                 rmse = np.asarray(rmse)
             telemetry.record_chunk("ccd", start=0, losses=rmse.tolist(),
-                                   wall_s=time.perf_counter() - t0)
+                                   wall_s=call.elapsed())
         return u, v, rmse
 
     def fit_prepared(self, state

@@ -31,13 +31,13 @@ accumulations f32 but near-tie assignments may differ across variants. Where
 the fused E-step kernel runs (TPU), ``rotation`` keeps XLA's products (its
 work is block-local over circulating centroids): it then agrees with the other
 four to rounding, with the same near-tie caveat, and they with each other
-bit for bit.
+bit for bit. Host phases: ``kmeans.prepare``; ``kmeans.call`` with
+``step.dispatch`` (and ``step.fetch`` where the call fetches its cost).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 from typing import Optional, Tuple
 
@@ -587,13 +587,14 @@ class KMeans:
                 chunk_fits[chunk] = KMeans(
                     self.session,
                     dataclasses.replace(self.config, iterations=chunk))._fit
-            t0 = time.perf_counter()
-            cen, cost = chunk_fits[chunk](pts, cen)
-            chunk_costs = np.asarray(cost).tolist()
-            wall = time.perf_counter() - t0
+            with telemetry.phase("kmeans.call") as call:
+                with telemetry.phase("step.dispatch"):
+                    cen, cost = chunk_fits[chunk](pts, cen)
+                with telemetry.phase("step.fetch"):
+                    chunk_costs = np.asarray(cost).tolist()
             costs.extend(chunk_costs)
             telemetry.record_chunk("kmeans", start=it, losses=chunk_costs,
-                                   wall_s=wall, ledger=ledger,
+                                   wall_s=call.elapsed(), ledger=ledger,
                                    extra={"comm": self.config.comm})
             it += chunk
             with telemetry.phase("kmeans.checkpoint"):

@@ -44,14 +44,14 @@ V is PSD with nullspace span{1}; B(X)X is orthogonal to 1, so CG iterates
 stay in the solvable subspace and the translation-invariant embedding is
 unaffected by any residual nullspace component in the warm start (the
 previous iteration's embedding, which makes uniform-weight problems converge
-in one CG step — V acts as n·centering there).
+in one CG step — V acts as n·centering there). Host phases: ``mds.prepare``
+(its one wait under ``session.fetch``); ``mds.call`` with dispatch and fetch.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import sys
-import time
 from typing import Tuple
 
 import jax
@@ -364,10 +364,10 @@ class WDAMDS:
             _normalise, d_dev, w_dev, in_specs=(sess.shard(), sess.shard()),
             out_specs=(sess.shard(), sess.shard(), sess.replicate()),
             donate_argnums=(1,))
-        self._max_delta = float(np.asarray(scales)[1])
+        with telemetry.phase("session.fetch"):    # prepare's one wait
+            self._max_delta = float(np.asarray(scales)[1])
         self.last_layout_stats = {
-            "row_tile": geom.row_tile,
-            "weights_dtype": geom.weights_dtype,
+            "row_tile": geom.row_tile, "weights_dtype": geom.weights_dtype,
             "resident_bytes": n * n * (4 + w_dev.dtype.itemsize),
             "kernel": "pallas" if geom.row_tile else "xla",
         }
@@ -412,9 +412,9 @@ class WDAMDS:
         call starts from: a caller that trains in several calls hands back
         what the call before returned."""
         key, placed = state
-        with telemetry.phase("mds.call"):
+        with telemetry.phase("mds.call") as call:
             step = self._fns[key]
-            t0 = time.perf_counter()
+            # (the dispatch keeps its line number: PERF.md section 7, row 11)
             with telemetry.phase("step.dispatch"):
                 xt, count, sigma = step(*placed)
             telemetry.record_program("mds", step, placed)
@@ -424,7 +424,7 @@ class WDAMDS:
             metrics.DEFAULT.count("mds.anneal.levels",
                                   self._level(int(done)) - self._level(start))
             telemetry.record_chunk("mds", start=start, losses=sigma.tolist(),
-                                   wall_s=time.perf_counter() - t0)
+                                   wall_s=call.elapsed())
         return (xt, count), sigma
 
     def embedding(self, carry) -> np.ndarray:

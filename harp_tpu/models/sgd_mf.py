@@ -888,14 +888,14 @@ class SGDMF:
         throughput, not the one-time D2H of the final model
         (``benchmark/configs/sgdmf-k100.driver.py``). :meth:`fit_prepared`
         adds the fetch + de-permutation."""
-        import time as _time
-
         layout, data, w0, h0, meta = state
-        with telemetry.phase("sgd_mf.call"):
+        # the lines from here to the dispatch keep the numbers they had: a
+        # kernel's payload, and with it the compile cache's key of the step,
+        # carries the line of every frame above it (PERF.md section 7, row 11)
+        with telemetry.phase("sgd_mf.call") as call:
             key = self._program(layout, self.config.minibatches_per_hop,
                                 self.config.epochs, meta[6])
             step, args = self._compiled[key], (*data, w0, h0)
-            t0 = _time.perf_counter()
             with telemetry.phase("step.dispatch"):
                 out_w, out_h, rmse = step(*args)
             telemetry.record_program("sgd_mf.fit", step, args)
@@ -906,7 +906,7 @@ class SGDMF:
             # docstring)
             telemetry.record_chunk(
                 "sgd_mf", start=0, losses=rmse.tolist(),
-                wall_s=_time.perf_counter() - t0,
+                wall_s=call.elapsed(),
                 ledger=telemetry.ledger_for("sgd_mf",
                                             quant=self.config.quant))
         return out_w, out_h, rmse

@@ -19,10 +19,15 @@ training path):
 * :mod:`~harp_tpu.telemetry.host_spans` — ``phase(name)``, the one host span:
   ``(name, start, end, parent, call)`` on ``time.perf_counter()`` in a bounded
   ring, and a ``TraceAnnotation`` in any open profiler session; ``traced``
-  counts the traces of a program. Readers: ``phases``, ``self_seconds``.
+  counts the traces of a program, and jax's own reports of every trace,
+  lowering, compile and cache load become ``program.lower`` /
+  ``program.compile`` / ``program.cache_load`` records under the phase that
+  paid for them. ``PHASES`` lists the names. Readers: ``phases``,
+  ``self_seconds``, ``union_seconds``.
 * :mod:`~harp_tpu.telemetry.scopes` — the list of device scopes, the
-  ``scoped`` decorator, and the two readers that turn a compiled text and a
-  profiler trace into device time per scope.
+  ``scoped`` decorator, the two readers that turn a compiled text and a
+  profiler trace into device time per scope, and ``idle_by_phase``, which
+  puts the device's idle time of a trace down to the host phases.
 
 Layers that write, when enabled:
 
@@ -75,8 +80,9 @@ from harp_tpu.telemetry.exporter import (MetricsExporter,
                                          prometheus_text)
 from harp_tpu.telemetry.gang import (gather_snapshots, publish_straggler_report,
                                      straggler_report)
-from harp_tpu.telemetry.host_spans import (PhaseRecord, phase, phases,
-                                           self_seconds, traced)
+from harp_tpu.telemetry.host_spans import (PHASES, PhaseRecord, phase, phases,
+                                           self_seconds, traced,
+                                           union_seconds)
 from harp_tpu.telemetry.spans import record_span
 from harp_tpu.telemetry.step_log import (StepLog, active, configure, disable,
                                          record_chunk, record_program,
@@ -85,11 +91,12 @@ from harp_tpu.telemetry.watchdog import SLOWatchdog
 from harp_tpu.telemetry.xprof import XprofController, request_xprof
 
 __all__ = [
-    "CommLedger", "MetricsExporter", "PhaseRecord", "SLOWatchdog", "StepLog",
+    "CommLedger", "MetricsExporter", "PHASES", "PhaseRecord", "SLOWatchdog",
+    "StepLog",
     "XprofController", "active", "aggregate_snapshots", "configure",
     "disable", "gather_snapshots", "ledger_for", "load_manifest",
     "manifest_target", "phase", "phases", "prometheus_text",
     "publish_straggler_report", "record_chunk", "record_program",
     "record_span", "record_timing", "request_xprof", "scopes",
-    "self_seconds", "spans", "straggler_report", "traced",
+    "self_seconds", "spans", "straggler_report", "traced", "union_seconds",
 ]

@@ -22,6 +22,34 @@ tail shows in whatever waits for it next.
 
 :func:`traced` is the counter beside the spans: one Python line inside a
 traced function runs when jax traces it and never on a cached call.
+
+**The compile path** leaves its records where it runs. jax reports every
+trace, lowering, backend compile and load from the persistent cache to
+``jax.monitoring`` when it ends; this module listens (registered once, on
+import) and keeps each as a record whose ``end`` is the moment of the report,
+whose ``start`` lies the reported seconds before it, whose ``parent`` is the
+phase open on that thread (a compile runs on the thread that dispatched, so
+that is the ``step.dispatch`` or ``session.run`` that paid for it) and whose
+``detail`` is the function's name as jax gives it:
+
+* ``program.lower``: the trace to a jaxpr and the lowering to an MLIR module
+  (a Pallas kernel's Mosaic lowering included);
+* ``program.compile``: the backend's compile, or the cache's load in its
+  place (jax times ``compile_or_get_cached`` whole);
+* ``program.cache_load``: the retrieval from the persistent cache alone,
+  inside the ``program.compile`` that asked for it (no ``detail``: jax gives
+  that event no name).
+
+jax reports a nested trace inside its caller's (every jitted ``jax.numpy``
+function a traced body calls, hundreds a program): only the outermost of a
+thread's open traces and lowerings leaves a record, so that a start-up does
+not flood the ring, and a reader still adds the *union* of a name's intervals
+(:func:`union_seconds`), never the durations: a lowering can trace. The
+cache's hits and misses are counted in ``utils.metrics.DEFAULT`` as
+``program.cache.hits`` / ``.misses``. Nothing of this fires on a cached call.
+
+:data:`PHASES` lists every name the package emits, as
+``telemetry.scopes.SCOPES`` does for the device.
 """
 
 from __future__ import annotations
@@ -32,6 +60,7 @@ import threading
 import time
 from typing import List, NamedTuple, Optional, Sequence
 
+import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 from harp_tpu.telemetry import step_log
@@ -39,6 +68,23 @@ from harp_tpu.utils import metrics as metrics_lib
 
 RING_CAPACITY = 4096
 TRACE_MARK = "program.trace"
+LOWER = "program.lower"
+COMPILE = "program.compile"
+CACHE_LOAD = "program.cache_load"
+
+PHASES = (
+    "kmeans.prepare", "sgd_mf.prepare", "als.prepare", "ccd.prepare",
+    "mds.prepare",
+    "session.place",    # HarpSession.scatter / replicate_put: the enqueue
+    "session.run",      # a one-shot program: trace, compile or load, enqueue
+    "session.fetch",    # a blocking fetch inside a prepare: the wait
+    "kmeans.call", "sgd_mf.call", "als.call", "ccd.call", "mds.call",
+    "step.dispatch",    # the jitted call alone, which returns at the enqueue
+    "step.fetch",       # the fetch of the call's quality: the wait for the run
+    "kmeans.checkpoint", "sgd_mf.checkpoint", "lda.checkpoint",
+    "gang.straggler_publish",
+    TRACE_MARK, LOWER, COMPILE, CACHE_LOAD,
+)
 
 
 class PhaseRecord(NamedTuple):
@@ -73,7 +119,8 @@ class _Ring:
 _ring = _Ring(RING_CAPACITY)
 _ids = itertools.count()         # next() is atomic under the GIL
 _calls = itertools.count()
-_here = threading.local()        # .phase: the innermost open phase
+_here = threading.local()        # .phase: the innermost open phase;
+#                                  .lowering: jax's open traces and lowerings
 
 
 def _keep(record: PhaseRecord) -> None:
@@ -92,9 +139,12 @@ def _keep(record: PhaseRecord) -> None:
 
 
 class phase:
-    """Context manager: one host span (module docstring)."""
+    """Context manager: one host span (module docstring). ``start`` is its
+    beginning on ``time.perf_counter()`` and :meth:`elapsed` the seconds
+    since, for whoever needs the call's wall time on the same clock
+    (``record_chunk(wall_s=call.elapsed())``)."""
 
-    __slots__ = ("name", "id", "call", "_parent", "_start", "_note")
+    __slots__ = ("name", "id", "call", "start", "_parent", "_note")
 
     def __init__(self, name: str):
         self.name = name
@@ -106,16 +156,28 @@ class phase:
         _here.phase = self
         self._note = TraceAnnotation(self.name)   # inert with no session
         self._note.__enter__()
-        self._start = time.perf_counter()
+        self.start = time.perf_counter()
         return self
+
+    def elapsed(self) -> float:
+        return time.perf_counter() - self.start
 
     def __exit__(self, *exc) -> None:
         end = time.perf_counter()
         self._note.__exit__(*exc)
         parent = _here.phase = self._parent
-        _keep(PhaseRecord(self.name, self._start, end,
+        _keep(PhaseRecord(self.name, self.start, end,
                           None if parent is None else parent.id,
                           self.call, self.id))
+
+
+def _mark(name: str, start: float, end: float,
+          detail: Optional[str]) -> None:
+    """A record nobody entered: under the phase open on this thread."""
+    parent = getattr(_here, "phase", None)
+    _keep(PhaseRecord(
+        name, start, end, None if parent is None else parent.id,
+        next(_calls) if parent is None else parent.call, next(_ids), detail))
 
 
 def traced(program: str) -> None:
@@ -123,11 +185,51 @@ def traced(program: str) -> None:
     counter of ``utils.metrics.DEFAULT`` and a zero-length ``program.trace``
     mark in the ring. Call it from inside the traced function."""
     metrics_lib.DEFAULT.count(f"program.traces.{program}")
-    parent = getattr(_here, "phase", None)
     now = time.perf_counter()
-    _keep(PhaseRecord(
-        TRACE_MARK, now, now, None if parent is None else parent.id,
-        next(_calls) if parent is None else parent.call, next(_ids), program))
+    _mark(TRACE_MARK, now, now, program)
+
+
+# -- the compile path (module docstring) ------------------------------------- #
+
+_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": LOWER,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": LOWER,
+    "/jax/core/compile/backend_compile_duration": COMPILE,
+    "/jax/compilation_cache/cache_retrieval_time_sec": CACHE_LOAD,
+}
+_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "program.cache.hits",
+    "/jax/compilation_cache/cache_misses": "program.cache.misses",
+}
+
+
+def _on_start(event: str, _value: float, **_kw) -> None:
+    # jax reports the start of what it will report the duration of
+    if _DURATIONS.get(event) == LOWER:
+        _here.lowering = getattr(_here, "lowering", 0) + 1
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    name = _DURATIONS.get(event)
+    if name is None:
+        return
+    if name == LOWER:
+        _here.lowering = max(0, getattr(_here, "lowering", 1) - 1)
+        if _here.lowering:
+            return          # inside its caller's, which reports when it ends
+    now = time.perf_counter()
+    _mark(name, now - seconds, now, kw.get("fun_name"))
+
+
+def _on_event(event: str, **_kw) -> None:
+    counter = _COUNTS.get(event)
+    if counter is not None:
+        metrics_lib.DEFAULT.count(counter)
+
+
+jax.monitoring.register_scalar_listener(_on_start)
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
 
 
 def phases(since: Optional[float] = None,
@@ -142,6 +244,17 @@ def phases(since: Optional[float] = None,
 def dropped() -> int:
     """How many records the ring has let go of since the process started."""
     return _ring.dropped
+
+
+def union_seconds(records: Sequence[PhaseRecord], name: str) -> float:
+    """Seconds the records called ``name`` cover together: an interval that
+    lies inside another of the name (a nested trace) is not counted twice."""
+    total, reach = 0.0, float("-inf")
+    for r in sorted((r for r in records if r.name == name),
+                    key=lambda r: r.start):
+        total += max(0.0, r.end - max(r.start, reach))
+        reach = max(reach, r.end)
+    return total
 
 
 def self_seconds(records: Sequence[PhaseRecord], name: str) -> float:

@@ -229,17 +229,191 @@ def device_time_by_scope(xplane_path: str, scopes: Dict[str, Optional[str]],
     return out
 
 
+# -- idle time by host phase -------------------------------------------------- #
+#
+# The other half of a trace, the time in which the device ran nothing (told
+# here and not in the module's docstring: a line above ``scoped`` is a line
+# above every kernel a scoped function calls)::
+#
+#     python -m harp_tpu.telemetry.scopes <trace dir> --idle [--span window]
+#         [--also call,fetch_quality]
+
+USAGE = ("python -m harp_tpu.telemetry.scopes <trace dir> <hlo text>",
+         "python -m harp_tpu.telemetry.scopes <trace dir> --idle "
+         "[--span <annotation>] [--also <annotation>,...]")
+OTHER = "host_other"         # idle time that no listed annotation covers
+
+
+def idle_by_phase(xplane_path: str, device: int = 0, *, span: str = "",
+                  also=()) -> Optional[dict]:
+    """Where one chip's idle time of a trace went, by host phase.
+
+    The traced span runs from the first ``step.dispatch`` to the end of the
+    last step program (or, with ``span``, over the longest host annotation of
+    that name: the benchmark's ``window``). Idle is the span less the union
+    of the chip's ``XLA Ops``. Every idle interval is cut wherever an
+    annotation named in ``host_spans.PHASES`` (or in ``also``: a harness's
+    own spans) begins or ends, and each piece goes to the innermost such
+    annotation that covers it (the one that began last), ``host_other``
+    where none does: a gap is split, never given whole to one name.
+
+    The device's clock runs some hundreds of microseconds off the host's:
+    the device's events are shifted by the least amount that starts every
+    step program (an ``XLA Modules`` event) at or after the start of the
+    ``step.dispatch`` that launched it (the last one that began before the
+    program did, give or take the shift).
+
+    Returns ``{"span_s", "idle_s", "shift_s", "by_phase": {name: seconds},
+    "calls": [{"launch_s", "step_s", "completion_s"}, ...]}``: per step
+    program the launch latency (its ``step.dispatch``'s start to the
+    program's start), its time on the device, and the completion latency
+    (the program's end to the end of the fetch that waited for it: the first
+    listed annotation with ``fetch`` in its name that was open or began
+    after the program did; None where there is none). None where the trace
+    has no such device plane, no step program or no ``step.dispatch``.
+    """
+    import bisect
+
+    from jax.profiler import ProfileData
+
+    from harp_tpu.telemetry import host_spans
+
+    listed = set(host_spans.PHASES) | set(also) | ({span} if span else set())
+    data = ProfileData.from_file(xplane_path)
+    planes = sorted((p for p in data.planes
+                     if p.name.startswith("/device:TPU:")),
+                    key=lambda p: p.name)
+    if device >= len(planes):
+        return None
+    lines = {line.name: line for line in planes[device].lines}
+    ns = 1e-9
+    ops = [(e.start_ns * ns, (e.start_ns + e.duration_ns) * ns)
+           for e in (lines["XLA Ops"].events if "XLA Ops" in lines else ())]
+    modules = sorted(
+        (e.start_ns * ns, (e.start_ns + e.duration_ns) * ns)
+        for e in (lines["XLA Modules"].events if "XLA Modules" in lines
+                  else ()))
+    notes = sorted(       # the listed host annotations: (start, end, name)
+        (e.start_ns * ns, (e.start_ns + e.duration_ns) * ns, e.name)
+        for plane in data.planes if plane.name == "/host:CPU"
+        for line in plane.lines for e in line.events if e.name in listed)
+    dispatches = [n for n in notes if n[2] == "step.dispatch"]
+    if not modules or not dispatches:
+        return None
+
+    # each program's dispatch: the last that began before the program did.
+    # The clocks differ by far less than two dispatches lie apart, so half
+    # the least distance between two dispatches (5 ms at most) is the slack
+    starts = [d[0] for d in dispatches]
+    slack = min([5e-3] + [0.5 * (b - a) for a, b in zip(starts, starts[1:])])
+    paired = []
+    for m in modules:
+        i = bisect.bisect_right(starts, m[0] + slack) - 1
+        if i >= 0:
+            paired.append((m, dispatches[i]))
+    if not paired:
+        return None
+    shift = max(0.0, max(d[0] - m[0] for m, d in paired))
+
+    if span:
+        whole = [n for n in notes if n[2] == span]
+        if not whole:
+            return None
+        lo, hi, _ = max(whole, key=lambda n: n[1] - n[0])
+    else:
+        lo, hi = starts[0], max(m[1] for m in modules) + shift
+    gaps, cur = [], lo          # the span less the union of the operations
+    for a, b in sorted((a + shift, b + shift) for a, b in ops):
+        if a >= hi:
+            break
+        if a > cur:
+            gaps.append((cur, a))
+        cur = max(cur, b)
+    if cur < hi:
+        gaps.append((cur, hi))
+
+    covers = [n for n in notes if n[2] != span]
+    cover_starts = [n[0] for n in covers]
+    longest = max((n[1] - n[0] for n in covers), default=0.0)
+    by_phase: Dict[str, float] = {}
+    for g_lo, g_hi in gaps:
+        near = [n for n in covers[bisect.bisect_left(cover_starts,
+                                                     g_lo - longest):
+                                  bisect.bisect_left(cover_starts, g_hi)]
+                if n[1] > g_lo]
+        cuts = sorted({g_lo, g_hi, *(t for n in near for t in n[:2]
+                                     if g_lo < t < g_hi)})
+        for a, b in zip(cuts, cuts[1:]):
+            inside = [n for n in near if n[0] <= a and n[1] >= b]
+            name = (max(inside, key=lambda n: (n[0], -n[1]))[2]   # began last
+                    if inside else OTHER)
+            by_phase[name] = by_phase.get(name, 0.0) + b - a
+
+    fetches = [n for n in notes if "fetch" in n[2]]
+    calls = []
+    for (m_lo, m_hi), d in paired:
+        m_lo, m_hi = m_lo + shift, m_hi + shift
+        waited = next((f for f in fetches if f[1] >= m_hi and f[1] > d[0]),
+                      None)
+        calls.append({
+            "launch_s": m_lo - d[0], "step_s": m_hi - m_lo,
+            "completion_s": None if waited is None else waited[1] - m_hi})
+    return {"span_s": hi - lo, "idle_s": sum(b - a for a, b in gaps),
+            "shift_s": shift, "by_phase": by_phase, "calls": calls}
+
+
+def _print_idle(report: dict) -> None:
+    print(f"device events shifted by {1e6 * report['shift_s']:.1f} us; span "
+          f"{report['span_s']:.6f} s, idle {report['idle_s']:.6f} s "
+          f"({100.0 * report['idle_s'] / report['span_s']:.2f} %)")
+    idle = report["idle_s"]
+    for name, seconds in sorted(report["by_phase"].items(),
+                                key=lambda kv: -kv[1]):
+        print(f"{name:24s} {seconds:12.6f} s "
+              f"{100.0 * seconds / idle if idle else 0.0:6.2f} %")
+    calls = report["calls"]
+    print(f"{len(calls)} step programs: launch, device and completion "
+          "milliseconds of each")
+    for i, c in enumerate(calls):
+        done = ("     -" if c["completion_s"] is None
+                else f"{1e3 * c['completion_s']:10.3f}")
+        print(f"{i:6d} {1e3 * c['launch_s']:10.3f} {1e3 * c['step_s']:12.3f} "
+              f"{done}")
+
+
 def main(argv=None) -> int:
-    argv = sys.argv[1:] if argv is None else argv
-    if len(argv) != 2:
-        print(__doc__.split("::")[1].strip().splitlines()[0], file=sys.stderr)
+    import argparse
+
+    ap = argparse.ArgumentParser(prog="python -m harp_tpu.telemetry.scopes",
+                                 usage="\n       ".join(USAGE))
+    ap.add_argument("trace")
+    ap.add_argument("hlo", nargs="?")
+    ap.add_argument("--idle", action="store_true")
+    ap.add_argument("--span", default="")
+    ap.add_argument("--also", default="")
+    try:
+        args = ap.parse_args(argv)
+        if args.idle == (args.hlo is not None):
+            ap.error("give the step's hlo text, or --idle")
+    except SystemExit:
         return 2
-    trace_dir, hlo_path = argv
+    trace_dir = args.trace
     traces = ([trace_dir] if os.path.isfile(trace_dir) else sorted(glob.glob(
         os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)))
     if not traces:
         print(f"no .xplane.pb under {trace_dir}", file=sys.stderr)
         return 1
+    if args.idle:
+        report = idle_by_phase(
+            traces[-1], span=args.span,
+            also=tuple(n for n in args.also.split(",") if n))
+        if report is None:
+            print(f"{traces[-1]} holds no step program under a "
+                  "step.dispatch to read", file=sys.stderr)
+            return 1
+        _print_idle(report)
+        return 0
+    hlo_path = args.hlo
     with open(hlo_path) as fh:
         mapped = scope_map(fh.read())
     if not any(mapped.values()):

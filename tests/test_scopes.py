@@ -694,3 +694,93 @@ def test_the_command_prints_a_table(capsys, tmp_path):
     assert any(line.startswith("kmeans.scores") for line in lines)
     assert scopes.main([str(tmp_path), str(hlo)]) == 1       # no trace there
     assert scopes.main([]) == 2
+
+
+# --------------------------------------------------------------------------- #
+# the other half of a trace: idle time by host phase (ISSUE 36)
+# --------------------------------------------------------------------------- #
+
+# nine calls of sgdmf-k100.ml10m on one v5e chip, driven as the harness
+# drives them and traced as it traces (recorded by PR 36, seed 4294936022):
+# the program's phases lie in its host plane beside the harness's spans
+PHASED = os.path.join(os.path.dirname(TRACE),
+                      "sgdmf_v5e_1chip_phases.xplane.pb")
+HARNESS_SPANS = ("call", "fetch_quality", "job_reset")
+
+
+def test_idle_by_phase_sums_to_the_idle_time():
+    from benchmark import trace_reduce
+
+    assert os.path.getsize(PHASED) < 200_000
+    report = scopes.idle_by_phase(PHASED, span="window", also=HARNESS_SPANS)
+    # the harness's own reduction of the same window: busy is the union of
+    # the chip's XLA Ops, idle the window less it
+    summary = trace_reduce.reduce(PHASED, spans=HARNESS_SPANS)
+    assert report["span_s"] == pytest.approx(summary.window_s, rel=1e-9)
+    assert report["idle_s"] == pytest.approx(
+        summary.window_s - summary.busy_s, rel=1e-6)
+    assert sum(report["by_phase"].values()) == pytest.approx(
+        report["idle_s"], rel=1e-9)
+    assert 0.0 < report["shift_s"] < 1e-3    # some hundreds of microseconds
+    # with the program's names alone the same idle time, and what only the
+    # harness's spans covered falls to host_other
+    strict = scopes.idle_by_phase(PHASED, span="window")
+    assert strict["idle_s"] == report["idle_s"]
+    assert sum(strict["by_phase"].values()) == pytest.approx(
+        strict["idle_s"], rel=1e-9)
+    assert set(strict["by_phase"]) == {
+        "step.fetch", "step.dispatch", "sgd_mf.call", scopes.OTHER}
+    for name in ("step.fetch", "step.dispatch", "sgd_mf.call"):
+        assert strict["by_phase"][name] == report["by_phase"][name]
+    assert strict["by_phase"][scopes.OTHER] == pytest.approx(
+        sum(report["by_phase"][n] for n in (*HARNESS_SPANS, scopes.OTHER)),
+        rel=1e-9)
+    assert scopes.idle_by_phase(PHASED, device=1) is None
+    assert scopes.idle_by_phase(PHASED, span="no-such-span") is None
+    # the trace recorded before the program had phases holds none to read
+    assert scopes.idle_by_phase(TRACE) is None
+
+
+def test_idle_by_phase_splits_a_gap_at_the_phase_boundaries():
+    """The harness's reduction gives each gap whole to one span (``call``
+    takes all of it here); between two step programs the host leaves the
+    fetch that waited, ends the call, runs the harness's loop and is well
+    into the next dispatch before the device starts again."""
+    from benchmark import trace_reduce
+
+    summary = trace_reduce.reduce(PHASED, spans=HARNESS_SPANS)
+    assert [name for name, _ in summary.idle_gaps] == ["call"]
+    report = scopes.idle_by_phase(PHASED, span="window", also=HARNESS_SPANS)
+    by_phase, calls = report["by_phase"], report["calls"]
+    assert len(calls) == len(summary.step_s) == 9
+    # ten gaps (before, between and after nine programs), seven names
+    assert set(by_phase) == {"step.fetch", "step.dispatch", "sgd_mf.call",
+                             *HARNESS_SPANS, scopes.OTHER}
+    assert all(v > 0.0 for v in by_phase.values())
+    # the wait for the news of the end is most of it, the launch the next
+    assert by_phase["step.fetch"] > 0.7 * report["idle_s"]
+    assert by_phase["step.dispatch"] > by_phase["sgd_mf.call"] > 0.0
+    # per call: the completion latencies are the idle time under step.fetch,
+    # the launches the idle time under step.dispatch
+    assert sum(c["completion_s"] for c in calls) == pytest.approx(
+        by_phase["step.fetch"], rel=1e-4)
+    assert sum(c["launch_s"] for c in calls) == pytest.approx(
+        by_phase["step.dispatch"], rel=0.25)
+    for c, step in zip(calls, summary.step_s):
+        assert c["step_s"] == pytest.approx(step, rel=1e-9)
+        assert 0.0 <= c["launch_s"] < 1e-3 < c["completion_s"] < 3e-3
+
+
+def test_the_command_prints_the_idle_table(capsys):
+    assert scopes.main([PHASED, "--idle", "--span", "window",
+                        "--also", "call,fetch_quality"]) == 0
+    lines = capsys.readouterr().out.splitlines()
+    assert lines[0].startswith("device events shifted by 85.1 us")
+    assert lines[1].startswith("step.fetch") and "%" in lines[1]
+    assert any(line.startswith(scopes.OTHER) for line in lines)
+    assert "9 step programs" in lines[7]
+    assert len(lines) == 8 + 9 and len(lines[-1].split()) == 4
+    assert scopes.main([PHASED, "--idle"]) == 0          # first dispatch on
+    capsys.readouterr()
+    assert scopes.main([TRACE, "--idle"]) == 1           # no phases in it
+    assert scopes.main(["--idle"]) == 2
