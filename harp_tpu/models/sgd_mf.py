@@ -348,13 +348,20 @@ class SGDMF:
 
     def _build(self, w: int, num_data_args: int,
                make_update_bucket: Callable, epochs: int,
-               body_hops: bool = False):
+               body_hops: bool = False,
+               w_carry: Tuple[Callable, Callable] = (lambda w: w,
+                                                     lambda w: w)):
         """Shared rotation/epoch harness for both layouts.
 
         ``make_update_bucket(local_data)`` receives the worker-local shards of
         the data arrays (leading worker axis stripped) and returns
         ``update_bucket(w_local, h_block, sse, cnt, bucket_id)`` — the only
         part that differs between the sparse and dense programs.
+
+        ``w_carry``: ``(enter, leave)``, the form ``update_bucket`` takes and
+        returns ``w_local`` in. ``enter`` is applied once to the call's
+        ``(rows, K)`` table, ``leave`` once to the last epoch's carry: the
+        hops and epochs between them carry whatever ``enter`` made.
 
         ``body_hops``: the update itself performs the ring hop (the fused
         dense kernel's in-kernel remote-copy epilogue returns the NEXT
@@ -408,13 +415,15 @@ class SGDMF:
             # two-slice h0 arrives as this worker's (1, 2, cpb, K) chunk:
             # slice A block w and slice B block W+w
             h_init = (h0[0, 0], h0[0, 1]) if two_slice else h0
+            enter, leave = w_carry
+            w_init = enter(w0)
             # the epoch loop's plumbing stacks the per-epoch RMSE
             with jax.named_scope("sgdmf.rmse"):
                 (w_local, h_fin), rmse = jax.lax.scan(
-                    epoch, (w0, h_init), None, length=epochs)
+                    epoch, (w_init, h_init), None, length=epochs)
             if two_slice:
                 h_fin = jnp.stack(h_fin, axis=0)[None]   # (1, 2, cpb, K)
-            return w_local, h_fin, rmse
+            return leave(w_local), h_fin, rmse
 
         sess = self.session
         return sess.spmd(
@@ -500,25 +509,26 @@ class SGDMF:
             v_slab, row_cnt, col_cnt = data
 
             @scoped("sgdmf.stripes")
-            def _run_stripes_pallas(w_local, h_block, sse, cnt, block, rcnt,
+            def _run_stripes_pallas(w_t, h_block, sse, cnt, block, rcnt,
                                     ccnt, col_tile, ring_hop):
                 # fused hop kernel: pred/G stay in VMEM → one slab read per
                 # hop instead of XLA's ~5 slab-sized passes (pallas_kernels
                 # module doc). It takes the WHOLE slab and picks the
                 # resident block in its own index map: no copy of the block
-                # stands in front of it. Factors ride transposed (K, rows).
+                # stands in front of it. Factors ride transposed (K, rows):
+                # W is CARRIED so (w_carry below), the H block, which the
+                # rotator ships as (cpb, K), is transposed here.
                 # With ring_hop the kernel ALSO ships the updated H to the
                 # ring neighbor (VMEM → remote HBM, ops/ring_dma) and the
                 # returned block is the received one — the rotation scan
                 # then runs shift=0 (body_hops).
                 outs = pallas_kernels.dense_mf_hop_pallas(
-                    v_slab, block, w_local.T, h_block.T,
+                    v_slab, block, w_t, h_block.T,
                     rcnt.reshape(nmb, s_rows), ccnt, lr, lam,
                     col_tile=col_tile, ring_hop=ring_hop)
                 w_t, hop_sse, h_t = outs[0], outs[2], outs[-1 if ring_hop
                                                            else 1]
-                return (w_t.T, h_t.T, sse + hop_sse,
-                        cnt + jnp.sum(ccnt))
+                return w_t, h_t.T, sse + hop_sse, cnt + jnp.sum(ccnt)
 
             @scoped("sgdmf.stripes")
             def _run_stripes(w_local, h_block, sse, cnt, vb, rcnt, ccnt):
@@ -589,8 +599,14 @@ class SGDMF:
 
             return update_bucket
 
+        if not fused:
+            return self._build(w, 3, make_update_bucket, epochs)
+        # the kernel takes and returns W as (K, rpw): the call carries it so
+        # across its hops and epochs, transposed once on the way in and once
+        # on the way out (every caller sees the (rows, K) tables)
+        transpose = scoped("sgdmf.stripes")(jnp.transpose)
         return self._build(w, 3, make_update_bucket, epochs,
-                           body_hops=ring_hop)
+                           body_hops=ring_hop, w_carry=(transpose, transpose))
 
     def _program(self, layout: str, nmb: int, epochs: int, geom: Tuple):
         """Compile (or fetch) the SPMD program for a given per-hop budget.

@@ -32,18 +32,26 @@ from harp_tpu.ops import lane_pack
 # re-reads G for the dW/dH GEMMs: ~5 slab-sized HBM passes per epoch (16.7 ms
 # an epoch at MovieLens-10M's shape: ledger, PR 25, `sgdmf-k100.ml10m`). This
 # kernel fuses the whole stripe update: pred and G live only in VMEM, so the
-# epoch's HBM traffic collapses to one slab read plus factor-sized I/O (2.86
-# ms there, 85 % of the MXU's peak: PERF.md, Findings, PR 26). Factors are
-# carried TRANSPOSED — (K, rows) — so every block's lane dimension is a
-# 128-multiple (K rides the sublane dimension, where 8 | K suffices).
+# epoch's HBM traffic collapses to one slab read plus factor-sized I/O.
+# Factors are carried TRANSPOSED — (K, rows) — so every block's lane dimension
+# is a 128-multiple (K rides the sublane dimension, where 8 | K suffices);
+# the dense fused program carries W in this form across its hops and epochs
+# (models/sgd_mf._build_dense: transposed once a call, not once a hop).
 #
 # That puts a stripe's ROWS on the lanes of every W block, so a stripe must
 # be a whole number of 128-lane tiles; the dense layout stores its stripes,
 # column blocks and rank at such sizes (models/sgd_mf.DenseGeometry).
 #
 # Grid: (nmb stripes, n_ct column tiles), sequential on TPU with j innermost.
+# W is pre-update for a whole stripe, so its layout work is done ONCE A
+# STRIPE, at the stripe's first tile: the (K, s) float32 block is cast to
+# bf16 and kept in VMEM scratch in both forms the products take, (K, s) for
+# dH and (s, K) for the prediction, whose left operand Mosaic would
+# otherwise transpose in front of every step's first MXU pass (PR 37).
 # Per step: pred = W_sᵀ·H_j (MXU, bf16), G = where(isnan(V), 0, V − pred),
-# dWᵀ += H_j·Gᵀ (accumulated in VMEM scratch across j), dHᵀ = W_sᵀ·G applied
+# dWᵀ += H_j·Gᵀ (accumulated in VMEM scratch across j; as dW += G·H_jᵀ and
+# transposed at the stripe's end where the tile is 256 lanes: the faster
+# form there, dense_mf_hop_pallas), dHᵀ = W_sᵀ·G applied
 # to H_j IMMEDIATELY (tile j is touched once per stripe, so in-stripe update
 # order matches the XLA path), W written once at the stripe's last tile.
 # H lives ENTIRELY in VMEM for the whole kernel (full-array out block,
@@ -57,21 +65,23 @@ from harp_tpu.ops import lane_pack
 # resident changes every hop with (wid - t) % W, a value only the device
 # knows: picked in front of the kernel (`jnp.take`) XLA materialises the
 # block, 481 MB read and 481 MB written a hop at MovieLens-20M's shape on
-# four chips, which took longer than the hop itself (5.84 against 4.20 ms
-# an epoch: PERF.md, Findings, PR 28). The body sees an (s, col_tile) tile.
+# four chips, which took longer than the hop itself (PERF.md, Findings, PR
+# 28). The body sees an (s, col_tile) tile.
 
 
 def _dense_mf_hop_kernel(block_ref, v_ref, wt_ref, rc_ref, cc_ref, ht_in_ref,
                          wt_out_ref, ht_ref, sse_ref, *refs,
                          lr: float, lam: float, col_tile: int, n_ct: int,
-                         nmb: int = 1, ring: Optional[dict] = None):
+                         nmb: int = 1, ring: Optional[dict] = None,
+                         dw_rows: bool = False):
     del block_ref                                 # the index maps read it
     if ring is not None:
-        hn_ref, dw_ref, send_sem, recv_sem = refs
+        hn_ref, dw_ref, wb_ref, wbt_ref, send_sem, recv_sem = refs
     else:
-        (dw_ref,) = refs
+        dw_ref, wb_ref, wbt_ref = refs
     i = pl.program_id(0)
     j = pl.program_id(1)
+    bf = jnp.bfloat16
 
     @pl.when((i == 0) & (j == 0))
     def _init():
@@ -81,24 +91,25 @@ def _dense_mf_hop_kernel(block_ref, v_ref, wt_ref, rc_ref, cc_ref, ht_in_ref,
     @pl.when(j == 0)
     def _stripe_start():
         dw_ref[...] = jnp.zeros_like(dw_ref)
+        wt = wt_ref[...]                          # (K, s) f32, pre-update
+        wb_ref[...] = wt.astype(bf)               # dH's left operand
+        wbt_ref[...] = wt.T.astype(bf)            # (s, K): the prediction's
 
-    bf = jnp.bfloat16
-    wt = wt_ref[...]                              # (K, s) f32, pre-update
-    wt_b = wt.astype(bf)
     cols = pl.ds(j * col_tile, col_tile)
     ht = ht_ref[:, cols]                          # (K, CT) f32, current
     ht_b = ht.astype(bf)
-    pred = jax.lax.dot_general(wt_b, ht_b, (((0,), (0,)), ((), ())),
+    pred = jax.lax.dot_general(wbt_ref[...], ht_b, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)  # (s, CT)
     # NaN test in f32: mosaic has no bf16 vector compare (cast is free VPU)
     vf = v_ref[...].astype(jnp.float32)           # (s, CT); NaN = missing
     g = jnp.where(jnp.isnan(vf), jnp.zeros_like(pred),
                   vf - pred).astype(bf)
+    dw_lhs, dw_rhs = (g, ht_b) if dw_rows else (ht_b, g)
     dw_ref[...] += jax.lax.dot_general(
-        ht_b, g, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)       # (K, s)
+        dw_lhs, dw_rhs, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)       # (s, K) or (K, s)
     dh = jax.lax.dot_general(
-        wt_b, g, (((1,), (0,)), ((), ())),
+        wb_ref[...], g, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)       # (K, CT)
     cc = cc_ref[0:1, :]                           # (1, CT): stripe i's counts
     ht_ref[:, cols] = ht + lr * (dh - lam * cc * ht)
@@ -108,7 +119,9 @@ def _dense_mf_hop_kernel(block_ref, v_ref, wt_ref, rc_ref, cc_ref, ht_in_ref,
     @pl.when(j == n_ct - 1)
     def _stripe_end():
         rc = rc_ref[0:1, :]                       # (1, s): stripe i's counts
-        wt_out_ref[...] = wt + lr * (dw_ref[...] - lam * rc * wt)
+        wt = wt_ref[...]                          # float32, pre-update
+        dw = dw_ref[...].T if dw_rows else dw_ref[...]
+        wt_out_ref[...] = wt + lr * (dw - lam * rc * wt)
 
     if ring is not None:
         from harp_tpu.ops import ring_dma
@@ -137,13 +150,17 @@ DENSE_MF_VMEM_LIMIT = 100 * 1024 * 1024
 def dense_mf_hop_vmem_bytes(k: int, cpb: int, s: int, col_tile: int) -> int:
     """VMEM the fused hop needs at these shapes, from above: 2.5 copies of
     the resident H, 7 of a stripe's (K, s) factor block (in and out double
-    buffered, the dW scratch, the bf16 operand and a temporary), 6 bytes a
+    buffered, the dW scratch, a bf16 value and a temporary), the stripe's two
+    bf16 operands in scratch ((K, s), and (s, K) on 128 lanes), 6 bytes a
     cell of the (s, col_tile) slab tile (its two bf16 buffers plus what
-    mosaic keeps of pred/G), 4 MiB. Fitted to the least ``vmem_limit_bytes``
-    at which the v5e compiler accepts the kernel, bisected at ten shapes
-    (s 1024-17920, cpb 2048-32768, K 16-128, every tile): 2 to 10 MiB over
-    it at each (PERF.md, Findings, PR 26)."""
-    return (10 * k * cpb + 28 * k * s + 6 * s * col_tile) + (4 << 20)
+    mosaic keeps of pred/G), 4 MiB. PR 26 fitted it to the least
+    ``vmem_limit_bytes`` at which the v5e compiler accepts the kernel (ten
+    shapes, s 1024-17920, cpb 2048-32768, K 16-128, every tile: 2 to 10 MiB
+    over it at each); the kernel that keeps the stripe's operands in scratch
+    asks for less than that one did, so this now stands 4 to 23 MiB over the
+    least limit at each of twelve such shapes (PERF.md, Findings, PR 37)."""
+    return (10 * k * cpb + 28 * k * s + (2 * k + 256) * s
+            + 6 * s * col_tile) + (4 << 20)
 
 
 def dense_mf_col_tile(cpb: int, s_rows: int, k: int) -> int:
@@ -169,7 +186,8 @@ def dense_mf_hop_pallas(v_slab: jax.Array, block, w_t: jax.Array,
     (n_blocks, rpw, cpb) bf16 NaN-encoded, the WHOLE slab; block an int32
     scalar (traced or not) in [0, n_blocks); w_t (K, rpw) f32; h_t (K, cpb)
     f32; rc2 (nmb, s_rows) and cc2 (nmb, cpb) the picked block's regularizer
-    counts. Returns (w_t_new, h_t_new, sse). nmb = rc2.shape[0].
+    counts. Returns (w_t_new, h_t_new, sse), w_t_new in w_t's buffer where
+    the caller lets go of it. nmb = rc2.shape[0].
 
     The block is picked in the slab's index map, from the prefetched scalar
     (kernel comment): no copy of the block stands in front of the kernel,
@@ -200,9 +218,14 @@ def dense_mf_hop_pallas(v_slab: jax.Array, block, w_t: jax.Array,
 
         ring = {"axis_name": axis_name,
                 "num_workers": _lax_ops.num_workers(axis_name)}
+    # dW accumulates as G·H_jᵀ, (s, K), where a tile is two 128-lane chunks
+    # of contraction and as H_j·Gᵀ, (K, s), elsewhere: the faster form at
+    # each of eight shapes on the chip (-4 to -9 % of a hop at tile 256,
+    # +4 % at 512, +23 % at 128: PERF.md, Findings, PR 37), same sums
+    dw_rows = col_tile == 2 * lane_pack.LANES
     kernel = functools.partial(_dense_mf_hop_kernel, lr=lr, lam=lam,
                                col_tile=col_tile, n_ct=n_ct, nmb=nmb,
-                               ring=ring)
+                               ring=ring, dw_rows=dw_rows)
     # per-stripe count rows ride in 8-sublane-replicated blocks: mosaic
     # cannot vector-load a single DYNAMIC sublane row, so give each stripe an
     # aligned (8, ·) block and read its (static) first row in-kernel
@@ -221,7 +244,10 @@ def dense_mf_hop_pallas(v_slab: jax.Array, block, w_t: jax.Array,
         jax.ShapeDtypeStruct((k, cpb), jnp.float32),
         jax.ShapeDtypeStruct((1, 128), jnp.float32),
     ]
-    scratch_shapes = [pltpu.VMEM((k, s), jnp.float32)]
+    scratch_shapes = [pltpu.VMEM((s, k) if dw_rows else (k, s),
+                                 jnp.float32),                  # dW
+                      pltpu.VMEM((k, s), jnp.bfloat16),         # W_s
+                      pltpu.VMEM((s, k), jnp.bfloat16)]         # W_sᵀ
     params = {"vmem_limit_bytes": DENSE_MF_VMEM_LIMIT}
     if ring is not None:
         out_specs.append(pl.BlockSpec(memory_space=pl.ANY))     # h_t_next
@@ -248,6 +274,10 @@ def dense_mf_hop_pallas(v_slab: jax.Array, block, w_t: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        # W in place (operand 2 -> result 0): a stripe's block is read before
+        # it is written and no other stripe's is touched, so a caller that
+        # carries W through a loop keeps ONE buffer and copies nothing
+        input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(**params),
         interpret=interpret,
         name="dense_mf_hop",

@@ -218,12 +218,11 @@ def _bf16_shape(shape) -> str:
     return "bf16[" + ",".join(str(n) for n in shape) + "]"
 
 
-def _block_sized_bf16(lines, cells: int):
-    """The instructions among ``lines`` that yield a bf16 array of at least
-    ``cells`` elements: a copy of a slab's block, by whatever name (``(name,
-    opcode, shape)`` each). What only renames a buffer (a parameter, a
-    tuple or its element, a loop's carry, a bitcast) yields nothing new."""
-    out = []
+def _yields(lines):
+    """``(name, opcode, result)`` of the instructions among ``lines`` that
+    make a value: what only renames a buffer (a parameter, a tuple or its
+    element, a loop's carry, a bitcast) yields nothing new. ``result`` is
+    the text of the result's shapes."""
     for line in lines:
         if scopes._INSTRUCTION.match(line) is None:
             continue
@@ -231,11 +230,16 @@ def _block_sized_bf16(lines, cells: int):
         if opcode in ("parameter", "get-tuple-element", "bitcast", "tuple",
                       "while"):
             continue
-        result = line.split(" = ", 1)[1].split(opcode + "(", 1)[0]
-        for dims in re.findall(r"bf16\[([\d,]+)\]", result):
-            if np.prod([int(n) for n in dims.split(",")]) >= cells:
-                out.append((name, opcode, dims))
-    return out
+        yield name, opcode, line.split(" = ", 1)[1].split(opcode + "(", 1)[0]
+
+
+def _block_sized_bf16(lines, cells: int):
+    """The instructions among ``lines`` that yield a bf16 array of at least
+    ``cells`` elements: a copy of a slab's block, by whatever name (``(name,
+    opcode, shape)`` each)."""
+    return [(name, opcode, dims) for name, opcode, result in _yields(lines)
+            for dims in re.findall(r"bf16\[([\d,]+)\]", result)
+            if np.prod([int(n) for n in dims.split(",")]) >= cells]
 
 
 PROGRAMS = {
@@ -300,7 +304,9 @@ def test_every_kernel_of_the_loop_bodies_has_a_listed_scope(compiled, program):
     assert not bare, bare
     if program.endswith("-fused"):
         # the hop is the one kernel; what stands around it (the transposes
-        # of W and H, the count broadcasts) is fusions
+        # of the H block the rotator ships, the count broadcasts) is fusions:
+        # W is carried in the kernel's form and nothing of its size stands
+        # in the loops (test_the_hop_loops_hold_no_w_sized_operand_...)
         assert "tpu_custom_call" in text
         assert {op for _, op in kernels} >= {"fusion", "custom-call"}
     else:
@@ -435,6 +441,49 @@ def test_the_fused_hop_compiles_at_the_cells_stored_geometry(
     assert _bf16_shape(slab_shape) in call.split("custom-call(")[1], call
     assert not _block_sized_bf16(text.splitlines(),
                                  g.rpw_store * g.cpb_store)
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+def test_the_hop_loops_hold_no_w_sized_operand_but_the_kernels(compiled,
+                                                               workers):
+    """The dense fused program carries W as ``(K, rpw)``, the form the hop
+    kernel takes and returns in place (``input_output_aliases``): inside the
+    epoch and hop loops nothing but the kernel yields a float32 array of W's
+    size, neither a transpose nor a layout copy nor a copy of the carry. The
+    ``(rows, K)`` table is laid out once where the call takes it and once
+    where it returns it."""
+    text = compiled(f"sgdmf-{workers}-fused")
+    rows, k = 8 * 128, 104
+    w_sized = re.compile(r"f32\[(%d,%d|%d,%d)\]" % (rows, k, k, rows))
+
+    def makes_w(lines):
+        return [(name, opcode) for name, opcode, result in _yields(lines)
+                if w_sized.search(result)]
+
+    loops = _loop_lines(text)
+    in_loops = makes_w(loops)
+    assert [op for _, op in in_loops] == ["custom-call"], in_loops
+    assert in_loops[0][0].startswith("dense_mf_hop"), in_loops
+    # an asynchronous copy is one move under two names
+    at_the_edges = {re.sub(r"-(start|done)", "", name) for name, _ in makes_w(
+        set(text.splitlines()) - set(loops))}
+    assert 1 <= len(at_the_edges) <= 2, at_the_edges
+
+
+@pytest.mark.parametrize("cpb, s_rows, k, tile", [
+    (10752, 8960, 104, 512),        # sgdmf-k100.ml10m, as stored
+    (6912, 4352, 104, 256)])        # sgdmf-k100.ml20m-x4, a chip's
+def test_the_hop_kernels_tile_at_the_cells_counts_the_stripes_operands(
+        cpb, s_rows, k, tile):
+    """Pure shapes: with the stripe's two bf16 operands in scratch (``2 K s``
+    bytes and ``256 s``: the ``(s, K)`` form fills 128 lanes) the VMEM
+    estimate still picks the tile each cell ran at before PR 37."""
+    from harp_tpu.ops import pallas_kernels as pk
+
+    assert pk.dense_mf_col_tile(cpb, s_rows, k) == tile
+    without = 10 * k * cpb + 28 * k * s_rows + 6 * s_rows * tile + (4 << 20)
+    assert (pk.dense_mf_hop_vmem_bytes(k, cpb, s_rows, tile) - without
+            == 2 * k * s_rows + 256 * s_rows)
 
 
 def test_the_als_iteration_fits_the_chip_at_the_cells_full_shape(

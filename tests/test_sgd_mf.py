@@ -241,20 +241,25 @@ def test_nan_ratings_rejected_and_auto_dense_respects_int32_guard(session):
     assert big._choose_layout(512, 512) == "dense"
 
 
+@pytest.mark.parametrize("nmb", [2, 3])
 @pytest.mark.parametrize("col_tile", [128, 256, 512])
-def test_dense_mf_hop_pallas_matches_xla_stripes(col_tile):
+def test_dense_mf_hop_pallas_matches_xla_stripes(col_tile, nmb):
     """The fused pallas hop (interpret mode on CPU) is bit-comparable to the
     XLA stripe loop in models/sgd_mf._build_dense at the tiles the stored
     layout reaches: rank 104 with four zero rank columns, pad rows at every
     stripe's end and pad columns at the block's (NaN cells, zero counts,
-    zero factors), which a hop must leave bitwise as they were."""
+    zero factors), which a hop must leave bitwise as they were. Two stripes
+    and three, over eight, four and two column tiles: every stripe's W block
+    is another draw, so a stripe that ran on the bf16 operands an earlier
+    stripe's first tile built would miss the stripe scan by the factors'
+    own size."""
     import jax
     import jax.numpy as jnp
 
     from harp_tpu.ops import pallas_kernels as pk
 
     rng = np.random.default_rng(0)
-    NMB, S, CPB, K = 2, 128, 1024, 104
+    NMB, S, CPB, K = nmb, 128, 1024, 104
     S_LIVE, CPB_LIVE, K_LIVE = 121, 1001, 100
     RPW = NMB * S
     LR, LAM = 0.05, 0.01
@@ -312,6 +317,59 @@ def test_dense_mf_hop_pallas_matches_xla_stripes(col_tile):
         assert not got[~live_row].any() and not got[:, K_LIVE:].any()
     for got in (h_new, np.asarray(h_ref)):
         assert not got[~live_col].any() and not got[:, K_LIVE:].any()
+
+
+@pytest.mark.parametrize("workers, epochs", [(1, 2), (2, 1), (4, 1)])
+def test_the_fused_program_carries_w_in_the_kernels_form(monkeypatch, workers,
+                                                         epochs):
+    """The dense fused program carries W as ``(K, rpw)`` across its hops and
+    epochs and transposes it at the call's two edges only. Two hops of it
+    (two epochs on a ring of one, one epoch on a ring of two) and four (a
+    ring of four) leave, bit for bit, the stored tables the same hops leave
+    through the kernel's ``(rows, K)`` interface of PR 36: ``W.T`` handed in
+    and ``.T`` taken back at every hop, the ring walked by hand (the kernel
+    in interpret mode on the CPU mesh)."""
+    import functools
+
+    import jax
+
+    from harp_tpu.ops import pallas_kernels as pk
+
+    rows, cols, vals = datagen.sparse_ratings(
+        num_users=96, num_items=80, rank=4, density=0.25, seed=5)
+    cfg = sgd_mf.SGDMFConfig(rank=6, lam=0.01, lr=0.05, epochs=epochs,
+                             layout="dense", minibatches_per_hop=2)
+    hop = functools.partial(pk.dense_mf_hop_pallas, interpret=True)
+    monkeypatch.setattr(pk, "use_dense_mf_pallas", lambda *shape: True)
+    monkeypatch.setattr(pk, "dense_mf_hop_pallas", hop)
+    model = sgd_mf.SGDMF(HarpSession(num_workers=workers), cfg)
+    state = model.prepare(rows, cols, vals, 96, 80)
+    assert model.last_layout_stats["fused_hop"] is True
+    _, (v_slab, row_cnt, col_cnt), w0, h0, meta = state
+    g = meta[6]
+    w_dev, h_dev, _ = model.train_prepared(state)
+    assert w_dev.shape == (workers * g.rpw_store, g.rank_store)
+
+    v_slab, row_cnt, col_cnt = (np.asarray(a) for a in
+                                (v_slab, row_cnt, col_cnt))
+    w = np.asarray(w0).reshape(workers, g.rpw_store, g.rank_store).copy()
+    h = np.asarray(h0).reshape(workers, g.cpb_store, g.rank_store).copy()
+    one_hop = jax.jit(lambda slab, b, wt, ht, rc, cc: hop(
+        slab, b, wt, ht, rc, cc, cfg.lr, cfg.lam,
+        col_tile=model.last_layout_stats["col_tile"])[:2])
+    for t in range(epochs * workers):
+        for wid in range(workers):
+            b = (wid - t) % workers            # the block resident at hop t
+            w_t, h_t = one_hop(
+                v_slab[wid], np.int32(b), w[wid].T, h[b].T,
+                row_cnt[wid, b].reshape(g.nmb, g.s_store), col_cnt[wid, b])
+            w[wid], h[b] = np.asarray(w_t).T, np.asarray(h_t).T
+    np.testing.assert_array_equal(
+        np.asarray(w_dev), w.reshape(-1, g.rank_store))
+    # block b ends its last trip round the ring where it started
+    np.testing.assert_array_equal(
+        np.asarray(h_dev), h.reshape(-1, g.rank_store))
+    assert np.abs(w - np.asarray(w0).reshape(w.shape)).max() > 0
 
 
 @pytest.mark.parametrize("block", [0, 1, 2])
