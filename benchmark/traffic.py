@@ -1,10 +1,14 @@
 """The one general generator: a cell's traffic file -> the job's inputs.
 
 A traffic file (``benchmark/workloads/<cell>.json``) is data: the name of a
-generator below and its parameters. A later PR adds a cell by adding such a
-file; a new *kind* of data needs a generator here, which only a benchmark PR
-may add. Everything random is drawn from ``--seed`` with numpy's
-``SeedSequence``, so one seed gives one data set on any machine.
+generator and its parameters. A later PR adds a cell by adding such a file,
+and a new *kind* of data is a new file under ``generators/``:
+``benchmark/generators/<name>.py`` exposes ``generate(params, config, seed)``
+and returns a dict with ``samples_per_epoch``, as the two built-ins in
+``GENERATORS`` below do. A name is looked up in ``GENERATORS`` first, then
+as such a file; a name found in both places is an error. Everything random
+is drawn from ``--seed`` with numpy's ``SeedSequence``, so one seed gives one
+data set on any machine.
 
 What differs between seeds is the sample (points, noise, which ratings
 exist), never the sizes or the structure: the mixture's centres, the initial
@@ -16,7 +20,9 @@ traffic file. Every seed therefore gives the same job in another sample, and
 from __future__ import annotations
 
 import concurrent.futures
-from typing import Callable, Dict
+import importlib.util
+import os
+from typing import Callable, Dict, Optional
 
 import numpy as np
 
@@ -134,15 +140,51 @@ GENERATORS: Dict[str, Callable[[dict, dict, int], dict]] = {
 }
 
 
-def generate(traffic: dict, config: dict, seed: int) -> dict:
-    """The inputs of one job of this cell, from ``seed``."""
-    try:
-        gen = GENERATORS[traffic["generator"]]
-    except KeyError:
+GENERATOR_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "generators")
+_LOADED: Dict[str, Callable[[dict, dict, int], dict]] = {}
+
+
+def resolve(name: str, directory: Optional[str] = None
+            ) -> Callable[[dict, dict, int], dict]:
+    """The generator called ``name``: a built-in of ``GENERATORS``, else the
+    ``generate`` of ``<directory>/<name>.py``, each file loaded once.
+
+    ``directory`` defaults to ``GENERATOR_DIR``, read at the call: the
+    harness passes none, so a run takes the ``generators/`` beside this
+    module, which is its checkout's. A test on a copied tree passes the
+    tree's own (or sets ``GENERATOR_DIR``)."""
+    directory = GENERATOR_DIR if directory is None else directory
+    path = os.path.join(directory, f"{name}.py")
+    in_file = os.path.basename(str(name)) == name and os.path.isfile(path)
+    if name in GENERATORS:
+        if in_file:
+            raise ValueError(f"traffic generator {name!r} is both in "
+                             f"GENERATORS and the file {path}")
+        return GENERATORS[name]
+    if not in_file:
         raise ValueError(
-            f"traffic generator {traffic.get('generator')!r} is not one of "
-            f"{sorted(GENERATORS)}") from None
-    return gen(traffic["params"], config, seed)
+            f"traffic generator {name!r} is not one of GENERATORS "
+            f"{sorted(GENERATORS)} nor a file {name}.py in {directory}")
+    if path not in _LOADED:
+        spec = importlib.util.spec_from_file_location(
+            "bench_generator_" + "".join(c if c.isalnum() else "_"
+                                         for c in path), path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        _LOADED[path] = module.generate
+    return _LOADED[path]
+
+
+def generate(traffic: dict, config: dict, seed: int,
+             directory: Optional[str] = None) -> dict:
+    """The inputs of one job of this cell, from ``seed``."""
+    name = traffic.get("generator")
+    data = resolve(name, directory)(traffic["params"], config, seed)
+    if "samples_per_epoch" not in data:
+        raise ValueError(f"traffic generator {name!r} returned no "
+                         "samples_per_epoch")
+    return data
 
 
 def halved(data: dict) -> dict:

@@ -67,14 +67,18 @@ def test_the_cell_is_in_the_manifest_as_the_issue_states_it():
     assert cell.part("driver").FIELDS == ("rank", "lam", "inner_iterations")
     assert set(cell.limits) <= {"quality_gap", "step1_norm_gap",
                                 "step3_norm_gap", "step1_diff", "step3_diff"}
-    entry = next(w for w in cell.manifest["workloads"] if w["name"] == CELL)
-    assert cell.manifest["workloads"][-1] == entry      # put at the end
-    assert cell.manifest["configs"][-1]["name"] == "ccd-k100"
+    # found by name, wherever the entries stand: each is listed once
+    assert [w["name"] for w in cell.manifest["workloads"]].count(CELL) == 1
+    assert [c["name"] for c in cell.manifest["configs"]].count(
+        "ccd-k100") == 1
     names = {m["name"] for m in cell.metrics("per_layer")}
     assert {"ccd_sweep_ms", "ccd_sweep_roofline_share", "step_mfu",
             "update_roofline_share", "device_idle_share", "peak_hbm_gib",
             "epochs_to_target", "data_prep_s", "backend_compile_s"} == names
-    for m in cell.manifest["per_layer"][-2:]:
+    sweep = [m for m in cell.manifest["per_layer"]
+             if m["name"] in ("ccd_sweep_ms", "ccd_sweep_roofline_share")]
+    assert len(sweep) == 2
+    for m in sweep:
         assert m["workloads"] == [CELL] and m["source"] == "device_trace"
         assert (m["layer"], m["moves"]) == ("models / kernels",
                                             "samples_per_s")

@@ -82,8 +82,59 @@ def test_every_file_of_a_cell_is_found_by_name(cell_name):
 
 def test_configuration_files_state_every_field_the_driver_passes(manifest):
     for c in manifest["configs"]:
-        cell = next(w for w in manifest["workloads"] if w["config"] == c["name"])
-        found = harness.find_cell(cell["name"])
+        cells = [harness.find_cell(w["name"]) for w in manifest["workloads"]
+                 if w["config"] == c["name"]]
+        found = cells[0]
         for field in found.part("driver").FIELDS:
             assert field in found.config, (c["name"], field)
-        assert found.config["reduced"] == c["reduced"] == []
+        # a cut is the configuration's own: each key it names is a key of
+        # the file or a parameter of one of its cells' traffic, with the
+        # published value beside it and the deployment the cut stands for
+        assert found.config["reduced"] == c["reduced"]
+        params = {k for cell in cells for k in cell.traffic["params"]}
+        for key in c["reduced"]:
+            assert key in found.config or key in params, (c["name"], key)
+            assert key in found.config.get("published", {}), (c["name"], key)
+        if c["reduced"]:
+            deployment = found.config.get("deployment")
+            assert isinstance(deployment, str) and deployment.strip(), c["name"]
+
+
+def _cut(tree, reduced, published=True):
+    """``kmeans-d100`` in a copy of the benchmark, stated as cut in
+    ``reduced`` (its entry and its file alike)."""
+    path = os.path.join(tree, "benchmark", "configs", "kmeans-d100.json")
+    with open(path) as fh:
+        config = json.load(fh)
+    config.update(reduced=reduced, deployment="a pod's 8M-point share of one chip")
+    if published:
+        config["published"] = {key: 33554432 for key in reduced}
+    with open(path, "w") as fh:
+        json.dump(config, fh)
+    with open(os.path.join(tree, "BENCHMARK.json")) as fh:
+        manifest = json.load(fh)
+    next(c for c in manifest["configs"]
+         if c["name"] == "kmeans-d100")["reduced"] = reduced
+    return manifest
+
+
+def test_a_traffic_parameter_cut_with_its_published_value_passes(
+        tmp_path, monkeypatch):
+    tree = tiny.found_in(monkeypatch, harness, tiny.copy(str(tmp_path)))
+    test_configuration_files_state_every_field_the_driver_passes(
+        _cut(tree, ["points"]))
+
+
+def test_a_cut_without_its_published_value_fails(tmp_path, monkeypatch):
+    tree = tiny.found_in(monkeypatch, harness, tiny.copy(str(tmp_path)))
+    manifest = _cut(tree, ["points"], published=False)
+    with pytest.raises(AssertionError):
+        test_configuration_files_state_every_field_the_driver_passes(manifest)
+
+
+def test_a_cut_of_a_key_the_configuration_does_not_have_fails(
+        tmp_path, monkeypatch):
+    tree = tiny.found_in(monkeypatch, harness, tiny.copy(str(tmp_path)))
+    manifest = _cut(tree, ["num_points"])
+    with pytest.raises(AssertionError, match="num_points"):
+        test_configuration_files_state_every_field_the_driver_passes(manifest)

@@ -106,42 +106,45 @@ _ADDED = {"configs": ("wdamds-d3",), "workloads": (CELL,),
 
 
 def _before_this_cell(manifest):
-    """The manifest less this cell's entries, which have to be the END of
-    their lists: what the benchmark held before PR 34."""
+    """The manifest less this cell's entries, each found by name wherever it
+    stands: what the benchmark held before PR 34, and what later PRs added."""
     before = dict(manifest)
     for group, added in _ADDED.items():
         names = [e["name"] for e in manifest[group]]
-        assert tuple(names[-len(added):]) == added      # appended, in order
-        before[group] = manifest[group][:-len(added)]
+        assert all(names.count(name) == 1 for name in added), added
+        before[group] = [e for e in manifest[group] if e["name"] not in added]
     return before
 
 
-def test_what_the_benchmark_had_is_a_prefix_of_every_list():
+def _in_order(entries, wanted):
+    """The names of ``entries`` that are in ``wanted``, in their order."""
+    return [e["name"] for e in entries if e["name"] in wanted]
+
+
+def test_what_the_benchmark_had_is_still_listed_in_its_order():
     manifest = harness.find_cell(CELL).manifest
     before = _before_this_cell(manifest)
-    assert [c["name"] for c in before["configs"]] == [
-        "kmeans-d100", "sgdmf-k100", "als-k100", "ccd-k100"]
-    assert [w["name"] for w in before["workloads"]] == [
-        *tiny.CELLS, "als-k100.ml10m", "ccd-k100.ml10m"]
-    assert [m["name"] for m in before["per_layer"]][-4:] == [
-        "als_solve_roofline_share", "als_solve_ms", "ccd_sweep_ms",
-        "ccd_sweep_roofline_share"]
-    assert len(manifest["configs"]) == 5 and len(manifest["workloads"]) == 6
+    had = {"configs": ["kmeans-d100", "sgdmf-k100", "als-k100", "ccd-k100"],
+           "workloads": [*tiny.CELLS, "als-k100.ml10m", "ccd-k100.ml10m"],
+           "per_layer": ["als_solve_roofline_share", "als_solve_ms",
+                         "ccd_sweep_ms", "ccd_sweep_roofline_share"]}
+    for group, names in had.items():
+        assert _in_order(before[group], names) == names, group
+    assert "wdamds-d3" not in {c["name"] for c in before["configs"]}
+    assert CELL not in {w["name"] for w in before["workloads"]}
+    assert not set(READERS) & {m["name"] for m in before["per_layer"]}
 
 
 def test_ccd_cells_accepted_manifest_test_holds_on_what_was_there(monkeypatch):
-    """``test_ccd_cell.py``'s manifest test holds ``ccd-k100``'s entries to
-    the LAST place of their lists, and the benchmark's contract puts a new
-    cell's at the end: with this cell appended that test cannot pass as
-    written (``tests/conftest.py`` expects its failure, by name). Every one of
-    its asserts, the three of position too, is held here on the manifest less
-    this cell's tail."""
+    """``test_ccd_cell.py``'s manifest test holds on the manifest less this
+    cell's entries, as it did before PR 34 added them: it finds its own
+    entries by name and asks nothing of what stands around them."""
     from tests.benchmark import test_ccd_cell as accepted
 
     find_cell = harness.find_cell
 
-    def without_this_cell(name, root=harness.ROOT):
-        cell = find_cell(name, root)
+    def without_this_cell(name, *root):
+        cell = find_cell(name, *root)
         return dataclasses.replace(
             cell, manifest=_before_this_cell(cell.manifest))
 

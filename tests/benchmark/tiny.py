@@ -45,11 +45,17 @@ def _rewrite(path: str, change) -> None:
         json.dump(doc, fh)
 
 
-def build(root: str) -> str:
-    """Write the tiny tree under ``root`` and return ``root``."""
+def copy(root: str) -> str:
+    """The committed benchmark under ``root``, at its committed sizes."""
     shutil.copytree(BENCH, os.path.join(root, "benchmark"),
                     ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
+    return root
+
+
+def build(root: str) -> str:
+    """Write the tiny tree under ``root`` and return ``root``."""
+    copy(root)
     for cell, (params, target, max_epochs) in _TRAFFIC.items():
         def change(doc, params=params, target=target, max_epochs=max_epochs):
             doc["params"].update(params)
@@ -60,6 +66,15 @@ def build(root: str) -> str:
     for config, values in _CONFIG.items():
         _rewrite(os.path.join(root, "benchmark", "configs", config + ".json"),
                  lambda doc, values=values: doc.update(values))
+    return root
+
+
+def found_in(monkeypatch, harness, root: str) -> str:
+    """``harness.find_cell`` reads the tree at ``root`` where a test names
+    none, as the accepted tests do; returns ``root``."""
+    real = harness.find_cell
+    monkeypatch.setattr(harness, "find_cell",
+                        lambda name, at=root: real(name, at))
     return root
 
 
