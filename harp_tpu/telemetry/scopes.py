@@ -93,6 +93,9 @@ SCOPES = (
     "mds.bc",           # one fused pass over delta and w: B(X)X and the stress
     "mds.cg",           # the Guttman solve: w's matvecs and the CG arithmetic
     "kmeans.estep",     # the fused E-step: scores, argmin, one-hot, stats, norms
+    "em.factor",        # Cholesky, the whitening A_k and b_k, log det, constants
+    "em.estep",         # one fused pass: whitened product, log-sum-exp, moments
+    "em.update",        # the statistics' psum, the M-step arithmetic, the quality
 )
 _LISTED = frozenset(SCOPES)
 

@@ -42,21 +42,3 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: multi-process gang relaunch tests (minutes); "
         "excluded from tier-1 (-m 'not slow')")
-
-
-def pytest_collection_modifyitems(items):
-    # ONE accepted test, by name, and no list to add to: it holds ccd-k100's
-    # entries to the LAST place of BENCHMARK.json's lists, where the
-    # benchmark's contract puts every new cell's (the driver refused PR 34
-    # with its entries anywhere else), and tests/benchmark/ is only a
-    # `benchmark` PR's to edit. All its asserts run, and pass, on the manifest
-    # less PR 34's tail in tests/benchmark/test_wdamds_cell.py. strict: the
-    # `benchmark` PR that drops its three position asserts (ROADMAP.md W10 (e))
-    # turns this into a failure until these lines go with them.
-    for item in items:
-        if item.nodeid.endswith(
-                "tests/benchmark/test_ccd_cell.py::"
-                "test_the_cell_is_in_the_manifest_as_the_issue_states_it"):
-            item.add_marker(pytest.mark.xfail(
-                raises=AssertionError, strict=True,
-                reason="asserts ccd-k100 is the last cell of BENCHMARK.json"))

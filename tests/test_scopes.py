@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding
 
-from harp_tpu.models import als, ccd, kmeans, mds, sgd_mf
+from harp_tpu.models import als, ccd, em, kmeans, mds, sgd_mf
 from harp_tpu.session import HarpSession
 from harp_tpu.telemetry import scopes
 
@@ -188,6 +188,31 @@ def _mds_step(topo, workers: int = 1):
         return key, model._fns[key].lower(*args).compile()
 
 
+EM_POINTS = 6_000_000   # the cell emgmm-k100d100.aniso-6m
+
+
+def _em_step(topo, rows: int = EM_POINTS):
+    """The compiled call of two EM iterations (one would leave no loop) at
+    the cell emgmm-k100d100.aniso-6m's full shape (K = D = 100, the points
+    stored in 128 lanes), with the fused E-step the dispatch picks on the
+    chip (the predicate and the geometry's ``interpret`` ask ``jax`` for its
+    backend, which is the CPU here)."""
+    from harp_tpu.ops import em_kernels
+
+    sess = HarpSession(num_workers=1, devices=topo.devices[:1])
+    model = em.EMGMM(sess, em.EMConfig(num_components=100, iterations=2))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(em_kernels, "use_em_estep_pallas", lambda *a: True)
+        geom = dataclasses.replace(em._geometry(rows, 100, 100),
+                                   interpret=False)
+    key = model._program(geom, 2)
+    args = (_shaped(sess, (rows, geom.d_store), jnp.float32, sess.shard()),
+            _shaped(sess, (100,), jnp.float32, sess.replicate()),
+            _shaped(sess, (100, 100), jnp.float32, sess.replicate()),
+            _shaped(sess, (100, 100, 100), jnp.float32, sess.replicate()))
+    return model._fns[key].lower(*args).compile()
+
+
 def _loop_lines(text: str):
     """The instruction lines of every ``while`` body of the compiled text,
     nested loops included."""
@@ -278,6 +303,10 @@ PROGRAMS = {
                     {"mds.anneal", "mds.bc", "mds.cg"}),
     "mds-4-fused": (lambda t: _mds_step(t, 4)[1].as_text(),
                     {"mds.anneal", "mds.bc", "mds.cg", "lax.allgather"}),
+    # EM iterations at the cell's full shape: the factorization, the fused
+    # E-step, the M-step
+    "em-1-fused": (lambda t: _em_step(t).as_text(),
+                   {"em.factor", "em.estep", "em.update"}),
 }
 
 
@@ -614,6 +643,26 @@ def test_the_solve_kernel_compiles_at_rank_100(topo, no_compile_cache, rows):
         jax.ShapeDtypeStruct((104, rows), jnp.float32, sharding=one)
     ).compile().as_text()
     assert "tpu_custom_call" in text and pk.SPD_SOLVE_NAME in text
+
+
+def test_the_em_iteration_reads_the_points_in_one_kernel(compiled):
+    """The E-step of the cell's iteration is ONE kernel, ``em_estep`` under
+    ``em.estep``, handed the stored points and the stacked operand's six
+    bfloat16 passes side by side; the factorization's two custom calls are
+    the TPU library's, and nothing row-sized but the points is made."""
+    text = compiled("em-1-fused")
+    mapped = scopes.scope_map(text)
+    calls = [name for name, opcode in _loop_kernels(text)
+             if opcode == "custom-call" and mapped[name] == "em.estep"]
+    assert len(calls) == 1 and calls[0].startswith("em_estep"), calls
+    assert {"Cholesky", "InvertDiagBlocksLowerTriangular"} <= set(
+        re.findall(r'custom_call_target="(\w+)"', text))
+    call = next(line for line in _loop_lines(text)
+                if scopes._instruction(line.strip())[0] == calls[0])
+    assert f"f32[{EM_POINTS},128]" in call and "bf16[10816,768]" in call
+    assert not re.search(r"= \w+\[%d[,\]]" % EM_POINTS, "\n".join(
+        line for line in text.splitlines() if " parameter(" not in line
+        and "get-tuple-element(" not in line)), "an N-sized value is made"
 
 
 def test_the_fused_hop_picks_its_block_out_of_the_whole_slab(compiled):
