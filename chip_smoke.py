@@ -71,6 +71,12 @@ SGD_MF = {"users": 32768, "items": 32768, "density": 0.01, "rank": 32,
 # (895 rows), block (1069 columns) or rank on a tile of the fused hop kernel
 SGD_MF_UNALIGNED = {"users": 7157, "items": 1069, "density": 0.05,
                     "rank": 100, "nmb": 8, "epochs": 3}
+# the cell sgdmf-k100.ml20m-x4 as four chips store it: 8 stripes of 4,352
+# rows a worker, column blocks of 6,912 (27 tiles of 256), rank 100 as 104.
+# Few ratings: the dense hop kernel's time does not depend on how many of
+# its cells hold one
+SGD_MF_X4 = {"users": 138_493, "items": 26_744, "density": 2e-4,
+             "rank": 100, "nmb": 8, "epochs": 5, "calls": 10}
 RESTART = {"n": 100_000, "k": 100, "d": 100, "iterations": 6, "crash_at": 3}
 FLASH = {"l": 16384, "h": 8}
 # ring attention block length per chip: below and at the flash crossover
@@ -789,8 +795,85 @@ def leg_multichip_ring() -> dict:
         raise SystemExit("sgd_mf in-kernel ring hop: factors differ from "
                          "the ppermute schedule's")
     out["sgd_mf[hop fused into the dense kernel]"] = "bitwise == ppermute"
+    _sgd_mf_wires(sess, out)
     out["cache"] = stats.row()
     return out
+
+
+def _sgd_mf_wires(sess, out: dict) -> None:
+    """The four-chip cell's stored shape on its three wires: the ppermute
+    schedule (``fused_dma=False``), the hop kernel sending its H block whole
+    after its last stripe, and the program's own choice, the kernel sending
+    each column tile as the last stripe finishes it. Each wire's factors
+    equal the ppermute schedule's bit for bit, the two in-kernel programs
+    hold no collective-permute, and each wire prints one timing line: the
+    median wall of ``calls`` training calls of ``epochs`` epochs."""
+    import statistics
+
+    from harp_tpu.io import datagen
+    from harp_tpu.models import sgd_mf
+    from harp_tpu.ops import ring_dma
+    from harp_tpu.utils import metrics
+
+    c = SGD_MF_X4
+    rows, cols, vals = datagen.sparse_ratings(
+        c["users"], c["items"], rank=8, density=c["density"], seed=2)
+    tiled = ring_dma.stream_hop
+
+    def whole(*args):           # args[7]: the block's column tiles
+        return tiled(*args, tiles_per_send=args[7])
+
+    state, factors = None, {}
+    for wire, fused_dma, send in (("ppermute", False, tiled),
+                                  ("in_kernel, whole block", None, whole),
+                                  ("in_kernel, per tile", None, tiled)):
+        model = sgd_mf.SGDMF(sess, sgd_mf.SGDMFConfig(
+            rank=c["rank"], lr=1e-4, epochs=c["epochs"],
+            minibatches_per_hop=c["nmb"], fused_dma=fused_dma))
+        if state is None:
+            state = model.prepare(rows, cols, vals, c["users"], c["items"],
+                                  seed=3)
+            stats = model.last_layout_stats
+            if (stats["col_tile"], stats["ring_hop"]) != (256, "ppermute"):
+                raise SystemExit(f"sgd_mf x4 shape: {stats}")
+        layout, data, w0, h0, meta = state
+        if meta[6].cpb_store != 6912 or meta[6].s_store != 4352:
+            raise SystemExit(f"sgd_mf x4 shape: stored as {meta[6]}")
+        counter = "sgd_mf.ring." + wire.split(",")[0]
+        if "sgd_mf.ring." + model._ring_wire(True, 4) != counter:
+            raise SystemExit(f"sgd_mf x4: the program picks "
+                             f"{model._ring_wire(True, 4)}, not {wire}")
+        before = metrics.DEFAULT.counters.get(counter, 0)
+        ring_dma.stream_hop = send
+        try:
+            key = model._program(layout, c["nmb"], c["epochs"], meta[6])
+            text = model._compiled[key].lower(*data, w0, h0).compile(
+            ).as_text()
+            factors[wire] = model.fit_prepared(state)
+            walls = []
+            for _ in range(c["calls"]):
+                t0 = time.perf_counter()
+                model.train_prepared(state)
+                walls.append(time.perf_counter() - t0)
+        finally:
+            ring_dma.stream_hop = tiled
+        _assert_mosaic(text, f"sgd_mf x4, {wire}")
+        if (wire == "ppermute") != ("collective-permute" in text):
+            raise SystemExit(f"sgd_mf x4, {wire}: the compiled program's "
+                             "ring hop is not where it should be")
+        counted = metrics.DEFAULT.counters.get(counter, 0) - before
+        ms = statistics.median(walls) * 1e3
+        print(f"[multichip_ring] sgd_mf x4 wire {wire}: {ms:.3f} ms a call "
+              f"of {c['epochs']} epochs ({ms / c['epochs']:.4f} ms an "
+              f"epoch), median of {c['calls']}; sgd_mf.ring counted "
+              f"{counted:g} traced hop bodies", file=sys.stderr, flush=True)
+        out[f"sgd_mf x4 ms a call [{wire}]"] = round(ms, 3)
+    want = factors["ppermute"]
+    for wire, got in factors.items():
+        if not all(a.tobytes() == b.tobytes() for a, b in zip(want, got)):
+            raise SystemExit(f"sgd_mf x4, {wire}: factors differ from the "
+                             "ppermute schedule's")
+    out["sgd_mf x4 [three wires]"] = "bitwise == ppermute"
 
 
 def main(argv=None) -> int:

@@ -119,16 +119,18 @@ class SGDMFConfig:
     #   oracle and small-world fallback), "auto" = device when the mesh has
     #   >1 worker, host on a 1-worker mesh (nothing to redistribute).
     reshard_chunk_bytes: int = 0   # 0 = collectives.reshard default (1 MiB)
-    fused_dma: bool = False    # r10: H-block rotation hops ride the fused
-    #   ring-DMA engine (ops/ring_dma) instead of ppermute. On TPU with the
-    #   fused dense hop kernel live, the hop fuses INTO the kernel
-    #   (dense_mf_hop_pallas ring_hop: H leaves VMEM straight for the
-    #   neighbor's HBM — the ppermute staging round trips vanish); every
-    #   other path hops through ring_dma.hop. Bitwise-identical to the
-    #   ppermute schedule on every backend (the engine moves bytes, it
-    #   never rounds); off-TPU the tagged fallback keeps the jaxpr budget's
-    #   fused_dma rows honest. A quantized wire (quant=) takes precedence
-    #   over fusion (rotation.py module doc).
+    fused_dma: Optional[bool] = None  # the H-block rotation hops' wire.
+    #   None: the program decides: the hop fuses INTO the dense hop kernel
+    #   wherever that can run (TPU, the fused kernel live, a ring of more
+    #   than one, one slice, an unquantized wire; dense_mf_hop_pallas
+    #   ring_hop: each tile of H leaves VMEM for the neighbour's HBM as the
+    #   last stripe finishes it), ppermute elsewhere. True: the same, and
+    #   every other path hops through the ring-DMA engine (ops/ring_dma.hop;
+    #   off-TPU its tagged fallback keeps the jaxpr budget's fused_dma rows
+    #   honest). False: ppermute. Bitwise the same factors on every wire
+    #   (the engine moves bytes, it never rounds); a quantized wire (quant=)
+    #   takes precedence over fusion (rotation.py module doc).
+    #   last_layout_stats["ring_hop"] says which wire the hops ride.
 
 
 # --------------------------------------------------------------------------- #
@@ -393,7 +395,7 @@ class SGDMF:
                 w, cfg.num_slices,
                 comm=(quantize.CommConfig(quant=cfg.quant)
                       if cfg.quant is not None else None),
-                fused_dma=cfg.fused_dma and not body_hops,
+                fused_dma=bool(cfg.fused_dma) and not body_hops,
                 shift=0 if body_hops else 1)
 
             def epoch(state, _):
@@ -481,6 +483,26 @@ class SGDMF:
         return (pallas_kernels.dense_mf_col_tile(*shape)
                 if pallas_kernels.use_dense_mf_pallas(*shape) else 0)
 
+    def _ring_wire(self, fused: bool, w: int) -> str:
+        """The wire a dense hop's H block rides to the right neighbour:
+        ``"in_kernel"`` (the fused hop kernel streams it out behind its last
+        stripe), ``"ring_dma"`` (the engine's own hop after the update:
+        ``fused_dma=True`` where the kernel cannot send), ``"ppermute"``, or
+        ``"none"`` on a ring of one. The kernel sends wherever it can: on
+        the TPU backend (remote DMA has no other lowering), with the fused
+        kernel live, on the one-slice schedule (the two-slice pipeline
+        overlaps its own hops) and an unquantized wire (``quant`` takes the
+        encode path), unless ``fused_dma=False``."""
+        cfg = self.config
+        if w == 1:
+            return "none"
+        if (fused and cfg.fused_dma is not False and cfg.num_slices == 1
+                and cfg.quant is None and ring_dma.use_ring_dma()):
+            return "in_kernel"
+        if cfg.fused_dma and cfg.quant is None:
+            return "ring_dma"
+        return "ppermute"
+
     def _build_dense(self, w: int, nmb: int, g: DenseGeometry, epochs: int):
         lr, lam = self.config.lr, self.config.lam
         # the program runs on the STORED geometry throughout
@@ -490,17 +512,10 @@ class SGDMF:
         col_tile = self._hop_tile(g, nmb)
         fused = col_tile > 0
         slab_pick = _slab_pick(fused, self.config.num_slices * w)
-        # in-kernel ring hop (r10): fused dense kernel + fused_dma + a plain
-        # (unquantized) multi-worker wire (quant takes the encode path) on
-        # the 1-slice schedule ONLY — the kernel's blocking send+wait would
-        # defeat the 2-slice pipeline's compute/DMA overlap, so 2-slice
-        # keeps the out-of-kernel fused hop. The kernel then returns the
-        # already-hopped H block, so _build runs the rotation scan with
-        # shift=0 (body_hops).
-        ring_hop = bool(fused and self.config.fused_dma and w > 1
-                        and self.config.num_slices == 1
-                        and self.config.quant is None
-                        and ring_dma.use_ring_dma())
+        # the kernel that sends its H block returns the block it received,
+        # so _build runs the rotation scan with shift=0 (body_hops)
+        wire = self._ring_wire(fused, w)
+        ring_hop = wire == "in_kernel"
 
         def make_update_bucket(data):
             # missing entries are NaN-encoded in the value slab — no separate
@@ -570,6 +585,8 @@ class SGDMF:
                 # hops run, and where the resident block is picked
                 metrics.DEFAULT.count(
                     "sgd_mf.hops.fused" if fused else "sgd_mf.hops.xla")
+                if wire != "none":
+                    metrics.DEFAULT.count("sgd_mf.ring." + wire)
                 if slab_pick != "static":
                     metrics.DEFAULT.count(
                         "sgd_mf.picks.in_kernel" if fused
@@ -831,6 +848,7 @@ class SGDMF:
             "pad_overhead": rpw_st * cpb_st / (rpw * cpb),
             "fused_hop": col_tile > 0, "col_tile": col_tile,
             "slab_pick": _slab_pick(col_tile > 0, n_blocks),
+            "ring_hop": self._ring_wire(col_tile > 0, w),
         }
 
         # the first model is drawn at the LOGICAL sizes (what the

@@ -126,21 +126,20 @@ def _dense_mf_hop_kernel(block_ref, v_ref, wt_ref, rc_ref, cc_ref, ht_in_ref,
     if ring is not None:
         from harp_tpu.ops import ring_dma
 
-        @pl.when((i == nmb - 1) & (j == n_ct - 1))
-        def _ring_send():
-            # r10 fused rotation hop — the first consumer of the shared
-            # ring engine: H is resident in VMEM for the whole kernel, so
-            # the hop DMAs it VMEM → remote HBM directly. ppermute instead
-            # costs writing H to HBM, reading it into the collective's
-            # staging buffer, and writing it out on the receiver — two
-            # whole-H HBM round trips this send skips. The send can only
-            # start once the last stripe's update lands (the hop ships the
-            # UPDATED block), so it does not overlap this hop's compute;
-            # the overlap schedule stays the rotation scan's job.
-            ax, nw = ring["axis_name"], ring["num_workers"]
-            ring_dma.ring_ready(ax, nw, 1)
-            ring_dma.start_hop(ht_ref, hn_ref, send_sem, recv_sem, ax, nw,
-                               1).wait()
+        # The fused rotation hop. H is resident in VMEM for the whole
+        # kernel, and column tile j is final once the LAST stripe has
+        # updated it, at this step (nmb - 1, j): its copy to the right
+        # neighbour's h_t_next (VMEM -> remote HBM) starts right after the
+        # store above and runs while the MXU takes tiles j+1, ...; the last
+        # step waits for every send and receive. The transfer overlaps the
+        # last stripe, where a ppermute after the kernel waits for the whole
+        # block (on four v5e chips half of that wait went: PERF.md, Findings)
+        # and costs writing H to HBM and a staging copy on either side.
+        # The handshake opens the last stripe (ops/ring_dma.stream_hop); the
+        # program sends this way wherever it can (models/sgd_mf._ring_wire).
+        ring_dma.stream_hop(ht_ref, hn_ref, send_sem, recv_sem,
+                            i == nmb - 1, j, col_tile, n_ct,
+                            ring["axis_name"], ring["num_workers"])
 
 
 # what the kernel may ask of VMEM (v5e: 128 MiB physical)
@@ -196,9 +195,10 @@ def dense_mf_hop_pallas(v_slab: jax.Array, block, w_t: jax.Array,
 
     ``ring_hop`` (TPU only, inside shard_map over ``axis_name``): also
     ring-ship the UPDATED H block to the right neighbor from inside the
-    kernel (ops/ring_dma engine; kernel comment) and return
-    ``(w_t_new, h_t_new, sse, h_t_next)`` — ``h_t_next`` is the block this
-    worker receives, i.e. what ``lax_ops.rotate(h, 1)`` would deliver; the
+    kernel, each column tile as the last stripe finishes it (kernel
+    comment; ops/ring_dma.stream_hop), and return
+    ``(w_t_new, h_t_new, sse, h_t_next)``: ``h_t_next`` is the block this
+    worker receives, what ``lax_ops.rotate(h, 1)`` would deliver; the
     caller's rotation scan must then run shift=0."""
     if ring_hop and interpret:
         raise ValueError("ring_hop=True has no interpret-mode lowering "

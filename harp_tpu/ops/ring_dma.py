@@ -22,10 +22,13 @@ pattern: one engine, many call sites). Three layers:
   entered the kernel), :func:`start_hop`/:func:`hop_op` (device-id ring
   math + ``make_async_remote_copy`` with ``DeviceIdType.MESH``, returned
   STARTED so the caller computes before ``.wait()`` — the per-hop
-  start/wait split). These are what the fused kernels consume: the
-  flash-attention ring epilogue (``pallas_kernels._flash_kernel``), the
-  dense-MF hop epilogue (``pallas_kernels.dense_mf_hop_pallas``), and the
-  in-kernel ring allgather below.
+  start/wait split), :func:`stream_hop` (a block sent column tile by
+  column tile as the kernel's grid finishes them). These are what the
+  fused kernels consume: the flash-attention ring epilogue
+  (``pallas_kernels._flash_kernel``), the dense-MF hop, which streams the
+  updated H block out behind its last stripe
+  (``pallas_kernels.dense_mf_hop_pallas``), and the in-kernel ring
+  allgather below.
 * **Host-level fused ops** — :func:`hop` (one whole-payload ring hop as a
   pallas kernel: barrier, start, wait; HBM→remote-HBM, zero staging) and
   :func:`ring_allgather` (the W−1-hop in-kernel relay, double-buffered
@@ -188,6 +191,52 @@ def start_hop(src_ref, dst_ref, send_sem, recv_sem, axis_name: str,
                 num_workers, shift)
     op.start()
     return op
+
+
+def stream_hop(src_ref, dst_ref, send_sem, recv_sem, live, j, tile: int,
+               n_tiles: int, axis_name: str, num_workers: int,
+               tiles_per_send: int = 1) -> None:
+    """One ring hop of a ``(rows, n_tiles * tile)`` block, streamed out of a
+    kernel's grid column tile by column tile as the kernel finishes them.
+
+    Call it at every grid step, after the step has stored column tile ``j``
+    of ``src_ref``; ``live`` is true on the steps whose tiles are final (the
+    dense-MF hop: its last stripe, whose step j is tile j's last update).
+    The first live step does the :func:`ring_ready` handshake; every
+    ``tiles_per_send``-th live step starts the copy of the tiles stored since
+    the last send into the right neighbour's ``dst_ref`` at the same
+    columns; the last live step (``j == n_tiles - 1``) waits for every send
+    and every receive, one wait a send (a DMA semaphore counts what each
+    copy moved). So the block's transfer overlaps the live steps' compute,
+    and the last send at least stays exposed. ``tiles_per_send == n_tiles``
+    sends the block whole at the last step: nothing overlaps.
+
+    One send and one recv DMA semaphore serve all the copies: a wait takes
+    one copy's worth off its semaphore, in whatever order the copies end.
+    The copy of step j reads the tiles after the step's stores, in program
+    order."""
+    if n_tiles % tiles_per_send:
+        raise ValueError(f"{tiles_per_send} tiles a send do not divide "
+                         f"{n_tiles}")
+    cols = tiles_per_send * tile
+
+    def op(c):
+        window = pl.ds(c * cols, cols)
+        return hop_op(src_ref.at[:, window], dst_ref.at[:, window], send_sem,
+                      recv_sem, axis_name, num_workers)
+
+    @pl.when(live & (j == 0))
+    def _ready():
+        ring_ready(axis_name, num_workers)
+
+    @pl.when(live & (lax.rem(j + 1, tiles_per_send) == 0))
+    def _send():
+        op(lax.div(j + 1, tiles_per_send) - 1).start()
+
+    @pl.when(live & (j == n_tiles - 1))
+    def _drain():
+        for c in range(n_tiles // tiles_per_send):
+            op(c).wait()
 
 
 # --------------------------------------------------------------------------- #

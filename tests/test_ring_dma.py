@@ -205,6 +205,14 @@ def test_ring_attention_fused_parity(session, rng, l_local, causal, flash):
     np.testing.assert_array_equal(outs[False], outs[True])
 
 
+def test_stream_hop_sends_whole_tiles_only():
+    """A send carries a whole number of the kernel's column tiles, and the
+    sends cover the block: 4 tiles a send cannot cover 27."""
+    with pytest.raises(ValueError, match="do not divide"):
+        ring_dma.stream_hop(None, None, None, None, True, 0, 256, 27,
+                            "workers", 4, tiles_per_send=4)
+
+
 def test_flash_ring_hop_rejects_bad_modes():
     from harp_tpu.ops import pallas_kernels as pk
 

@@ -81,13 +81,16 @@ def _kmeans_text(topo, workers: int, ambient="highest", fused: bool = False,
             return model._fit.lower(points, centroids).compile().as_text()
 
 
-def _sgdmf_text(topo, workers: int, fused: bool = False) -> str:
+def _sgdmf_text(topo, workers: int, fused: bool = False,
+                ring: bool = False) -> str:
     """The dense SGD-MF step at the cells' widths as the layout stores them
     (rank 100 as 104, ML-10M's or ML-20M's columns per block padded to 256),
     the stripes cut to 128 rows. ``fused``: the program the chip
     runs, with the fused hop kernel (the dispatch asks ``jax`` for its
-    backend, which is the CPU here)."""
-    from harp_tpu.ops import pallas_kernels
+    backend, which is the CPU here); ``ring``: with the hop's H block sent
+    from inside that kernel, the wire the chip picks on a ring of four
+    (``fused_dma`` left to the program)."""
+    from harp_tpu.ops import pallas_kernels, ring_dma
 
     sess = HarpSession(num_workers=workers, devices=topo.devices[:workers])
     model = sgd_mf.SGDMF(sess, sgd_mf.SGDMFConfig(
@@ -99,6 +102,7 @@ def _sgdmf_text(topo, workers: int, fused: bool = False) -> str:
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(pallas_kernels, "use_dense_mf_pallas",
                       lambda *shape: fused)
+        patch.setattr(ring_dma, "use_ring_dma", lambda: ring)
         key = model._program("dense", g.nmb, 5, g)
     shard = sess.shard()
     args = (_shaped(sess, (workers, workers, rows, cpb), jnp.bfloat16, shard),
@@ -284,6 +288,9 @@ PROGRAMS = {
     "sgdmf-4-fused": (lambda t: _sgdmf_text(t, 4, fused=True),
                       {"sgdmf.select", "sgdmf.stripes", "sgdmf.rmse",
                        "rotation.hop"}),
+    # the same with the kernel sending its H block: no hop outside it
+    "sgdmf-4-ring-fused": (lambda t: _sgdmf_text(t, 4, fused=True, ring=True),
+                           {"sgdmf.select", "sgdmf.stripes", "sgdmf.rmse"}),
     # the ALS iteration at the cell's full shape; its one kernel is the solve
     "als-1-fused": (lambda t: _als_step(t).as_text(),
                     {"als.outer", "als.gram", "als.rhs", "als.solve",
@@ -695,6 +702,24 @@ def test_the_ring_hop_is_a_collective_permute_under_its_own_name(compiled):
     hops = [name for name, op in _loop_kernels(text)
             if op == "collective-permute"]
     assert hops and {mapped[name] for name in hops} == {"rotation.hop"}
+
+
+def test_on_the_chip_the_hop_kernel_sends_the_ring_hop_itself(compiled):
+    """Where the chip's backend runs the fused kernel on a ring of four, the
+    program leaves the wire to the kernel (it streams each finished tile of
+    H to its neighbour): no collective-permute is left in the program, the
+    kernel returns the received block beside its own three results, and the
+    RMSE's psum is the loops' one collective."""
+    text = compiled("sgdmf-4-ring-fused")
+    assert "collective-permute" not in text
+    (call,) = [line for line in _loop_lines(text) if "custom-call(" in line
+               and "tpu_custom_call" in line]
+    assert call.split("=")[0].strip().startswith("%dense_mf_hop")
+    outs = call.split("=", 1)[1].split("custom-call(")[0]
+    assert outs.count("f32[104,6912]") == 2, outs      # H updated, H received
+    assert {op for _, op in _loop_kernels(text)} & {
+        "all-gather", "reduce-scatter", "all-to-all",
+        "collective-permute"} == set()
 
 
 def test_scope_map_gives_none_without_a_scope(compiled):
