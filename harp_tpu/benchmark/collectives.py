@@ -246,7 +246,9 @@ def bench_collectives_quantized(
                     "codec": codec or "f32",
                     "payload_bytes_per_worker": payload,
                     "us_per_op": round(sec / loops * 1e6, 1),
-                    "busbw_gbps": round(moved / (sec / loops) / 1e9, 3),
+                    # four significant digits: a slow small op keeps its
+                    # bandwidth rather than rounding to 0
+                    "busbw_gbps": float(f"{moved / (sec / loops) / 1e9:.4g}"),
                     "link_class": link,
                     "num_workers": session.num_workers,
                     "convention": CONVENTION_NOTE,

@@ -445,6 +445,41 @@ def load_corpus(spec: str, num_threads: int = 4) -> np.ndarray:
     return _assemble_rows(results, np.int64)
 
 
+def is_docword(path: str) -> bool:
+    """Whether ``path`` is in the UCI bag-of-words ``docword.*.txt`` format:
+    three header lines of one integer each (documents, words, nonzeros)."""
+    with (_fsspec_open(path, "rt") if _is_url(path) else open(path)) as fh:
+        head = [fh.readline().split() for _ in range(3)]
+    return all(len(h) == 1 and h[0].isdigit() for h in head)
+
+
+def load_docword(path: str) -> Tuple[np.ndarray, np.ndarray, int]:
+    """A UCI bag-of-words corpus (``docword.nytimes.txt``, ``.pubmed``,
+    ...): three header lines (D, W, NNZ), then ``docID wordID count``
+    triplets with ids from 1. Returns the CSR corpus ``(doc_ptr (D + 1,)
+    int64, words (N,) int32, W)``: each document's tokens back to back,
+    word ids from 0, a word repeated by its count, in file order within a
+    document."""
+    with (_fsspec_open(path, "rt") if _is_url(path) else open(path)) as fh:
+        d, w, nnz = (int(fh.readline()) for _ in range(3))
+        triplets = np.loadtxt(fh, dtype=np.int64, ndmin=2)
+    if triplets.shape != (nnz, 3):
+        raise ValueError(f"{path}: header says {nnz} triplets, the file "
+                         f"holds {triplets.shape[0]} rows of "
+                         f"{triplets.shape[1:]}")
+    doc, word, count = triplets.T
+    if nnz and (doc.min() < 1 or doc.max() > d or word.min() < 1
+                or word.max() > w or count.min() < 1):
+        raise ValueError(f"{path}: ids outside 1..{d} / 1..{w} or a count "
+                         f"below 1")
+    order = np.argsort(doc, kind="stable")
+    doc, word, count = doc[order], word[order], count[order]
+    doc_ptr = np.zeros(d + 1, np.int64)
+    np.cumsum(np.bincount(doc - 1, weights=count, minlength=d).astype(
+        np.int64), out=doc_ptr[1:])
+    return doc_ptr, np.repeat(word - 1, count).astype(np.int32), w
+
+
 def load_labeled_csv(spec: str, num_threads: int = 4
                      ) -> Tuple[np.ndarray, np.ndarray]:
     """Dense CSV with the LABEL in the last column (the daal_svm/daal_naive

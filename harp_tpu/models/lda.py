@@ -53,7 +53,9 @@ oracle the convergence-parity test measures against.
 
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -62,9 +64,10 @@ import numpy as np
 
 from harp_tpu import telemetry
 from harp_tpu.collectives import lax_ops, quantize, rotation
-from harp_tpu.ops import lane_pack
+from harp_tpu.ops import lane_pack, lda_kernels
 from harp_tpu.parallel.mesh import WORKERS, fetch
 from harp_tpu.session import HarpSession
+from harp_tpu.utils import metrics
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,6 +162,17 @@ class LDAConfig:
     #   "host" = the PR 8 numpy re-match/rebuild (parity oracle + 1-worker
     #   fallback), "auto" = device when the mesh has >1 worker.
     reshard_chunk_bytes: int = 0  # 0 = collectives.reshard default (1 MiB)
+    layout: str = "auto"        # "auto": prepare lays a (D, L) matrix out
+    #   in the (doc, vocab block) buckets above (every variant, and
+    #   fit_checkpointed) and a CSR corpus as the stream; "stream" lays a
+    #   matrix out as the stream too. The stream (plain CGS only): tokens
+    #   back to back, sorted by word inside G groups of whole documents, a
+    #   sweep of G blocked steps (group-start counts), int32 counts, the
+    #   counter-hash random stream of ops/lda_kernels and one fused Pallas
+    #   pass a group on TPU (prepare / train_prepared / parameters; see
+    #   _stream_layout)
+    groups_per_sweep: int = 16  # G of the stream layout: a group's tokens
+    #   draw against the counts at the group's start
 
 
 def bucketize_tokens(docs: np.ndarray, num_blocks: int, vpb: int,
@@ -251,6 +265,16 @@ class LDA:
                     "vocab_sub_block requires method='cgs' with "
                     "wt_access='auto'/'gemm_scatter' (the sub-block layout "
                     "exists to narrow the gemm_scatter one-hot)")
+        if config.layout not in ("auto", "stream"):
+            raise ValueError(f"layout must be 'auto' or 'stream', got "
+                             f"{config.layout!r}")
+        if config.layout == "stream" and _bucketed_variants(config):
+            raise ValueError(f"layout='stream' runs plain CGS; "
+                             f"{_bucketed_variants(config)} need the "
+                             f"bucketed layout")
+        if config.groups_per_sweep < 1:
+            raise ValueError(f"groups_per_sweep must be positive, got "
+                             f"{config.groups_per_sweep}")
         self.session = session
         self.config = config
         self._fns = {}
@@ -565,11 +589,23 @@ class LDA:
                        sess.replicate()),
         )
 
-    def prepare(self, docs: np.ndarray, seed: int = 0):
-        """Bucketize + place tokens and initial counts on the mesh ONCE.
+    def prepare(self, docs, seed: int = 0):
+        """Lay out + place tokens and initial counts on the mesh ONCE.
 
-        Returns an opaque state for :meth:`fit_prepared` — keeps host layout
-        and H2D transfer out of timed regions (KMeans.prepare idiom)."""
+        ``docs`` is a (D, L) token matrix (equal-length documents) or a CSR
+        corpus ``(doc_ptr (D + 1,), words (N,))``. Under ``layout="auto"`` a
+        CSR corpus goes to the stream and a matrix to the buckets. Returns an
+        opaque state for :meth:`fit_prepared` (either layout) and
+        :meth:`train_prepared` / :meth:`fit_checkpointed` (stream /
+        bucketed) — keeps host layout and H2D transfer out of timed regions
+        (KMeans.prepare idiom)."""
+        if self.config.layout == "stream" or isinstance(docs, tuple):
+            if _bucketed_variants(self.config):
+                raise ValueError(
+                    f"a CSR corpus runs on the stream layout, which runs "
+                    f"plain CGS; {_bucketed_variants(self.config)} need a "
+                    f"(D, L) matrix")
+            return self._prepare_stream(as_csr(docs), seed)
         sess, cfg = self.session, self.config
         w = sess.num_workers
         nb = w * cfg.num_model_slices
@@ -675,6 +711,10 @@ class LDA:
     def fit_prepared(self, state
                      ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Run training on already-placed device data (no host prep)."""
+        if _is_stream(state):
+            state, _, word_ll = self._sweeps(state, self.config.epochs)
+            word_topic, doc_topic, _ = self.parameters(state)
+            return doc_topic, word_topic, word_ll
         import time as _time
 
         key, data, seed, (word_block, word_slot, vpb) = state
@@ -695,12 +735,12 @@ class LDA:
         wt_final = wt_out[self._out_rows(key[0], word_block, word_slot, vpb)]
         return fetch(doc_topic), wt_final, ll
 
-    def fit(self, docs: np.ndarray, seed: int = 0
+    def fit(self, docs, seed: int = 0
             ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Train on a (num_docs, doc_len) token matrix.
+        """Train on a (num_docs, doc_len) token matrix or a CSR corpus.
 
-        Returns (doc_topic (D, K), word_topic (V, K), log-likelihood per epoch
-        in the reference formula)."""
+        Returns (doc_topic (D, K), word_topic (V, K), log-likelihood per
+        epoch: the reference formula's word part, under either layout)."""
         return self.fit_prepared(self.prepare(docs, seed))
 
     def fit_checkpointed(self, state, checkpointer, save_every: int = 1,
@@ -732,6 +772,8 @@ class LDA:
         the new layout. Same-world resume takes the historical bitwise path
         untouched."""
         sess, cfg = self.session, self.config
+        if _is_stream(state):
+            raise ValueError("fit_checkpointed runs the bucketed layout")
         key, data, seed, (word_block, word_slot, vpb) = state
         docs_b, mask_b, z_cur, wt_cur = data
         from harp_tpu.parallel import faults
@@ -977,6 +1019,372 @@ class LDA:
             # 2-slice placement) — mirror it so the scatter lands right
             wt = wt.reshape(2, nb_new // 2, vpb, k).transpose(1, 0, 2, 3)
         return {**saved, "z": z_new, "wt": wt.reshape(nb_new * vpb, k)}
+
+    # -- the stream layout ------------------------------------------------ #
+
+    def _prepare_stream(self, corpus, seed: int):
+        """Lay the corpus out as the stream (:func:`_stream_layout`), place
+        it, and make the first model and its counts on the mesh: one
+        program, run once."""
+        sess, cfg = self.session, self.config
+        with telemetry.phase("lda.prepare"):
+            doc_ptr, words = corpus
+            geom, host, arrays = _stream_layout(doc_ptr, words,
+                                                sess.num_workers, cfg)
+            seed_w = lda_kernels.seed_words(seed)
+            static = tuple(sess.scatter(a) for a in arrays) + (
+                sess.replicate_put(seed_w),)
+            first = sess.run(
+                functools.partial(_first_model, g=geom),
+                *static, in_specs=(sess.shard(),) * 6 + (sess.replicate(),),
+                out_specs=(sess.shard(), sess.replicate(), sess.shard(),
+                           sess.replicate()))
+            sweep = sess.replicate_put(np.zeros((), np.int32))
+            self.last_layout_stats = {
+                "layout": "stream",
+                "kernel": "pallas" if geom.kernel else "xla",
+                "token_tile": lda_kernels.TILE,
+                "count_write": "gemm_scatter" if geom.kernel else "segment",
+                "pad_tokens": int(geom.workers * geom.slots - geom.tokens),
+                "groups": geom.groups, "docs_per_group": geom.dg,
+                "resident_bytes": int(4 * geom.workers * (
+                    4 * geom.slots + geom.doc_rows * lda_kernels.KP)
+                    + 4 * geom.words_store * lda_kernels.KP),
+            }
+        return geom, host, static, (*first, sweep)
+
+    def _stream_program(self, g: "_Stream", sweeps: int):
+        """Key of the SPMD program of ``sweeps`` sweeps (built on first use):
+        the stream and the document rows sharded, the word rows and the
+        totals replicated."""
+        sess = self.session
+        key = ("lda.stream", g, sweeps)
+        if key not in self._fns:
+            self._fns[key] = sess.spmd(
+                functools.partial(_stream_sweeps, g=g, sweeps=sweeps),
+                in_specs=(sess.shard(),) * 6 + (sess.replicate(),)
+                + (sess.shard(), sess.replicate(), sess.shard())
+                + (sess.replicate(),) * 2,
+                out_specs=((sess.shard(), sess.replicate(), sess.shard(),
+                            sess.replicate(), sess.replicate()),
+                           sess.replicate(), sess.replicate(),
+                           sess.replicate()))
+        return key
+
+    def train_prepared(self, state, sweeps: Optional[int] = None):
+        """Run ``sweeps`` (default ``config.epochs``) sweeps of the stream
+        layout as one compiled program from the chain the state holds.
+        Returns ``(state, quality)``: the new state (z, the int32 word-topic,
+        doc-topic and topic-total counts and the sweep counter, still on the
+        device) and each sweep's ``−log p(w, z) / N`` after the sweep (host,
+        float64; the fetch forces execution). Two calls of 2 and 3 sweeps
+        make the chain of one call of 5."""
+        state, quality, _ = self._sweeps(state, sweeps or self.config.epochs)
+        return state, quality
+
+    def _sweeps(self, state, sweeps: int):
+        """:meth:`train_prepared`, also returning each sweep's word part of
+        the likelihood (the bucketed layout's per-epoch value)."""
+        g, host, static, chain = state
+        key = self._stream_program(g, sweeps)
+        with telemetry.phase("lda.call"):
+            step = self._fns[key]
+            with telemetry.phase("step.dispatch"):
+                chain, quality, word_ll, moved = step(*static, *chain)
+            telemetry.record_program("lda", step, (*static, *state[3]))
+            with telemetry.phase("step.fetch"):
+                quality, word_ll, moved = jax.device_get(
+                    (quality, word_ll, moved))
+            metrics.DEFAULT.count("lda.tokens.moved", int(np.sum(moved)))
+        return ((g, host, static, chain), np.asarray(quality, np.float64),
+                np.asarray(word_ll, np.float64))
+
+    def parameters(self, state) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(word-topic (W, K), doc-topic (D, K), z (N,))`` of a stream
+        state on the host: int32 counts in original word and document ids,
+        every token's topic in corpus order."""
+        g, host, static, chain = state
+        z, wt, dt = (fetch(a) for a in chain[:3])
+        topics = lda_kernels.TOPIC_SLOT[:g.k]
+        word_topic = np.take(wt.reshape(g.words_store, -1)[:g.vocab], topics,
+                             axis=1)
+        rows = dt.reshape(g.workers, g.doc_rows, -1)
+        doc_topic = np.concatenate(
+            [np.take(rows[p, :n], topics, axis=1)
+             for p, n in enumerate(host.docs)])
+        z_corpus = np.empty(g.tokens, np.int32)
+        z_corpus[host.index] = z[host.valid]
+        return word_topic, doc_topic, z_corpus
+
+
+# --------------------------------------------------------------------------- #
+# The stream layout
+# --------------------------------------------------------------------------- #
+
+@dataclasses.dataclass(frozen=True)
+class _Stream:
+    """Shapes of the stream layout, one worker's (the static key of the
+    step program)."""
+    workers: int
+    k: int
+    vocab: int
+    alpha: float
+    beta: float
+    words_store: int        # word rows: the vocabulary in whole word blocks
+    tokens: int             # N, the corpus's tokens
+    groups: int             # G
+    slots: int              # a worker's stream: tiles x TILE, window padded
+    tiles: int              # a worker's tile table, window padded
+    max_tiles: int          # the most tiles of a group: a group's window
+    doc_rows: int           # a worker's document rows, window padded
+    dg: int                 # the most documents of a group: the kernel's window
+    doc_const: float        # Σ_d [lgamma(n_d + Kα) − lgamma(Kα)], float64
+    kernel: bool            # lda_sample (else its jax.numpy twin)
+    interpret: bool = False
+
+
+@dataclasses.dataclass
+class _StreamHost:
+    """What :meth:`LDA.parameters` needs on the host."""
+    docs: Tuple[int, ...]   # documents of each worker, in corpus order
+    valid: np.ndarray       # the slots of the (workers x slots) stream
+    #                         that hold a token
+    index: np.ndarray       # those tokens' corpus indices, in slot order
+
+
+def _bucketed_variants(cfg: LDAConfig) -> list:
+    """The options set in ``cfg`` that only the bucketed layout runs."""
+    return [name for name, on in (
+        ("method='cvb0'", cfg.method != "cgs"),
+        ("num_model_slices=2", cfg.num_model_slices != 1),
+        ("quant", cfg.quant is not None),
+        ("fused_dma", cfg.fused_dma),
+        ("vocab_sub_block", bool(cfg.vocab_sub_block)),
+        ("wt_access", cfg.wt_access != "auto")) if on]
+
+
+def _is_stream(state) -> bool:
+    return isinstance(state[0], _Stream)
+
+
+def as_csr(docs) -> Tuple[np.ndarray, np.ndarray]:
+    """``(doc_ptr (D + 1,) int64, words (N,) int32)`` of a corpus given as a
+    ``(doc_ptr, words)`` pair or as a (D, L) matrix of equal-length
+    documents."""
+    if isinstance(docs, tuple):
+        doc_ptr, words = docs
+        doc_ptr = np.asarray(doc_ptr, np.int64)
+        words = np.asarray(words, np.int32)
+        if (doc_ptr.ndim != 1 or doc_ptr[0] != 0 or doc_ptr[-1] != len(words)
+                or np.any(np.diff(doc_ptr) < 0)):
+            raise ValueError("doc_ptr must rise from 0 to len(words)")
+        return doc_ptr, words
+    docs = np.asarray(docs)
+    d, l = docs.shape
+    return (np.arange(d + 1, dtype=np.int64) * l,
+            docs.reshape(-1).astype(np.int32))
+
+
+def _balanced(prefix: np.ndarray, parts: int) -> np.ndarray:
+    """Bounds of ``parts`` runs of whole documents: run p starts at the
+    first document whose token prefix reaches p·N/parts."""
+    n = int(prefix[-1])
+    starts = np.searchsorted(prefix[:-1] * parts,
+                             np.arange(parts, dtype=np.int64) * n, "left")
+    return np.append(starts, len(prefix) - 1)
+
+
+def _stream_layout(doc_ptr, words, workers: int, cfg: LDAConfig):
+    """The stream layout of a CSR corpus.
+
+    Documents go to workers in runs of balanced token counts; a worker's
+    documents to ``G`` groups the same way. Inside a group the tokens are
+    sorted by word and cut into tiles of ``TILE`` slots, each inside one
+    aligned block of ``WBLOCK`` words; only a tile's tail is padding.
+    Returns ``(geometry, host part, device arrays)``: the arrays are the
+    stream's word-in-block, document-in-group and corpus index per slot, the
+    tile table's word block and token count, and each group's (first tile,
+    tiles, first document row, documents), every one worker-major."""
+    lk = lda_kernels
+    if words.size and (words.min() < 0 or words.max() >= cfg.vocab):
+        raise ValueError(f"token ids must be in [0, {cfg.vocab}); got "
+                         f"[{words.min()}, {words.max()}]")
+    if cfg.num_topics > lk.KP:
+        raise ValueError(f"the stream layout holds at most {lk.KP} topics, "
+                         f"not {cfg.num_topics}")
+    d, n = len(doc_ptr) - 1, len(words)
+    nblocks = -(-cfg.vocab // lk.WBLOCK)
+    lens = np.diff(doc_ptr)
+    doc_of = np.repeat(np.arange(d, dtype=np.int32), lens)
+    owners = _balanced(doc_ptr, workers)
+    plan = []                           # (worker, a, b) per group
+    for p in range(workers):
+        lo, hi = owners[p], owners[p + 1]
+        bounds = lo + _balanced(doc_ptr[lo:hi + 1] - doc_ptr[lo],
+                                cfg.groups_per_sweep)
+        plan += [(p, int(bounds[g]), int(bounds[g + 1]))
+                 for g in range(cfg.groups_per_sweep)]
+
+    def group(item):
+        _, a, b = item
+        t0, t1 = int(doc_ptr[a]), int(doc_ptr[b])
+        order = np.argsort(words[t0:t1], kind="stable")
+        ws = words[t0:t1][order]
+        blk = ws // lk.WBLOCK
+        counts = np.bincount(blk, minlength=nblocks)
+        tiles = -(-counts // lk.TILE)
+        tok0 = np.cumsum(counts) - counts
+        tile0 = np.cumsum(tiles) - tiles
+        pos = tile0[blk] * lk.TILE + (np.arange(len(ws)) - tok0[blk])
+        tcnt = np.full(int(tiles.sum()), lk.TILE, np.int32)
+        has = tiles > 0
+        tcnt[(tile0 + tiles - 1)[has]] = (counts - lk.TILE * (tiles - 1))[has]
+        return (pos, ws % lk.WBLOCK, doc_of[t0:t1][order] - a,
+                t0 + order, np.repeat(np.arange(nblocks), tiles), tcnt)
+
+    with concurrent.futures.ThreadPoolExecutor(8) as pool:
+        laid = list(pool.map(group, plan))
+    g_tiles = np.array([len(x[5]) for x in laid]).reshape(workers, -1)
+    max_tiles = max(int(g_tiles.max()), 1)
+    tiles = int(g_tiles.sum(axis=1).max()) + max_tiles
+    slots = tiles * lk.TILE
+    ndocs = np.array([b - a for _, a, b in plan]).reshape(workers, -1)
+    dg = lane_pack.round_up(int(ndocs.max()), 8)
+    wdocs = np.diff(owners)
+    doc_rows = int(wdocs.max()) + dg
+    if max(doc_rows, nblocks * lk.WBLOCK) * lk.KP >= 2 ** 31:
+        raise ValueError(f"the stream layout addresses a worker's count "
+                         f"cells with int32: at most {2 ** 31 // lk.KP} "
+                         f"rows, not {max(doc_rows, nblocks * lk.WBLOCK)}")
+    wl, dl, index = (np.zeros((workers, slots), np.int32) for _ in range(3))
+    valid = np.zeros((workers, slots), bool)
+    twb, tcnt = (np.zeros((workers, tiles), np.int32) for _ in range(2))
+    meta = np.zeros((workers, cfg.groups_per_sweep, 4), np.int32)
+    for j, ((p, a, b), (pos, w, dd, ix, tb, tc)) in enumerate(zip(plan, laid)):
+        g = j % cfg.groups_per_sweep
+        first = int(g_tiles[p, :g].sum())
+        at = pos + first * lk.TILE
+        wl[p, at], dl[p, at], index[p, at], valid[p, at] = w, dd, ix, True
+        twb[p, first:first + len(tb)] = tb
+        tcnt[p, first:first + len(tc)] = tc
+        meta[p, g] = (first, len(tc), a - owners[p], b - a)
+    kalpha = cfg.num_topics * cfg.alpha
+    doc_const = float(np.sum(_lgamma(lens + kalpha) - _lgamma(kalpha)))
+    backend = jax.default_backend()
+    kernel = lk.use_lda_sample_pallas(backend, cfg.num_topics, workers, dg)
+    geom = _Stream(
+        workers=workers, k=cfg.num_topics, vocab=cfg.vocab,
+        alpha=float(cfg.alpha), beta=float(cfg.beta),
+        words_store=nblocks * lk.WBLOCK, tokens=n,
+        groups=cfg.groups_per_sweep, slots=slots, tiles=tiles,
+        max_tiles=max_tiles, doc_rows=doc_rows, dg=dg, doc_const=doc_const,
+        kernel=kernel, interpret=kernel and backend != "tpu")
+    host = _StreamHost(docs=tuple(int(x) for x in wdocs),
+                       valid=valid.reshape(-1), index=index[valid])
+    arrays = (wl.reshape(-1), dl.reshape(-1), index.reshape(-1),
+              twb.reshape(-1), tcnt.reshape(-1), meta.reshape(-1, 4))
+    return geom, host, arrays
+
+
+def _first_model(wl, dl, index, twb, tcnt, meta, seed, *, g: _Stream):
+    """z at sweep 0 and its counts: word-topic, doc-topic, topic totals."""
+    kp = lda_kernels.KP
+    tile = jnp.arange(g.slots) // lda_kernels.TILE
+    live = jnp.arange(g.slots) % lda_kernels.TILE < tcnt[tile]
+    z = jnp.where(live, lda_kernels.first_topics(
+        lda_kernels.sweep_key(seed, 0), index, g.k), 0)
+    slot = jnp.asarray(lda_kernels.TOPIC_SLOT, jnp.int32)[z]
+    word = twb[tile] * lda_kernels.WBLOCK + wl
+    group = jnp.repeat(jnp.arange(g.groups), meta[:, 1],
+                       total_repeat_length=g.tiles)
+    doc = meta[group[tile], 2] + dl
+    ones = live.astype(jnp.int32)
+    wt = jnp.zeros((g.words_store * kp,), jnp.int32).at[
+        word * kp + slot].add(ones)
+    dt = jnp.zeros((g.doc_rows * kp,), jnp.int32).at[doc * kp + slot].add(ones)
+    if g.workers > 1:
+        wt = jax.lax.psum(wt, WORKERS)
+    shape = (lda_kernels.SUBLANES, lda_kernels.LANES)
+    return (z, wt.reshape((g.words_store,) + shape),
+            dt.reshape((g.doc_rows,) + shape),
+            wt.reshape(g.words_store, kp).sum(axis=0).reshape(shape))
+
+
+def _stream_quality(wt, dt, nk, g: _Stream):
+    """``(−log p(w, z) / N, the word part of log p)`` of the counts: MALLET's
+    word part (the reference's formula) plus the document part, float32
+    sums."""
+    lg = jax.scipy.special.gammaln
+
+    def part(n, prior):
+        cells = jnp.where(n > 0, lg(n.astype(jnp.float32) + prior)
+                          - lg(jnp.float32(prior)), 0.0)
+        return jnp.sum(jnp.sum(cells, axis=(1, 2)))
+
+    wbeta = g.vocab * g.beta
+    topics = jnp.sum(jnp.where(nk > 0, lg(nk.astype(jnp.float32) + wbeta)
+                               - lg(jnp.float32(wbeta)), 0.0))
+    docs = part(dt, g.alpha)
+    if g.workers > 1:
+        docs = jax.lax.psum(docs, WORKERS)
+    word = part(wt, g.beta) - topics
+    return -(word + docs - jnp.float32(g.doc_const)) / g.tokens, word
+
+
+def _stream_sweeps(wl, dl, index, twb_all, tcnt_all, meta, seed, z, wt, dt,
+                   nk, sweep, *, g: _Stream, sweeps: int):
+    """``sweeps`` sweeps of G blocked groups from the chain ``(z, wt, dt,
+    nk, sweep)``: the new chain, each sweep's quality and moved tokens."""
+    telemetry.traced("lda")                # runs when jax traces, only
+    lk = lda_kernels
+    span = g.max_tiles * lk.TILE
+    if g.kernel:
+        metrics.DEFAULT.count("lda.sample.pallas")
+        sampler = functools.partial(
+            lk.sample_pallas, k=g.k, vocab=g.vocab, alpha=g.alpha,
+            beta=g.beta, dg=g.dg, interpret=g.interpret)
+    else:
+        metrics.DEFAULT.count("lda.sample.xla")
+        sampler = functools.partial(
+            lk.sample_xla, k=g.k, vocab=g.vocab, alpha=g.alpha, beta=g.beta,
+            chunk=lk.TILE, reduce=(functools.partial(jax.lax.psum,
+                                                     axis_name=WORKERS)
+                                   if g.workers > 1 else None))
+
+    def group(carry, gm):
+        z, wt, dt, nk, moved, key = carry
+        first = gm[0] * lk.TILE
+        with jax.named_scope("lda.sample"):
+            twb = jax.lax.dynamic_slice(twb_all, (gm[0],), (g.max_tiles,))
+            tcnt = jax.lax.dynamic_slice(tcnt_all, (gm[0],), (g.max_tiles,))
+            u = lk.uniforms(key, jax.lax.dynamic_slice(index, (first,),
+                                                       (span,)))
+            zn, wt, dt, dnk, mv = sampler(gm, twb, tcnt, wl, dl, z, u, wt,
+                                          dt, nk)
+        with jax.named_scope("lda.counts"):
+            old = jax.lax.dynamic_slice(z, (first,), (span,))
+            zn = jnp.where(jnp.arange(span) // lk.TILE < gm[1], zn, old)
+            z = jax.lax.dynamic_update_slice(z, zn, (first,))
+        return (z, wt, dt, nk + dnk, moved + mv, key), None
+
+    def one(chain, _):
+        z, wt, dt, nk, sweep = chain
+        key = lk.sweep_key(seed, sweep + 1)
+        with jax.named_scope("lda.counts"):
+            (z, wt, dt, nk, moved, _), _ = jax.lax.scan(
+                group, (z, wt, dt, nk, jnp.zeros((), jnp.int32), key), meta)
+        with jax.named_scope("lda.quality"):
+            quality, word = _stream_quality(wt, dt, nk, g)
+            if g.workers > 1:
+                moved = jax.lax.psum(moved, WORKERS)
+        return (z, wt, dt, nk, sweep + 1), (quality, word, moved)
+
+    # what the scans add (the carries, the curve's stacking) stands under
+    # lda.counts, a group's parts under their own names
+    with jax.named_scope("lda.counts"):
+        chain, (quality, word, moved) = jax.lax.scan(
+            one, (z, wt, dt, nk, sweep), None, length=sweeps)
+    return chain, quality, word, moved
 
 
 # --------------------------------------------------------------------------- #

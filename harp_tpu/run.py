@@ -11,6 +11,9 @@ flags derived from the model's config dataclass (harp_tpu.config):
     python -m harp_tpu.run sgd_mf --num-users 8192 --num-items 8192 \\
         --epochs 10 --work-dir /tmp/mf --save-every 2      # checkpoint+resume
     python -m harp_tpu.run lda --num-docs 2048 --vocab 2000 --num-topics 32
+    python -m harp_tpu.run lda --corpus-file docword.nytimes.txt \\
+        --num-topics 1000 --alpha 0.05 --groups-per-sweep 32 \\
+        --epochs 10                                 # ragged UCI corpus
     python -m harp_tpu.run pca --num-points 65536 --dim 256
     python -m harp_tpu.run nn --num-points 8192 --dim 64 --epochs 10
 
@@ -493,17 +496,34 @@ def run_sgd_mf(argv) -> int:
     return 0
 
 
+def _docword(spec: str) -> bool:
+    """Whether ``spec`` names one UCI docword file (else a token matrix)."""
+    from harp_tpu.io import loaders
+
+    paths = loaders.list_files(spec)
+    return len(paths) == 1 and loaders.is_docword(paths[0])
+
+
 def run_lda(argv) -> int:
     from harp_tpu.models.lda import LDAConfig
 
-    p = argparse.ArgumentParser(prog="harp_tpu.run lda")
+    p = argparse.ArgumentParser(
+        prog="harp_tpu.run lda",
+        description="LDA by collapsed Gibbs sampling (CVB0 with --method "
+        "cvb0). A docword corpus (CGS) runs on the stream layout: a ragged "
+        "corpus as one token stream sorted by word inside --groups-per-sweep "
+        "groups, int32 counts, through prepare / train_prepared, one fused "
+        "Pallas pass a group on TPU (lda_sample); it runs K = 1,000 on a "
+        "99.5 M-token corpus on one v5e chip.")
     _common_flags(p)
     p.add_argument("--num-docs", type=int, default=1024)
     p.add_argument("--doc-len", type=int, default=64)
     p.add_argument("--corpus-file", default="",
                    help="token-id corpus file/dir/glob (one doc per line, "
-                        "fixed length — e.g. datasets/lda); overrides the "
-                        "synthetic corpus; vocab grows to fit the data")
+                        "fixed length — e.g. datasets/lda), or a UCI "
+                        "bag-of-words docword.*.txt file (ragged documents, "
+                        "on the stream layout); overrides the synthetic "
+                        "corpus; vocab grows to fit the data")
     p.add_argument("--save-every", type=int, default=0,
                    help="checkpoint the chain (z + word-topic model) every "
                         "N epochs into work-dir (printModel parity; resumes "
@@ -520,7 +540,13 @@ def run_lda(argv) -> int:
     from harp_tpu.models import lda
 
     cfg = _config_from_args(lda.LDAConfig, args)
-    if args.corpus_file:
+    if args.corpus_file and _docword(args.corpus_file):
+        from harp_tpu.io import loaders
+
+        doc_ptr, words, vocab = loaders.load_docword(args.corpus_file)
+        docs, num_docs = (doc_ptr, words), len(doc_ptr) - 1
+        cfg = dataclasses.replace(cfg, vocab=max(cfg.vocab, vocab))
+    elif args.corpus_file:
         from harp_tpu.io import loaders
 
         docs = loaders.truncate_to_workers(loaders.load_corpus(
@@ -556,8 +582,9 @@ def run_lda(argv) -> int:
         ran = cfg.epochs
         dt = time.perf_counter() - t0
         timing = ""
-    toks = docs.size * ran
-    print(f"lda[cgs] workers={sess.num_workers} docs={num_docs} "
+    toks = (len(docs[1]) if isinstance(docs, tuple) else docs.size) * ran
+    layout = model.last_layout_stats.get("layout", "bucketed")
+    print(f"lda[{layout}] workers={sess.num_workers} docs={num_docs} "
           f"vocab={cfg.vocab} K={cfg.num_topics}: {toks / dt / 1e6:.2f} "
           f"M tokens/s{timing}, ll {ll[0]:.4e} -> {ll[-1]:.4e}")
     return 0

@@ -74,11 +74,12 @@ CACHE_LOAD = "program.cache_load"
 
 PHASES = (
     "kmeans.prepare", "sgd_mf.prepare", "als.prepare", "ccd.prepare",
-    "mds.prepare", "em.prepare",
+    "mds.prepare", "em.prepare", "lda.prepare",
     "session.place",    # HarpSession.scatter / replicate_put: the enqueue
     "session.run",      # a one-shot program: trace, compile or load, enqueue
     "session.fetch",    # a blocking fetch inside a prepare: the wait
     "kmeans.call", "sgd_mf.call", "als.call", "ccd.call", "mds.call", "em.call",
+    "lda.call",
     "step.dispatch",    # the jitted call alone, which returns at the enqueue
     "step.fetch",       # the fetch of the call's quality: the wait for the run
     "kmeans.checkpoint", "sgd_mf.checkpoint", "lda.checkpoint",

@@ -96,6 +96,9 @@ SCOPES = (
     "em.factor",        # Cholesky, the whitening A_k and b_k, log det, constants
     "em.estep",         # one fused pass: whitened product, log-sum-exp, moments
     "em.update",        # the statistics' psum, the M-step arithmetic, the quality
+    "lda.sample",       # a group's draws: lda_sample (counts written inside) or its twin
+    "lda.counts",       # the group's new topics into z and the totals
+    "lda.quality",      # per-sweep -log p(w, z) / N
 )
 _LISTED = frozenset(SCOPES)
 

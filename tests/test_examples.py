@@ -79,6 +79,22 @@ def test_run_lda_cli():
     assert "M tokens/s" in out.stdout and "ll" in out.stdout
 
 
+def test_run_lda_cli_on_a_uci_docword_file(tmp_path):
+    """A ragged UCI bag-of-words file: it runs on the stream layout with no
+    option set; asked for CVB0, which the stream does not run, the run stops
+    and says why."""
+    path = tmp_path / "docword.tiny.txt"
+    path.write_text("3\n12\n5\n1 1 3\n1 12 1\n2 4 2\n3 5 6\n3 2 1\n")
+    out = _run_cmd(["lda", "--corpus-file", str(path),
+                    "--num-topics", "4", "--epochs", "2",
+                    "--groups-per-sweep", "2"])
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "lda[stream]" in out.stdout and "docs=3" in out.stdout
+    refused = _run_cmd(["lda", "--method", "cvb0", "--corpus-file",
+                        str(path), "--epochs", "1"])
+    assert refused.returncode != 0 and "stream layout" in refused.stderr
+
+
 def test_run_pca_cli():
     out = _run_cmd(["pca", "--num-points", "1024", "--dim", "16",
                     "--iterations", "2"])

@@ -217,6 +217,44 @@ def _em_step(topo, rows: int = EM_POINTS):
     return model._fns[key].lower(*args).compile()
 
 
+LDA_TOKENS = 99_500_000  # the cell lda-k1000.nytimes-100m
+
+
+def _lda_step(topo):
+    """The compiled call of two sweeps at the cell lda-k1000.nytimes-100m's
+    full shape (K = 1,000, 102,660 words, 299,752 documents in 32 groups,
+    the stream's tiles as the cell's corpus lays them out, within a few
+    per cent), with the fused sampler the dispatch picks on the chip."""
+    from harp_tpu.models import lda
+    from harp_tpu.ops import lda_kernels as lk
+
+    sess = HarpSession(num_workers=1, devices=topo.devices[:1])
+    groups, docs, dg = 32, 299_752, 9_528
+    max_tiles = 4_100
+    tiles = groups * max_tiles
+    geom = lda._Stream(
+        workers=1, k=1000, vocab=102_660, alpha=0.05, beta=0.01,
+        words_store=102_784, tokens=LDA_TOKENS, groups=groups,
+        slots=tiles * lk.TILE, tiles=tiles, max_tiles=max_tiles,
+        doc_rows=docs + dg, dg=dg, doc_const=0.0, kernel=True)
+    model = lda.LDA(sess, lda.LDAConfig(num_topics=1000, vocab=102_660,
+                                        layout="stream",
+                                        groups_per_sweep=groups))
+    key = model._stream_program(geom, 2)
+    tile = (lk.SUBLANES, lk.LANES)
+    sh, rep = sess.shard(), sess.replicate()
+    args = ((_shaped(sess, (geom.slots,), jnp.int32, sh),) * 3
+            + (_shaped(sess, (tiles,), jnp.int32, sh),) * 2
+            + (_shaped(sess, (groups, 4), jnp.int32, sh),
+               _shaped(sess, (2,), jnp.uint32, rep),
+               _shaped(sess, (geom.slots,), jnp.int32, sh),
+               _shaped(sess, (geom.words_store, *tile), jnp.int32, rep),
+               _shaped(sess, (geom.doc_rows, *tile), jnp.int32, sh),
+               _shaped(sess, tile, jnp.int32, rep),
+               _shaped(sess, (), jnp.int32, rep)))
+    return model._fns[key].lower(*args).compile()
+
+
 def _loop_lines(text: str):
     """The instruction lines of every ``while`` body of the compiled text,
     nested loops included."""
@@ -314,6 +352,10 @@ PROGRAMS = {
     # E-step, the M-step
     "em-1-fused": (lambda t: _em_step(t).as_text(),
                    {"em.factor", "em.estep", "em.update"}),
+    # LDA sweeps at the cell's full shape: the fused sampler a group, the
+    # new topics into z, the quality a sweep
+    "lda-1-fused": (lambda t: _lda_step(t).as_text(),
+                    {"lda.sample", "lda.counts", "lda.quality"}),
 }
 
 
@@ -670,6 +712,22 @@ def test_the_em_iteration_reads_the_points_in_one_kernel(compiled):
     assert not re.search(r"= \w+\[%d[,\]]" % EM_POINTS, "\n".join(
         line for line in text.splitlines() if " parameter(" not in line
         and "get-tuple-element(" not in line)), "an N-sized value is made"
+
+
+def test_the_lda_sweep_samples_in_one_kernel_and_holds_no_tokens_by_topics(
+        topo, no_compile_cache):
+    """A group is ONE kernel, ``lda_sample`` under ``lda.sample``, handed the
+    stream and both tables; the step's scratch holds nothing of tokens x
+    topics (99.5 M x 1,000 int32 would be 398 GB) and fits beside them."""
+    step = _lda_step(topo)
+    text = step.as_text()
+    mapped = scopes.scope_map(text)
+    calls = [name for name, opcode in _loop_kernels(text)
+             if opcode == "custom-call" and mapped[name] == "lda.sample"]
+    assert len(calls) == 1 and calls[0].startswith("lda_sample"), calls
+    assert step.memory_analysis().temp_size_in_bytes < 2 ** 28
+    assert not re.search(r"\[%d,1000\]|\[%d,1024\]" % (LDA_TOKENS,
+                                                         LDA_TOKENS), text)
 
 
 def test_the_fused_hop_picks_its_block_out_of_the_whole_slab(compiled):

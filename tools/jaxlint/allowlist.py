@@ -40,6 +40,14 @@ ALLOWLIST: Allowlist = {
     ("harp_tpu/ops/linalg.py", "body", "JL106"):
         "distributed-sort permutation bookkeeping: O(W) control-plane "
         "rows per merge round, not data-plane traffic",
+    ("harp_tpu/models/lda.py", "_first_model", "JL106"):
+        "stream-layout first model: the counts of z at sweep 0, scattered "
+        "ONCE per prepare; every sweep's count writes on TPU are the "
+        "lda_sample kernel's one-hot GEMMs",
+    ("harp_tpu/ops/lda_kernels.py", "sample_xla", "JL106"):
+        "the lda_sample kernel's jax.numpy twin (CPU, the mesh, shapes the "
+        "kernel does not take): its exact int32 count writes are the "
+        "segment form the kernel's one-hot GEMM was timed against",
 
     # -- JL103 retrace-hazard: sanctioned one-shot / per-config compiles ----
     ("harp_tpu/session.py", "run", "JL103"):
