@@ -39,8 +39,10 @@ and written in place). For each part of :data:`PART` tokens it
    below ``u · c``: the draw, one token a lane;
 3. writes the word block's change as one-hot products on the MXU (the
    one-hot GEMM of ``ops/lane_pack.gemm_scatter`` at 128 words, ±1 operands
-   in bfloat16, float32 sums: exact), and the document rows' new counts a
-   token at a time into the VMEM copy, which goes back to HBM once a group.
+   in bfloat16, float32 sums: exact), and the document rows' new counts
+   into the VMEM copy, which goes back to HBM once a group: each token's
+   slot is computed on the vector unit (:func:`topic_slot`) and the tokens
+   add their one-hot rows eight a loop step.
 
 Nothing of size tokens × K leaves VMEM. :func:`sample_xla` is the same group
 in ``jax.numpy`` (row gathers, :func:`chained_draw` and scatter-adds over
@@ -95,6 +97,13 @@ def _slot_topic() -> np.ndarray:
 
 SLOT_TOPIC = _slot_topic()           # slot 128 s + v -> topic
 TOPIC_SLOT = np.argsort(SLOT_TOPIC)  # topic -> slot
+
+
+def topic_slot(z):
+    """The flat slot ``128 s + v`` of topics ``z`` (:data:`TOPIC_SLOT`), by
+    shifts and masks: topics are non-negative."""
+    m = z & 127
+    return 128 * (m >> 4) + 8 * (m & 15) + (z >> 7)
 
 
 # --------------------------------------------------------------------------- #
@@ -259,9 +268,10 @@ def _sample_kernel(meta, twb, tcnt,
         zn_out[q:q + 1, :] = zn
         moved_out[...] += (zn != zo).astype(jnp.int32)
 
-        # the new topics go to SMEM for the document counts below; the
-        # copy runs behind the word block's products
-        zrow[0:1, :] = zn
+        # the new topics' slots go to SMEM for the document counts below,
+        # -1 on the pad lanes (no slot); the copy runs behind the word
+        # block's products
+        zrow[0:1, :] = jnp.where(live, topic_slot(zn), -1)
         cp = pltpu.make_async_copy(zrow.at[0], zsm, sems.at[1])
         cp.start()
 
@@ -278,18 +288,20 @@ def _sample_kernel(meta, twb, tcnt,
             wt_out[:, s, :] += change
             nk_out[s:s + 1, :] += jnp.sum(change, axis=0, keepdims=True)
 
-        # the documents' new counts, a token at a time
+        # the documents' new counts, eight tokens a step over the whole
+        # part: a pad's document is row 0 (the layout pads with zeros) and
+        # its slot -1, so it adds zeros to a real row of the window
         cp.wait()
+        flat = sub8 * LANES + lane8
 
-        def count(j, c):
-            z = zsm[j]
-            m = z % LANES
-            hit = ((sub8 == m // 16) & (lane8 == 8 * (m % 16) + z // LANES))
-            d = dl_s[base + j]
-            dacc[d] = dacc[d] + hit.astype(jnp.int32)
+        def count(j8, c):
+            for jj in range(_UNROLL):
+                j = j8 * _UNROLL + jj
+                d = dl_s[base + j]
+                dacc[d] = dacc[d] + (flat == zsm[j]).astype(jnp.int32)
             return c
 
-        jax.lax.fori_loop(0, jnp.minimum(valid, PART), count, 0)
+        jax.lax.fori_loop(0, PART // _UNROLL, count, 0)
 
     @pl.when(i < n)
     def _():

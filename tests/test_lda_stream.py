@@ -222,15 +222,22 @@ def test_auto_lays_a_csr_corpus_out_as_the_stream_and_a_matrix_in_buckets(
         lda.LDA(one, _cfg(layout="bucketed"))
 
 
-@pytest.mark.parametrize("ragged", [False, True])
-def test_the_kernel_in_interpret_mode_is_its_twin(one, monkeypatch, ragged):
-    """Equal-length documents of 128 tokens (tiles that are whole parts) and
-    ragged ones: one sweep on the kernel draws every token as the twin, and
-    both tables come out the same."""
-    if ragged:
+@pytest.mark.parametrize("corpus", ["whole", "ragged", "one_document"],
+                         ids=["False", "True", "one_document"])
+def test_the_kernel_in_interpret_mode_is_its_twin(one, monkeypatch, corpus):
+    """Equal-length documents of 128 tokens (tiles that are whole parts),
+    ragged ones, and groups of one long document over six words of one word
+    block (every token of a part adds to the same document row, so each
+    count must see the previous token's; each group's last part is partial,
+    with pad lanes): one sweep on the kernel draws every token as the twin,
+    and both tables come out the same."""
+    rng = np.random.default_rng(7)
+    if corpus == "ragged":
         doc_ptr, words = _corpus(7, docs=24, longest=300)
+    elif corpus == "one_document":
+        doc_ptr = np.cumsum([0, 1000, 700, 1100, 300]).astype(np.int64)
+        words = rng.integers(0, 6, doc_ptr[-1]).astype(np.int32)
     else:
-        rng = np.random.default_rng(7)
         doc_ptr = np.arange(17, dtype=np.int64) * 128
         words = rng.integers(0, W, 16 * 128).astype(np.int32)
     out = {}
@@ -247,6 +254,15 @@ def test_the_kernel_in_interpret_mode_is_its_twin(one, monkeypatch, ragged):
     for x, y in zip(out[False][0], out[True][0]):
         assert np.array_equal(x, y)
     np.testing.assert_allclose(out[True][1], out[False][1], rtol=1e-6)
+
+
+def test_topic_slot_is_the_tables_slot_of_every_topic():
+    topics = np.arange(lda_kernels.KP, dtype=np.int32)
+    for z in (topics, jnp.asarray(topics)):
+        assert np.array_equal(np.asarray(lda_kernels.topic_slot(z)),
+                              lda_kernels.TOPIC_SLOT)
+    assert np.array_equal(
+        lda_kernels.SLOT_TOPIC[lda_kernels.topic_slot(topics)], topics)
 
 
 def test_eight_workers_keep_the_counts_and_reach_one_workers_likelihood(
