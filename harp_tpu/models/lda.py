@@ -1040,6 +1040,7 @@ class LDA:
                 out_specs=(sess.shard(), sess.replicate(), sess.shard(),
                            sess.replicate()))
             sweep = sess.replicate_put(np.zeros((), np.int32))
+            runs, parts = _word_block_runs(*arrays[3:], geom)
             self.last_layout_stats = {
                 "layout": "stream",
                 "kernel": "pallas" if geom.kernel else "xla",
@@ -1047,6 +1048,8 @@ class LDA:
                 "count_write": "gemm_scatter" if geom.kernel else "segment",
                 "pad_tokens": int(geom.workers * geom.slots - geom.tokens),
                 "groups": geom.groups, "docs_per_group": geom.dg,
+                # the kernel folds its word-block change once a run
+                "word_block_runs": runs, "parts_per_run": parts / max(runs, 1),
                 "resident_bytes": int(4 * geom.workers * (
                     4 * geom.slots + geom.doc_rows * lda_kernels.KP)
                     + 4 * geom.words_store * lda_kernels.KP),
@@ -1284,6 +1287,20 @@ def _stream_layout(doc_ptr, words, workers: int, cfg: LDAConfig):
     arrays = (wl.reshape(-1), dl.reshape(-1), index.reshape(-1),
               twb.reshape(-1), tcnt.reshape(-1), meta.reshape(-1, 4))
     return geom, host, arrays
+
+
+def _word_block_runs(twb, tcnt, meta, g: _Stream) -> Tuple[int, int]:
+    """The stream's runs, the consecutive tiles of one word block within one
+    group, and its parts of ``PART`` tokens, over every worker's groups: a
+    sweep of the kernel folds its word-block change once a run."""
+    twb = twb.reshape(g.workers, g.tiles)
+    tcnt = tcnt.reshape(g.workers, g.tiles)
+    start = np.ones_like(twb, bool)
+    start[:, 1:] = twb[:, 1:] != twb[:, :-1]
+    first = meta[:, 0].reshape(g.workers, -1)
+    start[np.arange(g.workers)[:, None], first] = True
+    parts = -(-tcnt.astype(np.int64) // lda_kernels.PART)
+    return int(np.sum(start & (tcnt > 0))), int(parts.sum())
 
 
 def _first_model(wl, dl, index, twb, tcnt, meta, seed, *, g: _Stream):

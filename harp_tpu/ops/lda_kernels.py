@@ -26,8 +26,9 @@ document rows.
 
 The kernel (:func:`sample_pallas`, named ``lda_sample``) runs one group. It
 holds the group's document rows in VMEM twice (the group-start counts it
-reads, and the new counts it writes) and a tile's word block twice (read,
-and written in place). For each part of :data:`PART` tokens it
+reads, and the new counts it writes), a tile's word block twice (read,
+and written in place) and the change of the word block's run so far. For
+each part of :data:`PART` tokens it
 
 1. copies every token's word row and document row into a staging buffer and
    turns each of the eight slot rows of the 128 tokens over (XLU), so that a
@@ -37,12 +38,16 @@ and written in place). For each part of :data:`PART` tokens it
    by topic; then the offsets, and in a second pass over the saved ``p``
    runs each chain's sum again and counts the topics with ``o_σ + a_σm``
    below ``u · c``: the draw, one token a lane;
-3. writes the word block's change as one-hot products on the MXU (the
-   one-hot GEMM of ``ops/lane_pack.gemm_scatter`` at 128 words, ±1 operands
-   in bfloat16, float32 sums: exact), and the document rows' new counts
-   into the VMEM copy, which goes back to HBM once a group: each token's
-   slot is computed on the vector unit (:func:`topic_slot`) and the tokens
-   add their one-hot rows eight a loop step.
+3. adds the word block's change, one-hot products on the MXU (the one-hot
+   GEMM of ``ops/lane_pack.gemm_scatter`` at 128 words, ±1 operands in
+   bfloat16, float32 sums: exact), into a contiguous VMEM accumulator,
+   slot row by slot row; the accumulator is folded into the word block's
+   sublane-strided rows, and into the totals, once per run (the consecutive
+   tiles of one word block within the group), at the run's last tile;
+   and writes the document rows' new counts into the VMEM copy, which goes
+   back to HBM once a group: each token's slot is computed on the vector
+   unit (:func:`topic_slot`) and the tokens add their one-hot rows eight a
+   loop step.
 
 Nothing of size tokens × K leaves VMEM. :func:`sample_xla` is the same group
 in ``jax.numpy`` (row gathers, :func:`chained_draw` and scatter-adds over
@@ -179,11 +184,12 @@ def _tile(i, meta):
 def _sample_kernel(meta, twb, tcnt,
                    wl_s, dl_s, wl_v, zo_v, u_v, wt_in, dt_hbm, rk,
                    zn_out, wt_out, dt_out, nk_out, moved_out,
-                   din, dacc, sw, sd, pbuf, zrow, zsm, sems,
+                   din, dacc, wacc, sw, sd, pbuf, zrow, zsm, sems,
                    *, k, alpha, beta, dg):
     i = pl.program_id(0)
     n = meta[1]
     ii = _tile(i, meta)
+    after = jnp.minimum(ii + 1, twb.shape[0] - 1)
 
     @pl.when(i == 0)
     def _():
@@ -204,9 +210,11 @@ def _sample_kernel(meta, twb, tcnt,
         moved_out[...] = jnp.zeros_like(moved_out)
 
     # a run of tiles on one word block starts from its group-start counts
+    # and an empty change
     @pl.when((i == 0) | ((i < n) & (twb[ii] != twb[jnp.maximum(ii - 1, 0)])))
     def _():
         wt_out[...] = wt_in[...]
+        wacc[...] = jnp.zeros_like(wacc)
 
     sig = jax.lax.broadcasted_iota(jnp.int32, (16, SUBLANES, LANES), 1)
     jdx = jax.lax.broadcasted_iota(jnp.int32, (16, SUBLANES, LANES), 0)
@@ -276,7 +284,7 @@ def _sample_kernel(meta, twb, tcnt,
         cp.start()
 
         # the word block's change: one-hot (words, tokens) against the
-        # tokens' ±1 rows, one product a slot row
+        # tokens' ±1 rows, one product a slot row, added up contiguously
         onehot = ((words == wl_v[q:q + 1, :]) & live).astype(jnp.bfloat16)
         for s in range(SUBLANES):
             kid = LANES * sig + 16 * s + jdx
@@ -285,8 +293,7 @@ def _sample_kernel(meta, twb, tcnt,
             change = jax.lax.dot_general(
                 onehot, delta.reshape(LANES, LANES), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32).astype(jnp.int32)
-            wt_out[:, s, :] += change
-            nk_out[s:s + 1, :] += jnp.sum(change, axis=0, keepdims=True)
+            wacc[s] += change
 
         # the documents' new counts, eight tokens a step over the whole
         # part: a pad's document is row 0 (the layout pads with zeros) and
@@ -307,6 +314,14 @@ def _sample_kernel(meta, twb, tcnt,
     def _():
         for q in range(TILE // PART):
             pl.when(q * PART < tcnt[i])(functools.partial(part, q))
+
+    # the run's change goes into the word block's strided rows and the
+    # totals once, at its last tile
+    @pl.when((i < n) & ((i == n - 1) | (twb[after] != twb[ii])))
+    def _():
+        for s in range(SUBLANES):
+            wt_out[:, s, :] += wacc[s]
+            nk_out[s:s + 1, :] += jnp.sum(wacc[s], axis=0, keepdims=True)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _():
@@ -375,6 +390,7 @@ def sample_pallas(meta, twb, tcnt, wl, dl, z, u, wt, dt, nk, *, k: int,
             scratch_shapes=[
                 pltpu.VMEM((dg, SUBLANES, LANES), jnp.int32),
                 pltpu.VMEM((dg, SUBLANES, LANES), jnp.int32),
+                pltpu.VMEM((SUBLANES, WBLOCK, LANES), jnp.int32),
                 pltpu.VMEM((PART, SUBLANES, LANES), jnp.int32),
                 pltpu.VMEM((PART, SUBLANES, LANES), jnp.int32),
                 pltpu.VMEM((KP // CHAINS, CHAINS, LANES), jnp.float32),

@@ -222,21 +222,36 @@ def test_auto_lays_a_csr_corpus_out_as_the_stream_and_a_matrix_in_buckets(
         lda.LDA(one, _cfg(layout="bucketed"))
 
 
-@pytest.mark.parametrize("corpus", ["whole", "ragged", "one_document"],
-                         ids=["False", "True", "one_document"])
+@pytest.mark.parametrize("corpus", ["whole", "ragged", "one_document",
+                                    "runs"],
+                         ids=["False", "True", "one_document", "runs"])
 def test_the_kernel_in_interpret_mode_is_its_twin(one, monkeypatch, corpus):
     """Equal-length documents of 128 tokens (tiles that are whole parts),
-    ragged ones, and groups of one long document over six words of one word
+    ragged ones, groups of one long document over six words of one word
     block (every token of a part adds to the same document row, so each
     count must see the previous token's; each group's last part is partial,
-    with pad lanes): one sweep on the kernel draws every token as the twin,
-    and both tables come out the same."""
+    with pad lanes), and groups of three word-block runs (three tiles, one
+    partial tile, then one tile that ends the group: the word block's change
+    is folded at a block change and at the group's last tile): one sweep on
+    the kernel draws every token as the twin, and both tables come out the
+    same."""
     rng = np.random.default_rng(7)
     if corpus == "ragged":
         doc_ptr, words = _corpus(7, docs=24, longest=300)
     elif corpus == "one_document":
         doc_ptr = np.cumsum([0, 1000, 700, 1100, 300]).astype(np.int64)
         words = rng.integers(0, 6, doc_ptr[-1]).astype(np.int32)
+    elif corpus == "runs":
+        # two documents a group: 2,060 tokens of block 0 (tiles of 1,024,
+        # 1,024 and 12), 40 of block 1, 700 of block 3 (one tile, 6 parts);
+        # the last group moves 60 of block 3's to block 2, a fourth run, so
+        # the others' windows end in steps past their tiles
+        doc_ptr = np.arange(9, dtype=np.int64) * 1400
+        words = np.concatenate([np.concatenate([
+            rng.integers(0, 128, 1030), rng.integers(128, 256, 20),
+            rng.integers(256, 384, 30 * (d >= 6)),
+            rng.integers(384, W, 350 - 30 * (d >= 6))])
+            for d in range(8)]).astype(np.int32)
     else:
         doc_ptr = np.arange(17, dtype=np.int64) * 128
         words = rng.integers(0, W, 16 * 128).astype(np.int32)
@@ -249,6 +264,9 @@ def test_the_kernel_in_interpret_mode_is_its_twin(one, monkeypatch, corpus):
         assert state[0].kernel is kernel
         assert model.last_layout_stats["count_write"] == (
             "gemm_scatter" if kernel else "segment")
+        if corpus == "runs":
+            assert model.last_layout_stats["word_block_runs"] == 3 * 3 + 4
+            assert model.last_layout_stats["parts_per_run"] == 4 * 24 / 13
         state, quality = model.train_prepared(state, 1)
         out[kernel] = (model.parameters(state), quality)
     for x, y in zip(out[False][0], out[True][0]):

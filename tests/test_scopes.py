@@ -9,6 +9,7 @@ file loads the TPU's library. Nothing runs: a compile says nothing about time.
 """
 
 import dataclasses
+import functools
 import os
 import re
 
@@ -728,6 +729,37 @@ def test_the_lda_sweep_samples_in_one_kernel_and_holds_no_tokens_by_topics(
     assert step.memory_analysis().temp_size_in_bytes < 2 ** 28
     assert not re.search(r"\[%d,1000\]|\[%d,1024\]" % (LDA_TOKENS,
                                                          LDA_TOKENS), text)
+
+
+def test_the_lda_kernel_fits_vmem_at_the_largest_group_it_admits(
+        topo, no_compile_cache):
+    """Mosaic accepts ``lda_sample`` under its VMEM limit at the most
+    documents a group that the dispatch hands it: the two copies of the
+    group's document rows beside the fixed part (staging, p, the word block
+    and its run's change)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from harp_tpu.ops import lda_kernels as lk
+
+    dg = max(d for d in range(8, 2 ** 15, 8)
+             if lk.use_lda_sample_pallas("tpu", 1000, 1, d))
+    assert dg == 12_800
+    one = SingleDeviceSharding(topo.devices[0])
+    max_tiles, slots = 3_600, 2 * 3_600 * lk.TILE
+    tile = (lk.SUBLANES, lk.LANES)
+
+    def shaped(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    sample = functools.partial(lk.sample_pallas, k=1000, vocab=102_660,
+                               alpha=0.05, beta=0.01, dg=dg)
+    text = jax.jit(sample).lower(
+        shaped((4,)), shaped((max_tiles,)), shaped((max_tiles,)),
+        shaped((slots,)), shaped((slots,)), shaped((slots,)),
+        shaped((max_tiles * lk.TILE,), jnp.float32),
+        shaped((102_784, *tile)), shaped((299_752 + dg, *tile)),
+        shaped(tile)).compile().as_text()
+    assert "tpu_custom_call" in text and lk.NAME in text
 
 
 def test_the_fused_hop_picks_its_block_out_of_the_whole_slab(compiled):
